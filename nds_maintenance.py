@@ -19,7 +19,7 @@ from datetime import datetime
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
 from nds_tpu.check import check_version, check_json_summary_folder, \
-    get_abs_path  # noqa: E402
+    get_abs_path, select_device  # noqa: E402
 
 check_version()
 
@@ -210,10 +210,7 @@ if __name__ == "__main__":
                         help='execution device.')
     args = parser.parse_args()
 
-    if args.device == "cpu":
-        os.environ["JAX_PLATFORMS"] = "cpu"
-        import jax
-        jax.config.update("jax_platforms", "cpu")
+    select_device(args.device)
 
     from nds_tpu.engine.session import Session  # noqa: E402
     from nds_tpu.warehouse import Warehouse  # noqa: E402

@@ -15,7 +15,7 @@ import sys
 
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
-from nds_tpu.check import check_version  # noqa: E402
+from nds_tpu.check import check_version, select_device  # noqa: E402
 
 check_version()
 
@@ -65,7 +65,9 @@ if __name__ == "__main__":
                         choices=["tpu", "cpu"],
                         default="tpu",
                         help="execution device; 'cpu' pins the engine to the "
-                        "host platform (useful for baseline/validation runs).")
+                        "host platform (useful for baseline/validation "
+                        "runs). 'tpu' with JAX_PLATFORMS unset pins the "
+                        "TPU: a missing chip is an error, never a CPU run.")
     parser.add_argument("--profile",
                         help="folder for per-query device profiler traces "
                         "(XProf/TensorBoard dumps).")
@@ -89,10 +91,7 @@ if __name__ == "__main__":
                         "official Power Run.")
     args = parser.parse_args()
 
-    if args.device == "cpu":
-        os.environ.setdefault("JAX_PLATFORMS", "cpu")
-        import jax
-        jax.config.update("jax_platforms", "cpu")
+    select_device(args.device)
 
     from nds_tpu.power import gen_sql_from_stream, run_query_stream  # noqa: E402
 

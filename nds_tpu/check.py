@@ -27,6 +27,31 @@ def check_version(min_version=MIN_PYTHON) -> None:
         )
 
 
+def select_device(device: str) -> None:
+    """Apply a driver's ``--device`` switch before any JAX backend exists.
+
+    ``cpu`` pins the host platform. ``tpu`` (every driver's default) must
+    never end on the CPU behind the user's back: with no chip and no
+    platform pinned, JAX initialises its CPU backend by itself and the
+    run proceeds. So where the caller left ``JAX_PLATFORMS`` unset, it is
+    pinned to ``tpu`` and a missing chip is JAX's own hard error. A
+    ``JAX_PLATFORMS`` the caller set is the caller's explicit choice and
+    is respected (tier-1 drives the default ``--device tpu`` under
+    ``JAX_PLATFORMS=cpu``)."""
+    import jax
+    if device == "cpu":
+        platform = "cpu"
+    elif os.environ.get("JAX_PLATFORMS"):
+        return
+    else:
+        platform = "tpu"
+    # jax is already imported (the package root imports it), so the
+    # variable alone would come too late: set the config too. The
+    # variable still matters for child processes.
+    os.environ["JAX_PLATFORMS"] = platform
+    jax.config.update("jax_platforms", platform)
+
+
 def repo_root() -> Path:
     return Path(__file__).resolve().parent.parent
 

@@ -33,12 +33,7 @@ import threading
 import jax
 import jax.numpy as jnp
 
-try:  # pallas is TPU/experimental; keep the engine importable without it
-    from jax.experimental import pallas as pl
-    _HAVE_PALLAS = True
-except ImportError:  # pragma: no cover
-    pl = None
-    _HAVE_PALLAS = False
+from jax.experimental import pallas as pl
 
 # row tile: sublane-friendly multiple; group tile: one lane width
 _TR = 512
@@ -48,7 +43,7 @@ _TG = 128
 def _pallas_mode() -> str:
     """'tpu' | 'interpret' | 'off'."""
     env = os.environ.get("NDS_TPU_PALLAS", "auto")
-    if env == "off" or not _HAVE_PALLAS:
+    if env == "off":
         return "off"
     if env == "interpret":
         return "interpret"
@@ -110,7 +105,7 @@ def _segment_sum_pallas(gids, weights, num_segments: int, interpret: bool):
         grid=grid,
         # the leading block index must stay i32: a literal 0 weak-types to
         # i64 under the engine's jax_enable_x64, and Mosaic refuses the
-        # mixed (i64, i32) index-map return (seen on the v5e attachment as
+        # mixed (i64, i32) index-map return (seen on a v5e as
         # "failed to legalize operation 'func.return'"); j - j keeps the
         # zero in the grid index's own dtype
         in_specs=[
@@ -130,7 +125,28 @@ def _segment_sum_pallas(gids, weights, num_segments: int, interpret: bool):
     return sums[0, :num_segments], counts[0, :num_segments]
 
 
+# interpret-mode only: the first kernel failure of a process flips every
+# kernel to its XLA twin (the listener reports it, so the statement ends
+# CompletedWithTaskFailures). On a chip (mode "tpu") nothing sets it: a
+# kernel the compiler refuses there fails the query — see _kernel_failed.
 _pallas_broken = False
+
+
+def _kernel_failed(what: str, exc: Exception,
+                   mode: str = "interpret") -> None:
+    """A kernel call site's handler. On a chip (``mode`` "tpu") the
+    failure is the query's: re-raised, no flag, no change of arm. In
+    interpret mode: record it and switch the process to the XLA twins."""
+    global _pallas_broken
+    if mode == "tpu":
+        raise exc
+    _pallas_broken = True
+    from nds_tpu.listener import report_task_failure
+    report_task_failure(f"pallas {what} kernel (XLA fallback for the "
+                        f"rest of the process)", exc)
+    import sys
+    print(f"# pallas kernels disabled ({type(exc).__name__}); "
+          f"using XLA fallback", file=sys.stderr)
 
 # the one-hot matmul does O(rows x groups) MACs — MXU throughput makes that
 # a win over scatter only while the group tile count stays small. Measured
@@ -140,6 +156,25 @@ _pallas_broken = False
 # (engine/stream.py _cache_key) and a post-import change must retrace.
 def max_groups() -> int:
     return int(os.environ.get("NDS_TPU_PALLAS_MAX_GROUPS", "2048"))
+
+
+def _spans_devices(*arrays) -> bool:
+    """True when a concrete input lives on more than one device: the
+    survivors of a sharded streamed scan, a table of an NDS_MESH_SHAPE
+    session. A Mosaic kernel traced into such a program is refused —
+    ``NotImplementedError: Mosaic kernels cannot be automatically
+    partitioned. Please wrap the call in a shard_map`` (seen on four v5e
+    chips, PR 22: query3's sum over shards=4 survivors; pinned in
+    tests/test_tpu_compile.py) — so by decision those inputs take the
+    XLA segment ops, which GSPMD partitions. Tracers carry no placement
+    (inside a shard_map body each shard is one device)."""
+    for a in arrays:
+        if isinstance(a, jax.core.Tracer):
+            continue
+        sharding = getattr(a, "sharding", None)
+        if sharding is not None and len(sharding.device_set) > 1:
+            return True
+    return False
 
 
 def pallas_active(num_segments: int | None = None) -> bool:
@@ -156,26 +191,20 @@ def segment_sum_fused(weights, gids, num_segments: int):
 
     Rows with gid < 0 are excluded (pre-masked nulls / filtered rows).
     Pallas MXU path on TPU (small group counts — see ``max_groups()``), XLA
-    segment ops elsewhere. Some TPU attachment paths (e.g. tunneled
-    remote-compile backends) cannot compile Mosaic kernels at all; the first
-    such failure permanently flips to the XLA fallback for the process
-    instead of failing the query.
+    segment ops elsewhere. On a chip a kernel that fails to compile or run
+    fails the query: the v5e compiler accepts all three segment kernels
+    (tests/test_tpu_compile.py), so a refusal is a fault to see, not a
+    reason to change arms in silence.
     """
-    global _pallas_broken
     mode = _pallas_mode()
     if mode != "off" and not _pallas_broken and \
-            num_segments <= max_groups():
+            num_segments <= max_groups() and \
+            not _spans_devices(gids, weights):
         try:
             return _segment_sum_pallas(gids, weights, num_segments,
                                        mode == "interpret")
-        except Exception as e:  # Mosaic unsupported on this attachment
-            _pallas_broken = True
-            from nds_tpu.listener import report_task_failure
-            report_task_failure("pallas segment-sum kernel "
-                                "(permanent XLA fallback)", e)
-            import sys
-            print(f"# pallas kernels disabled ({type(e).__name__}); "
-                  f"using XLA fallback", file=sys.stderr)
+        except Exception as e:
+            _kernel_failed("segment-sum", e, mode)
     live = gids >= 0
     safe = jnp.where(live, gids, 0)
     w = jnp.where(live, weights.astype(jnp.float32), 0.0)
@@ -299,22 +328,16 @@ def segment_sum_exact(values, gids, num_segments: int):
     the same gates as :func:`segment_sum_fused`; XLA segment ops
     elsewhere. Unlike the f32 kernel this is bit-exact — it serves the
     DEFAULT decimal bench path."""
-    global _pallas_broken
     mode = _pallas_mode()
     if mode != "off" and not _pallas_broken and \
-            exact_sum_supported(num_segments, int(values.shape[0])):
+            exact_sum_supported(num_segments, int(values.shape[0])) and \
+            not _spans_devices(gids, values):
         try:
             sums, counts = _segment_sum_exact_pallas(
                 gids, values, num_segments, mode == "interpret")
             return sums, counts.astype(jnp.int64)
-        except Exception as e:  # Mosaic unsupported on this attachment
-            _pallas_broken = True
-            from nds_tpu.listener import report_task_failure
-            report_task_failure("pallas exact segment-sum kernel "
-                                "(permanent XLA fallback)", e)
-            import sys
-            print("# pallas kernels disabled; using XLA fallback",
-                  file=sys.stderr)
+        except Exception as e:
+            _kernel_failed("exact segment-sum", e, mode)
     live = gids >= 0
     safe = jnp.where(live, gids, 0)
     v = jnp.where(live, values, 0)
@@ -398,21 +421,15 @@ def segment_minmax_fused(values, gids, num_segments: int):
     the engine's exact decimal/int64 min/max stays on XLA (f32 rounding
     would corrupt exact comparisons).
     """
-    global _pallas_broken
     mode = _pallas_mode()
     if mode != "off" and not _pallas_broken and \
-            num_segments <= max_groups():
+            num_segments <= max_groups() and \
+            not _spans_devices(gids, values):
         try:
             return _segment_minmax_pallas(gids, values, num_segments,
                                           mode == "interpret")
-        except Exception as e:  # Mosaic unsupported on this attachment
-            _pallas_broken = True
-            from nds_tpu.listener import report_task_failure
-            report_task_failure("pallas segment-min/max kernel "
-                                "(permanent XLA fallback)", e)
-            import sys
-            print("# pallas kernels disabled; using XLA fallback",
-                  file=sys.stderr)
+        except Exception as e:
+            _kernel_failed("segment-min/max", e, mode)
     live = gids >= 0
     safe = jnp.where(live, gids, 0)
     v = values.astype(jnp.float32)
@@ -669,11 +686,9 @@ def fused_chunk_scan(chunk_flat, n_dev, spec: ScanSpec, interpret: bool):
     syncs by construction (the `host-read-in-pallas` lint rule polices
     the kernel bodies).
 
-    Mosaic caveat: like the segment kernels, some attachment paths
-    cannot compile Pallas at all, and the int64 lanes here lean on the
-    x64 emulation; ``scan_spec_ready`` smoke-compiles the spec at
-    pipeline-build time so a refusing backend flips the process to the
-    XLA chain instead of failing mid-drive."""
+    Mosaic refuses this kernel as written (int64 lanes, the 64-bit casts
+    of the hash fold, the 1-D dict gather — see ``scan_kernels_active``),
+    so it runs in interpret mode only."""
     datas, valids, keybufs, tables = _scan_inputs(chunk_flat, spec)
     plen = datas[0].shape[0]
     n_pad = max(_ceil_to(plen, _TR_SCAN), _TR_SCAN)
@@ -737,38 +752,50 @@ def fused_chunk_scan(chunk_flat, n_dev, spec: ScanSpec, interpret: bool):
 
 def scan_kernels_active() -> bool:
     """True when pipeline builds should extract a scan spec and route
-    the per-chunk hot path through :func:`fused_chunk_scan`. Same
-    contract as :func:`pallas_active`: callers gate on this, and the
-    first backend refusal flips the process to the XLA chain."""
-    return not _pallas_broken and _pallas_mode() != "off"
+    the per-chunk hot path through :func:`fused_chunk_scan`. Callers
+    gate on this (and :func:`probe_kernel_active` builds on it).
+
+    Interpret mode only: the fused scan and the fused probe are OFF on
+    the chip by decision, not by a caught exception. Asked at a 1 Mi-row
+    shape (x64 on, jax 0.9.0 / libtpu 0.0.34), the v5e compiler refuses
+    both as written:
+
+    * ``fused_chunk_scan``, int64 lane: ``UNIMPLEMENTED: While rewriting
+      computation to not contain X64 element types ...
+      custom_call_target="tpu_custom_call",
+      operand_layout_constraints={s64[1,1048576]}``;
+    * int32 lane + validity + ``key_slots``: ``NotImplementedError:
+      64-bit types are not supported`` (the ``_fold_hash``/``n_dev`` lanes);
+    * int16 dict codes on the float lane: ``NotImplementedError: Only 2D
+      gather is supported``;
+    * ``fused_probe``, int64 keys over a uint64 hash table:
+      ``NotImplementedError: 64-bit types are not supported``.
+
+    tests/test_tpu_compile.py pins the four refusals; the day one of them
+    compiles, that test fails and this gate is where the kernel comes
+    back (ROADMAP Queue 1 item 6 / Queue 3 item 4: 32-bit lanes).
+    ``NDS_TPU_PALLAS=interpret`` keeps both reachable for the parity
+    tests and the diff harnesses."""
+    return not _pallas_broken and _pallas_mode() == "interpret"
 
 
 def scan_spec_ready(spec: ScanSpec, chunk_flat, plen: int) -> bool:
     """Smoke-run one fused scan over zeroed buffers of the real chunk
     shapes at pipeline-BUILD time (eager, one tile's work, result
-    discarded — no host read). A Mosaic refusal here flips the
-    permanent XLA fallback BEFORE any compiled pipeline bakes the
-    kernel in, so a refusing attachment degrades at build time, never
-    mid-drive."""
-    global _pallas_broken
-    mode = _pallas_mode()
-    if mode == "off" or _pallas_broken:
+    discarded — no host read), so a spec the interpreter cannot run
+    degrades to the XLA chain BEFORE any compiled pipeline bakes the
+    kernel in, never mid-drive."""
+    if not scan_kernels_active():
         return False
     try:
         dummy = tuple(
             None if x is None else jnp.zeros((plen,), dtype=x.dtype)
             for x in chunk_flat)
         fused_chunk_scan(dummy, jnp.asarray(plen, dtype=jnp.int64), spec,
-                         mode == "interpret")
+                         interpret=True)
         return True
-    except Exception as e:  # Mosaic unsupported on this attachment
-        _pallas_broken = True
-        from nds_tpu.listener import report_task_failure
-        report_task_failure("pallas fused chunk-scan kernel "
-                            "(permanent XLA fallback)", e)
-        import sys
-        print(f"# pallas kernels disabled ({type(e).__name__}); "
-              f"using XLA fallback", file=sys.stderr)
+    except Exception as e:
+        _kernel_failed("fused chunk-scan", e)
         return False
 
 
@@ -840,10 +867,11 @@ def probe_reference(views, valids, n_valid, excluded, rh_sorted):
 
 
 def probe_kernel_active(views, valids, plen_r: int) -> bool:
-    """Gate for the fused probe: Pallas on, int key views only, and the
+    """Gate for the fused probe: interpret mode only (Mosaic refuses it,
+    see :func:`scan_kernels_active`), int key views only, and the
     dimension hash table small enough to hold whole in VMEM. Callers
     fall back to the XLA probe whenever this says no."""
-    if _pallas_broken or _pallas_mode() == "off":
+    if not scan_kernels_active():
         return False
     if plen_r > _PROBE_MAX_R:
         return False
@@ -939,35 +967,28 @@ _probe_smoke_ok: bool | None = None
 def try_fused_probe(left_keys, lviews, lvalids, n_valid, excluded,
                     rh_sorted):
     """The ops.py seam: (counts, lo) through the fused probe, or None
-    when the gate declines / the backend refuses (first refusal flips
-    the permanent XLA fallback via a one-time eager smoke run, so a
-    Mosaic error can never surface mid-pipeline-drive)."""
-    global _probe_smoke_ok, _pallas_broken
+    when the gate declines (always, outside interpret mode) or a
+    one-time eager smoke run fails, so an error can never surface
+    mid-pipeline-drive."""
+    global _probe_smoke_ok
     if not probe_kernel_active(lviews, lvalids, int(rh_sorted.shape[0])):
         return None
     if any(lk.kind == "f64" for lk in left_keys):
         return None
-    mode = _pallas_mode()
     if _probe_smoke_ok is None:
         try:
             v = jnp.zeros(4, dtype=jnp.int64)
             rh = jnp.zeros(4, dtype=jnp.uint64)
             fused_probe((v,), (None,), jnp.asarray(4, dtype=jnp.int64),
-                        None, rh, mode == "interpret")
+                        None, rh, interpret=True)
             _probe_smoke_ok = True
-        except Exception as e:  # Mosaic unsupported on this attachment
+        except Exception as e:
             _probe_smoke_ok = False
-            _pallas_broken = True
-            from nds_tpu.listener import report_task_failure
-            report_task_failure("pallas fused join-probe kernel "
-                                "(permanent XLA fallback)", e)
-            import sys
-            print(f"# pallas kernels disabled ({type(e).__name__}); "
-                  f"using XLA fallback", file=sys.stderr)
+            _kernel_failed("fused join-probe", e)
     if not _probe_smoke_ok:
         return None
     return fused_probe(lviews, lvalids, n_valid, excluded, rh_sorted,
-                       mode == "interpret")
+                       interpret=True)
 
 
 # ---------------------------------------------------------------------------
@@ -1007,11 +1028,12 @@ def note_probe() -> None:
 
 
 def active_arm() -> str:
-    """"pallas" | "xla": the arm the segment/scan kernels take for this
-    process right now — NDS_TPU_PALLAS plus the permanent-fallback flip
-    (_pallas_broken), which until now was only visible through the
-    listener's task-failure report. Surfaced as the ``kernelArm``
-    annotation on every ``stream`` span so tools/trace_report.py can
-    attribute kernel coverage (and price fused-vs-XLA) per query."""
-    return "pallas" if (not _pallas_broken and _pallas_mode() != "off") \
-        else "xla"
+    """"pallas" | "xla": the arm the FUSED chunk kernels (scan pass, join
+    probe) take for this process right now — "xla" on a chip, where
+    Mosaic refuses them (:func:`scan_kernels_active`), "pallas" only in
+    interpret mode. Surfaced as the ``kernelArm`` annotation on every
+    ``stream`` span so tools/trace_report.py can attribute fused-kernel
+    coverage (and price fused-vs-XLA) per query. The segment kernels'
+    arm is :func:`_pallas_mode` itself (the Power ledger's ``pallas``
+    meta field)."""
+    return "pallas" if scan_kernels_active() else "xla"

@@ -2058,9 +2058,9 @@ def _build_pipeline(planner, parts, keep, alias, join_preds,
             flat0 = tuple(x for cname in first.column_names
                           for x in (first[cname].data,
                                     first[cname].valid))
-            # smoke-compile on this chunk's real shapes: a Mosaic-
-            # refusing attachment degrades to the XLA chain at BUILD
-            # time, never mid-drive
+            # smoke-run on this chunk's real shapes (interpret mode; the
+            # fused scan is off on a chip): a spec that cannot run
+            # degrades to the XLA chain at BUILD time, never mid-drive
             if not _K.scan_spec_ready(scan_spec, flat0, chunk_cap):
                 scan_spec, where_kept = None, list(where_conjuncts)
     # save/restore: a subquery residual planned DURING this record may

@@ -5,7 +5,7 @@ Every benchmark campaign so far wrote its evidence into four disjoint
 shapes — bench.py resume lines, power.py per-query JSON summaries,
 ``streamedScans`` lists and ``tracePhases`` rollups — none of which was
 schema-versioned, validated on load, or guaranteed to survive a kill
-(BENCH_r05 died at rc=124 with ``{"value": null, "n_queries": 0}``).
+(a run once died at rc=124 with ``{"value": null, "n_queries": 0}``).
 The ledger is the ONE append-only JSONL record both drivers write and
 every post-hoc tool reads:
 
@@ -46,7 +46,7 @@ pre-ledger campaign artifacts stay resumable.
 
 This module is deliberately STDLIB-ONLY (no jax, no nds_tpu imports):
 the bench.py parent — the budget supervisor that must never touch the
-device attachment — loads it by file path, bypassing the jax-importing
+chip — loads it by file path, bypassing the jax-importing
 package root.
 """
 
@@ -341,8 +341,8 @@ def evidence_from_scans(scans) -> dict:
 class Ledger:
     """Append-only writer. Every record is validated before it is
     written and durably flushed (flush + fsync) so a kill can lose at
-    most the statement in flight — the write discipline the BENCH_r05
-    postmortem demanded. Thread-safe: the heartbeat thread interleaves
+    most the statement in flight — the write discipline a run that
+    ended at rc 124 with no value demanded. Thread-safe: the heartbeat thread interleaves
     ``progress`` records with the main thread's ``query`` records."""
 
     def __init__(self, path: str, stamp: dict | None = None, **meta):

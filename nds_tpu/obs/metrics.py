@@ -44,7 +44,7 @@ Contract (DESIGN.md "Live metrics rollups"):
   sharing one env can land N distinct files in one directory.
 
 This module is deliberately STDLIB-ONLY (no jax, no nds_tpu imports):
-the bench.py parent — which must never touch the device attachment —
+the bench.py parent — which must never touch the chip —
 loads it by file path via ``tools/_ledger_load.py`` under the same
 discipline as the ledger.
 """

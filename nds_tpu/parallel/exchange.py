@@ -88,17 +88,10 @@ def all_gather_counted(x, axis: str, tiled: bool = True):
 
 
 def shard_map_compat(fn, mesh, in_specs, out_specs):
-    """``shard_map`` across jax versions (the replication-check kwarg was
-    renamed when it moved out of experimental); checks disabled — the
-    engine's bodies are manual SPMD by design."""
-    try:
-        from jax import shard_map as _sm
-        return _sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   check_vma=False)
-    except ImportError:  # pragma: no cover - older jax
-        from jax.experimental.shard_map import shard_map as _sm
-        return _sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   check_rep=False)
+    """``jax.shard_map`` with the replication check off — the engine's
+    bodies are manual SPMD by design."""
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def make_mesh(n_devices: int | None = None, axis: str = "part") -> Mesh:

@@ -2,7 +2,7 @@
 """Host-sync budget tests (DESIGN.md reduction items 1+3).
 
 Every device->host scalar read flushes the dispatch queue, costs a round
-trip to a (possibly tunneled) chip, and is a full-mesh barrier under GSPMD
+trip to the chip, and is a full-mesh barrier under GSPMD
 — the reference's Spark driver pays ONE round trip per query
 (ref: nds/nds_power.py:125-135, spark.sql(q).collect()). These tests pin
 the engine's per-query budget so a regression back to per-operator syncs

@@ -1,0 +1,243 @@
+# Copyright (c) 2026, nds-tpu authors. Licensed under the Apache License, Version 2.0.
+"""What the v5e compiler says about the engine's Pallas kernels.
+
+The TPU compiler is installed where the tests run; it compiles for a chip
+that is DESCRIBED, not attached (no chip time, nothing runs). These cases
+keep its answers for the five kernels of ``nds_tpu/engine/kernels.py`` at
+the shapes ``chip_smoke.py`` really produces at scale factor 1, so every
+later PR is held to them:
+
+* the three segment kernels compile, with a ``tpu_custom_call`` in the
+  program — on a chip a refusal fails the query (no XLA fallback there);
+* the fused chunk scan and the fused probe are REFUSED as written. The
+  four refusals are pinned with ``pytest.raises``: the day someone makes
+  one of them compile, its test fails loudly, becomes a positive case,
+  and ``kernels.scan_kernels_active()`` lets the kernel back on the chip.
+
+The topology is described inside a module-scoped fixture — never at
+import, never in a ``skipif`` or a ``parametrize`` argument: only one
+process may load the TPU library at a time, the suite runs under several
+xdist workers that each import every test file, and only the worker that
+is handed this file may load it. Keep these cases in this one file, and
+compile in the test's own process (a child could not load the library).
+"""
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import SingleDeviceSharding
+
+import nds_tpu  # noqa: F401  (turns x64 on, as the engine runs)
+from nds_tpu.engine import kernels as K
+
+# the streamed phase's chunk capacity at SF1 (NDS_TPU_STREAM_CHUNK_ROWS
+# in chip_smoke.py) and the 1 Mi-row shape the refusals were first seen at
+CHUNK = 131072
+MI = 1 << 20
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip is written to the persistent cache
+    # but cannot be read back without the chip (the next run would warn
+    # and compile again): keep these compiles out of it
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _compile(one_chip, fn, *shapes):
+    args = [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+            for shape, dtype in shapes]
+    return jax.jit(fn).lower(*args).compile()
+
+
+# (kernel, value dtype, rows, groups): where each shape comes from
+SEGMENT_CASES = [
+    # agg_count rides the f32 sum kernel: query96's count(*) over the
+    # streamed survivors, a chunk-capacity input, the 1 Mi issue shape,
+    # and the widest admitted corner (rows < 2^24, max_groups() = 2048)
+    ("sum", jnp.float32, 16, 16),
+    ("sum", jnp.float32, CHUNK, 1024),
+    ("sum", jnp.float32, MI, 1024),
+    ("sum", jnp.float32, 1 << 23, 2048),
+    # the exact int64 decimal sum: query56's streamed union (1024 x 1024)
+    # and resident scan (1 Mi x 256), and both corners of
+    # exact_sum_supported (rows * groups <= 3e8, rows < 2^23) — the most
+    # groups and the most rows it admits at power-of-two buckets
+    ("exact", jnp.int64, 1024, 1024),
+    ("exact", jnp.int64, MI, 256),
+    ("exact", jnp.int64, CHUNK, 2048),
+    ("exact", jnp.int64, 1 << 22, 64),
+    # float min/max (the --floats path): chunk capacity and the corner
+    ("minmax", jnp.float64, CHUNK, 1024),
+    ("minmax", jnp.float64, MI, 2048),
+]
+_SEGMENT_FN = {"sum": K._segment_sum_pallas,
+               "exact": K._segment_sum_exact_pallas,
+               "minmax": K._segment_minmax_pallas}
+
+
+@pytest.mark.parametrize("kernel,dtype,rows,groups", SEGMENT_CASES)
+def test_segment_kernel_compiles_for_v5e(one_chip, kernel, dtype, rows,
+                                         groups):
+    """Mosaic accepts the three segment kernels at the shapes the smoke
+    produces and at the corners of their gates (fast-memory limits show
+    at the edges)."""
+    if kernel == "exact":
+        assert rows * groups <= K.exact_onehot_budget() and rows < (1 << 23)
+    assert groups <= K.max_groups()
+    fn = _SEGMENT_FN[kernel]
+    compiled = _compile(
+        one_chip, lambda g, v: fn(g, v, groups, False),
+        ((rows,), jnp.int32), ((rows,), dtype))
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_fused_scan_int64_lane_is_refused(one_chip):
+    """REFUSED: ``UNIMPLEMENTED: While rewriting computation to not
+    contain X64 element types ... custom_call_target="tpu_custom_call",
+    operand_layout_constraints={s64[1,1048576]}`` — XLA:TPU emulates
+    int64 by rewriting it away and cannot rewrite through a Mosaic call
+    whose operand is s64."""
+    spec = K.ScanSpec([("ige", 0, 0)], [(0, -1, "id", 0, -1, 1.0)])
+    with pytest.raises(jax.errors.JaxRuntimeError,
+                       match="X64 element types"):
+        _compile(one_chip,
+                 lambda d, n: K.fused_chunk_scan((d, None), n, spec, False),
+                 ((MI,), jnp.int64), ((), jnp.int64))
+
+
+def test_fused_scan_hash_lane_is_refused(one_chip):
+    """REFUSED: ``NotImplementedError: 64-bit types are not supported``
+    — even over an int32 data lane, ``_fold_hash`` casts keys to int64
+    and the ``n_dev`` row bound is int64."""
+    spec = K.ScanSpec([("ige", 0, 0)], [(0, 1, "id", 0, -1, 1.0)],
+                      key_slots=(0,))
+    with pytest.raises(NotImplementedError, match="64-bit types"):
+        _compile(one_chip,
+                 lambda d, v, n: K.fused_chunk_scan((d, v), n, spec, False),
+                 ((MI,), jnp.int32), ((MI,), jnp.bool_), ((), jnp.int64))
+
+
+def test_fused_scan_dict_float_lane_is_refused(one_chip):
+    """REFUSED: ``NotImplementedError: Only 2D gather is supported`` —
+    the float lane decodes sorted-dict codes with a 1-D ``jnp.take``."""
+    spec = K.ScanSpec([("fge", 0, 1.5)], [(0, -1, "dict", 0, 0, 100.0)],
+                      tables=[np.arange(100, dtype=np.int64)])
+    with pytest.raises(NotImplementedError, match="Only 2D gather"):
+        _compile(one_chip,
+                 lambda d, n: K.fused_chunk_scan((d, None), n, spec, False),
+                 ((MI,), jnp.int16), ((), jnp.int64))
+
+
+def test_fused_probe_is_refused(one_chip):
+    """REFUSED: ``NotImplementedError: 64-bit types are not supported``
+    — int64 key views hashed to uint64 against a uint64 table."""
+    with pytest.raises(NotImplementedError, match="64-bit types"):
+        _compile(one_chip,
+                 lambda k, n, rh: K.fused_probe((k,), (None,), n, None, rh,
+                                                False),
+                 ((MI,), jnp.int64), ((), jnp.int64),
+                 ((K._PROBE_MAX_R,), jnp.uint64))
+
+
+def test_segment_kernel_over_a_mesh_is_refused(topo):
+    """REFUSED: ``NotImplementedError: Mosaic kernels cannot be
+    automatically partitioned. Please wrap the call in a shard_map`` —
+    what four real v5e chips answered when query3 summed the survivors of
+    a ``shards=4`` streamed scan (PR 22). ``kernels._spans_devices`` keeps
+    such inputs on the XLA segment ops."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    rows = NamedSharding(Mesh(np.asarray(topo.devices), ("shard",)),
+                         P("shard"))
+    args = [jax.ShapeDtypeStruct((1024,), dt, sharding=rows)
+            for dt in (jnp.int32, jnp.int64)]
+    with pytest.raises(NotImplementedError,
+                       match="cannot be automatically partitioned"):
+        jax.jit(lambda g, v: K._segment_sum_exact_pallas(
+            g, v, 64, False)).lower(*args).compile()
+
+
+def test_inputs_spanning_devices_take_the_xla_segment_ops(monkeypatch):
+    """The decision that follows: rows placed over several devices (here
+    two of the CPU's virtual ones) never reach a Pallas kernel, in any
+    mode, and the XLA twins give the same answer."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    def boom(*a, **k):
+        raise AssertionError("a Pallas kernel was handed sharded inputs")
+    for name in ("_segment_sum_pallas", "_segment_sum_exact_pallas",
+                 "_segment_minmax_pallas"):
+        monkeypatch.setattr(K, name, boom)
+    monkeypatch.setattr(K, "_pallas_broken", False)
+    monkeypatch.setattr(K, "_pallas_mode", lambda: "tpu")
+    rows = NamedSharding(Mesh(np.asarray(jax.devices()[:2]), ("shard",)),
+                         P("shard"))
+    gids = jax.device_put(jnp.arange(8, dtype=jnp.int32) % 4, rows)
+    vals = jax.device_put(jnp.arange(8, dtype=jnp.int64), rows)
+    assert K._spans_devices(gids, vals)
+    assert not K._spans_devices(jnp.arange(8))
+    sums, counts = K.segment_sum_exact(vals, gids, 4)
+    assert sums.tolist() == [4, 6, 8, 10] and counts.tolist() == [2] * 4
+    fsums, fcounts = K.segment_sum_fused(vals.astype(jnp.float32), gids, 4)
+    assert fsums.tolist() == [4.0, 6.0, 8.0, 10.0]
+    assert fcounts.tolist() == [2.0] * 4
+    mins, maxs = K.segment_minmax_fused(vals.astype(jnp.float64), gids, 4)
+    assert mins.tolist() == [0.0, 1.0, 2.0, 3.0]
+    assert maxs.tolist() == [4.0, 5.0, 6.0, 7.0]
+
+
+def test_fused_kernels_stay_off_the_chip(monkeypatch):
+    """The gate that follows from the refusals above: in mode ``tpu`` the
+    fused scan and probe are off by decision (``active_arm`` says xla),
+    while the segment kernels stay on; interpret mode keeps the fused
+    kernels reachable for the parity tests."""
+    monkeypatch.setattr(K, "_pallas_broken", False)
+    monkeypatch.setattr(K, "_pallas_mode", lambda: "tpu")
+    keys = (jnp.zeros(8, dtype=jnp.int64),)
+    assert K.pallas_active(16)
+    assert not K.scan_kernels_active()
+    assert not K.probe_kernel_active(keys, (None,), 1024)
+    assert K.active_arm() == "xla"
+    monkeypatch.setattr(K, "_pallas_mode", lambda: "interpret")
+    assert K.scan_kernels_active()
+    assert K.probe_kernel_active(keys, (None,), 1024)
+    assert K.active_arm() == "pallas"
+
+
+def test_segment_kernel_failure_on_chip_fails_the_query(monkeypatch):
+    """Mode ``tpu``: a segment kernel that fails raises — it does not set
+    the process-wide flag and carry on with ``jax.ops.segment_*``."""
+    def boom(*a, **k):
+        raise RuntimeError("injected Mosaic refusal")
+    monkeypatch.setattr(K, "_pallas_broken", False)
+    monkeypatch.setattr(K, "_pallas_mode", lambda: "tpu")
+    for name in ("_segment_sum_pallas", "_segment_sum_exact_pallas",
+                 "_segment_minmax_pallas"):
+        monkeypatch.setattr(K, name, boom)
+    gids = jnp.zeros(8, dtype=jnp.int32)
+    with pytest.raises(RuntimeError, match="injected"):
+        K.segment_sum_fused(jnp.ones(8, dtype=jnp.float32), gids, 4)
+    with pytest.raises(RuntimeError, match="injected"):
+        K.segment_sum_exact(jnp.ones(8, dtype=jnp.int64), gids, 4)
+    with pytest.raises(RuntimeError, match="injected"):
+        K.segment_minmax_fused(jnp.ones(8, dtype=jnp.float64), gids, 4)
+    assert K._pallas_broken is False

@@ -3,7 +3,7 @@
 
 ``nds_tpu/obs/ledger.py`` is deliberately stdlib-only, but importing it
 as ``nds_tpu.obs.ledger`` executes the package root, which imports jax —
-unacceptable for the bench.py parent (the device attachment belongs to
+unacceptable for the bench.py parent (the chip belongs to
 the serving child alone) and needless weight for post-hoc tools. This
 helper loads the module BY FILE PATH, once, cached under a canonical
 ``sys.modules`` name so every caller shares one module object (isinstance

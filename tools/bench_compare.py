@@ -5,8 +5,8 @@ against the static audits.
 
 Four tentpole claims (streamed conversion, partitioned accumulation,
 encoded upload, sharded collectives) landed with static proofs but no
-re-measured number — and the previous round artifact (BENCH_r05) was a
-null geomean nobody diffed. This tool makes rounds COMPARABLE and the
+re-measured number — and the previous round artifact (a run that ended
+at rc 124 with no value) was a null geomean nobody diffed. This tool makes rounds COMPARABLE and the
 comparison ENFORCEABLE:
 
 * **diff** (two rounds): per-query wall deltas, geomean ratio, and the
@@ -332,7 +332,7 @@ def gate(cmp, threshold=1.10, per_query_threshold=1.50,
     killed round B (no terminal record) or queries measured in A but
     absent from B fail unless ``allow_missing`` explicitly blesses a
     partial comparison — CI must never go green on a campaign that died
-    (the BENCH_r05 silent-death mode). Returns violation lines (empty =
+    (the silent death of a run that ended at rc 124 with no value). Returns violation lines (empty =
     pass)."""
     v = []
     for q, status in cmp.get("now_failing", {}).items():

@@ -47,7 +47,7 @@ def main():
     except OSError:
         pass
     device = (sys.argv[4] if len(sys.argv) > 4
-              else "single v5-lite chip via remote attachment")
+              else "unstated (pass the device as the fourth argument)")
     doc = {
         "scale_factor": 10,
         "device": device,
@@ -56,9 +56,8 @@ def main():
                       "query fails RESOURCE_EXHAUSTED — verified); fact "
                       "tables stream host->device in fixed-power-of-two "
                       "row chunks through the normal join graph"),
-        "peak_hbm": ("allocator stats unavailable through this remote "
-                     "attachment (memory_stats() returns None); on local "
-                     "chips nds_power.py records hbmBytesInUse/"
+        "peak_hbm": ("where memory_stats() returns allocator stats "
+                     "(a TPU does) nds_power.py records hbmBytesInUse/"
                      "peakHbmRaisedBy per query"),
         "n_measured": len(queries),
         "n_failed": len(failures),

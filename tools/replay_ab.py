@@ -1,5 +1,5 @@
 # Copyright (c) 2026, nds-tpu authors. Licensed under the Apache License, Version 2.0.
-"""Per-query eager-vs-replayed A/B on the attached device (REPLAY_r{N}).
+"""Per-query eager-vs-replayed A/B on the device JAX finds.
 
 For each query of the generated stream: run eager twice (timed second),
 then force-record + compile the whole-query replay program, then time the
@@ -9,7 +9,7 @@ deployment (round-3 verdict weak #2: the policy rested on a CPU
 measurement).
 
 Usage:
-    python tools/replay_ab.py [--queries q3,q9,...] [--out REPLAY_r04.json]
+    python tools/replay_ab.py [--queries q3,q9,...] [--out replay_ab.json]
 Env: NDS_BENCH_SCALE (default 0.05) selects the cached bench dataset.
 """
 
@@ -28,7 +28,7 @@ SCALE = os.environ.get("NDS_BENCH_SCALE", "0.05")
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--queries", help="comma list; default = whole stream")
-    ap.add_argument("--out", default=os.path.join(REPO, "REPLAY_r04.json"))
+    ap.add_argument("--out", default=os.path.join(REPO, "replay_ab.json"))
     ap.add_argument("--per_query_budget_s", type=float, default=600.0)
     args = ap.parse_args()
 
@@ -126,7 +126,7 @@ def main():
         "n_segmented": sum(1 for r in ok if r.get("segmented")),
         "geomean_eager_s": _geo([r["eager_s"] for r in ok]),
         "geomean_replay_s": _geo([r["replay_s"] for r in ok]),
-        "note": ("Per-query eager-vs-replayed wall on this attachment; "
+        "note": ("Per-query eager-vs-replayed wall on this device; "
                  "the session replay policy (session._replay_on) should "
                  "be ON where geomean_replay_s < geomean_eager_s."),
         "results": results,
