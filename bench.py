@@ -318,19 +318,16 @@ def run_server():
             t1 = time.perf_counter()
             # roofline decomposition measured on the final pass (sync
             # counts are deterministic per query; wait time is weather)
-            from nds_tpu.listener import drain_stream_events
+            from nds_tpu.obs import evidence as obs_evidence
             from nds_tpu.obs import export as obs_export
-            from nds_tpu.obs import trace as obs_trace
-            drain_stream_events()        # count only the final pass's scans
-            obs_trace.drain_spans()
-            s0, w0 = _ops.sync_count(), _ops.sync_wait_ns()
+            evidence = obs_evidence.begin()   # only the final pass's scans
             sess.sql(sql).collect()
             t2 = time.perf_counter()
-            stream_events = drain_stream_events()
-            trace_records = obs_trace.drain_spans()
+            ev = evidence.end()
+            stream_events = ev["streamEvents"]
             ms = min(t1 - t0, t2 - t1) * 1000.0
-            syncs = _ops.sync_count() - s0
-            sync_ms = (_ops.sync_wait_ns() - w0) / 1e6
+            syncs = ev["hostSyncs"]
+            sync_ms = ev["syncWaitMs"]
             scan = sum(getattr(sess, "last_scanned", {}).values())
             gbps = scan / max(t2 - t1, 1e-9) / 1e9
             # measured compile split (jax monitoring): the warm pass's
@@ -356,34 +353,28 @@ def run_server():
                 # plus the aggregated evidence dict the campaign ledger
                 # records (computed HERE from the live events, so the
                 # parent's ledger write need not re-derive it)
-                from nds_tpu.listener import (stream_event_json,
-                                              stream_evidence)
-                result["streamedScans"] = [
-                    stream_event_json(e) for e in stream_events]
+                from nds_tpu.listener import stream_evidence
+                result["streamedScans"] = ev["streamedScans"]
                 result["evidence"] = stream_evidence(stream_events)
             # fault-recovery evidence (engine/faults.py): every seam
             # recovery since the previous query — retries, degradation
             # ladder steps, watchdog timeouts — next to streamedScans,
             # so a fallback that fired in production is benchmark
             # evidence, not log noise
-            from nds_tpu.engine.faults import (drain_fault_events,
-                                               fault_event_json)
-            fault_events = drain_fault_events()
-            if fault_events:
-                result["faultEvents"] = [fault_event_json(e)
-                                         for e in fault_events]
-            if trace_records:
+            if ev["faultEvents"]:
+                result["faultEvents"] = ev["faultEvents"]
+            roll = ev["rollup"]
+            if ev["records"]:
                 # per-phase attribution of the final timed pass (obs
                 # layer; zero added syncs): plan vs drive vs materialize
                 # per query, plus top sync-charging host-read sites
-                roll = obs_export.rollup(trace_records)
                 result["tracePhases"] = roll
                 trace_d = os.environ.get("NDS_BENCH_TRACE_DIR")
                 if trace_d:
                     os.makedirs(trace_d, exist_ok=True)
                     obs_export.write_chrome_trace(
                         os.path.join(trace_d, f"{name}.trace.json"),
-                        trace_records, query=name, roll=roll)
+                        ev["records"], query=name, roll=roll)
             # per-query HBM footprint where the backend exposes
             # allocator stats (a TPU does; the CPU backend returns None)
             stats = device.memory_stats()
@@ -577,16 +568,27 @@ def perf_text(times, perf, platform="unknown", scale=None):
     return "\n".join(out) + "\n"
 
 
-def write_perf(times, perf, platform="unknown"):
-    """PERF.md: the per-query roofline table (wall, host-sync count and
+def bench_perf_path():
+    """Where a campaign's roofline table goes unless the caller names a
+    path: ``<repo>/chiprun_out/BENCH_PERF.md``, beside the run's other
+    outputs. Never ``<repo>/PERF.md``: that file is the builders' account
+    of the benchmark, and a campaign run used to overwrite it."""
+    return os.path.join(REPO, "chiprun_out", "BENCH_PERF.md")
+
+
+def write_perf(times, perf, platform="unknown", path=None):
+    """The campaign's per-query roofline table (wall, host-sync count and
     blocked time, bytes scanned, effective bandwidth) the geomean headline
-    decomposes into. Committed alongside BENCH_r{N}.json so 'is it fast?'
-    is answerable from artifacts (device vs host split per query).
+    decomposes into, written to ``path`` (default
+    :func:`bench_perf_path`) so 'is it fast?' is answerable from
+    artifacts (device vs host split per query).
     ``platform`` is the serving child's ``jax.devices()[0].platform`` —
     real provenance, not an assumed "attached chip"."""
     if not perf:
         return
-    with open(os.path.join(REPO, "PERF.md"), "w") as f:
+    path = path or bench_perf_path()
+    os.makedirs(os.path.dirname(path), exist_ok=True)
+    with open(path, "w") as f:
         f.write(perf_text(times, perf, platform))
 
 

@@ -38,6 +38,11 @@ with ``# nds-lint: ignore[rule]`` on the flagged line or the line above):
   this guard is ``obs.trace.span()`` returning a null span under
   ``replay_mode() == "replay"``; this rule catches the static case the
   runtime guard cannot see — a span lexically inside a jitted body.
+  ``obs.op(...)`` (the engine-primitive span) counts as a span in every
+  rule here. ``obs.scoped`` (``jax.named_scope`` around a body: names
+  operations at trace time, reads no clock) and ``obs.annotation`` (a
+  profiler ``TraceAnnotation``: no ring record, no counter read — what
+  the prefetch ring's worker uses in place of a span) do not.
   Only obs-owned calls trip it: conventional module names
   (``obs``/``_obs``/``obs_trace``), any ``nds_tpu.obs`` import alias,
   and bare names from-imported from the obs package — an unrelated
@@ -127,6 +132,8 @@ _TIME_FUNCS = {"time", "perf_counter", "perf_counter_ns", "monotonic"}
 _CHUNK_ITER_FUNCS = {"device_chunks", "padded_chunks"}
 # engine entry points that resolve a device scalar on host
 _ENGINE_SYNC_FUNCS = {"host_sync", "count_int", "resolve_counts"}
+# obs.trace entry points that open a span (clock + counter reads)
+_SPAN_ATTRS = ("span", "op")
 # ops.host_read-charging entry points (every counted device->host read
 # funnels through host_read; these are the call forms code reaches it by)
 _HOST_READ_FUNCS = {"host_read", "timed_read", "guarded_scalar_read"}
@@ -575,7 +582,7 @@ class _Lint(ast.NodeVisitor):
                 what = f"{f.attr}()"
             elif isinstance(f, ast.Name) and f.id in _HOST_READ_FUNCS:
                 what = f"{f.id}()"
-        is_span = (isinstance(f, ast.Attribute) and f.attr == "span"
+        is_span = (isinstance(f, ast.Attribute) and f.attr in _SPAN_ATTRS
                    and isinstance(f.value, ast.Name)
                    and f.value.id in self.obs_aliases) or \
             (isinstance(f, ast.Name) and f.id in self.span_funcs)
@@ -620,7 +627,7 @@ class _Lint(ast.NodeVisitor):
                 what = f"{f.attr}()"
             elif isinstance(f, ast.Name) and f.id in _HOST_READ_FUNCS:
                 what = f"{f.id}()"
-        is_span = (isinstance(f, ast.Attribute) and f.attr == "span"
+        is_span = (isinstance(f, ast.Attribute) and f.attr in _SPAN_ATTRS
                    and isinstance(f.value, ast.Name)
                    and f.value.id in self.obs_aliases) or \
             (isinstance(f, ast.Name) and f.id in self.span_funcs)
@@ -666,7 +673,7 @@ class _Lint(ast.NodeVisitor):
                 what = f"{f.attr}()"
             elif isinstance(f, ast.Name) and f.id in _HOST_READ_FUNCS:
                 what = f"{f.id}()"
-        is_span = (isinstance(f, ast.Attribute) and f.attr == "span"
+        is_span = (isinstance(f, ast.Attribute) and f.attr in _SPAN_ATTRS
                    and isinstance(f.value, ast.Name)
                    and f.value.id in self.obs_aliases) or \
             (isinstance(f, ast.Name) and f.id in self.span_funcs)
@@ -726,7 +733,7 @@ class _Lint(ast.NodeVisitor):
                 self._emit("time-in-jit", "error",
                            f"time.{f.attr}() inside a jax.jit function is "
                            "evaluated once at trace time", node.lineno)
-            if f.attr == "span" and owner in self.obs_aliases and \
+            if f.attr in _SPAN_ATTRS and owner in self.obs_aliases and \
                     self._in_jit():
                 self._emit("span-in-jit", "error",
                            "obs.span(...) inside a jax.jit function reads "

@@ -35,6 +35,8 @@ import jax.numpy as jnp
 
 from jax.experimental import pallas as pl
 
+from nds_tpu.obs import trace as _trace
+
 # row tile: sublane-friendly multiple; group tile: one lane width
 _TR = 512
 _TG = 128
@@ -90,6 +92,7 @@ def _ceil_to(x: int, m: int) -> int:
 
 
 @functools.partial(jax.jit, static_argnums=(2, 3))
+@_trace.scoped("kernel.segment_sum")
 def _segment_sum_pallas(gids, weights, num_segments: int, interpret: bool):
     n = gids.shape[0]
     n_pad = max(_ceil_to(n, _TR), _TR)
@@ -261,6 +264,7 @@ def _seg_exact_kernel(gid_ref, w_ref, acc_ref):
 
 
 @functools.partial(jax.jit, static_argnums=(2, 3))
+@_trace.scoped("kernel.segment_sum_exact")
 def _segment_sum_exact_pallas(gids, values, num_segments: int,
                               interpret: bool):
     n = gids.shape[0]
@@ -382,6 +386,7 @@ def _seg_minmax_kernel(gid_ref, v_ref, min_ref, max_ref):
 
 
 @functools.partial(jax.jit, static_argnums=(2, 3))
+@_trace.scoped("kernel.segment_minmax")
 def _segment_minmax_pallas(gids, values, num_segments: int, interpret: bool):
     n = gids.shape[0]
     n_pad = max(_ceil_to(n, _TR), _TR)
@@ -677,6 +682,7 @@ def scan_reference(chunk_flat, n_dev, spec: ScanSpec):
     return mask, h
 
 
+@_trace.scoped("kernel.chunk_scan")
 def fused_chunk_scan(chunk_flat, n_dev, spec: ScanSpec, interpret: bool):
     """ONE Pallas pass over the padded chunk: every referenced buffer
     crosses HBM->VMEM once, the lowered conjuncts and the partition hash
@@ -878,6 +884,7 @@ def probe_kernel_active(views, valids, plen_r: int) -> bool:
     return all(v.dtype != jnp.float64 for v in views)
 
 
+@_trace.scoped("kernel.probe")
 def fused_probe(views, valids, n_valid, excluded, rh_sorted,
                 interpret: bool):
     """(counts, lo) of the bound-bucket probe in ONE VMEM pass per chunk

@@ -383,6 +383,11 @@ def _resolve_refs(val):
     return val
 
 
+# frames the sync-site walk passes through: this module and the obs
+# layer's op()/traced() wrappers around its primitives
+_SYNC_SITE_SKIP = ("ops.py", os.path.join("obs", "trace.py"))
+
+
 def _sync_site() -> str:
     """First non-ops engine frame above the fetch — the call-site tag
     every sync-charging host read carries into the trace layer (the
@@ -391,7 +396,7 @@ def _sync_site() -> str:
     f = sys._getframe(1)
     while f is not None:
         fn = f.f_code.co_filename
-        if "nds_tpu" in fn and not fn.endswith("ops.py"):
+        if "nds_tpu" in fn and not fn.endswith(_SYNC_SITE_SKIP):
             return (f"{os.path.basename(fn)}:{f.f_lineno}:"
                     f"{f.f_code.co_name}")
         f = f.f_back
@@ -425,7 +430,10 @@ def host_read(tag: str, fetch):
         return val
     s0, w0 = sync_count(), sync_wait_ns()
     a_s0, a_w0 = _trace.attributed()
-    val = fetch()
+    # on the profiler's clock too: an idle chip during this interval is
+    # the host waiting on (or about to ask for) this read
+    with _trace.annotation("sync:" + tag):
+        val = fetch()
     a_s1, a_w1 = _trace.attributed()
     own = (sync_count() - s0) - (a_s1 - a_s0)
     if own > 0:
@@ -673,6 +681,7 @@ def lazy_shrink_rows() -> int:
     return int(os.environ.get("NDS_TPU_LAZY_SHRINK_ROWS", str(1 << 20)))
 
 
+@_trace.traced("compact")
 def compact_table(table: DeviceTable, mask: jnp.ndarray,
                   shrink: bool = False) -> DeviceTable:
     """Keep rows where ``mask`` is true, as a prefix-padded table.
@@ -720,6 +729,7 @@ def resolve_table(table: DeviceTable, shrink: bool = True) -> DeviceTable:
 
 
 @jax.jit
+@_trace.scoped("gather")
 def _gather_cols_impl(idx, datas, valids):
     """One fused gather of every column (and validity mask) of a table —
     a single device dispatch where a per-column loop costs 2 x ncols
@@ -730,6 +740,7 @@ def _gather_cols_impl(idx, datas, valids):
     return outs, vouts
 
 
+@_trace.traced("gather")
 def gather_table_rows(table: DeviceTable, idx: jnp.ndarray,
                       nrows: int) -> DeviceTable:
     """Fused whole-table row gather (clip mode); logical length ``nrows``."""
@@ -854,6 +865,7 @@ def sortable_view(col: Column) -> jnp.ndarray:
 
 
 @functools.partial(jax.jit, static_argnums=(2, 3, 4))
+@_trace.scoped("sort")
 def _lexsort_impl(views, valids, descending, nulls_last, pad_key, n_valid):
     """Jit-fused multi-key sort by iterative order-preserving re-coding.
 
@@ -902,6 +914,7 @@ def _lexsort_impl(views, valids, descending, nulls_last, pad_key, n_valid):
     return jnp.argsort(combined, stable=True)
 
 
+@_trace.traced("sort")
 def lexsort_indices(cols, descending=None, nulls_last=None,
                     n_valid: int | None = None) -> jnp.ndarray:
     """Stable multi-key sort. ``cols`` primary-first; per-key descending and
@@ -944,6 +957,7 @@ _PAD_GROUP_KEY = jnp.iinfo(jnp.int64).max // 2
 
 
 @functools.partial(jax.jit, static_argnums=(2,))
+@_trace.scoped("group_ids.rep")
 def _group_rep_impl(gids, n_valid, cap):
     """First-occurrence row index of each live group, bucket-padded to
     ``cap`` (static); the pad group scatters out of range and is dropped."""
@@ -955,6 +969,7 @@ def _group_rep_impl(gids, n_valid, cap):
 
 
 @jax.jit
+@_trace.scoped("group_ids")
 def _group_ids_impl(views, valids, n_valid):
     """Jit-fused iterative dense re-coding (see :func:`group_ids`). One XLA
     program per (arity, null pattern, bucket); returns per-row dense group
@@ -995,6 +1010,7 @@ def group_pack_min() -> int:
 
 
 @jax.jit
+@_trace.scoped("group_ids.key_ranges")
 def _int_key_ranges(views, n_valid):
     """Fused (min, max) of every integer key view over live rows — one
     dispatch, one host transfer for the whole key set."""
@@ -1008,6 +1024,7 @@ def _int_key_ranges(views, n_valid):
 
 
 @functools.partial(jax.jit, static_argnums=(3,))
+@_trace.scoped("group_ids.packed")
 def _group_ids_packed(views, valids, offsets, widths, n_valid):
     """Single-sort grouping: every key's offset code (null flag folded)
     packs into one int64, so ONE :func:`_dense_codes` sort replaces the
@@ -1069,6 +1086,7 @@ def _packed_group_plan(key_cols, views, n_valid):
     return tuple(offsets), tuple(widths)
 
 
+@_trace.traced("group_ids")
 def group_ids(key_cols, n_valid: int | None = None):
     """Grouping by iterative dense re-coding.
 
@@ -1127,12 +1145,14 @@ _I64_MAX = jnp.iinfo(jnp.int64).max
 
 
 @functools.partial(jax.jit, static_argnums=(2,))
+@_trace.scoped("agg.count")
 def _agg_count_impl(valid, gids, ngroups):
     ones = (jnp.ones(gids.shape[0], dtype=jnp.int64) if valid is None
             else valid.astype(jnp.int64))
     return jax.ops.segment_sum(ones, gids, num_segments=ngroups)
 
 
+@_trace.traced("agg", fn="count")
 def agg_count(col: Column | None, gids, ngroups) -> Column:
     """count(*) when col is None else count(col) (non-null). Pad rows need
     no masking here: grouping routes them to a trailing group that lands
@@ -1156,6 +1176,7 @@ def agg_count(col: Column | None, gids, ngroups) -> Column:
 
 
 @functools.partial(jax.jit, static_argnums=(3, 4))
+@_trace.scoped("agg.sum")
 def _agg_sum_impl(data, valid, gids, ngroups, as_f64):
     v = (jnp.ones(data.shape[0], dtype=bool) if valid is None else valid)
     d = jnp.where(v, data, 0)
@@ -1165,6 +1186,7 @@ def _agg_sum_impl(data, valid, gids, ngroups, as_f64):
     return out, cnt > 0
 
 
+@_trace.traced("agg", fn="sum")
 def agg_sum(col: Column, gids, ngroups) -> Column:
     col = plain_col(col)           # sums need logical values (fused decode)
     if col.kind == "f64":
@@ -1199,6 +1221,7 @@ def agg_sum(col: Column, gids, ngroups) -> Column:
 
 
 @functools.partial(jax.jit, static_argnums=(3, 4))
+@_trace.scoped("agg.min")
 def _agg_min_impl(view, valid, gids, ngroups, is_max):
     v = (jnp.ones(view.shape[0], dtype=bool) if valid is None else valid)
     if view.dtype == jnp.float64:
@@ -1214,6 +1237,7 @@ def _agg_min_impl(view, valid, gids, ngroups, is_max):
     return out, cnt > 0
 
 
+@_trace.traced("agg", fn="minmax")
 def agg_min(col: Column, gids, ngroups, is_max=False) -> Column:
     if col.kind == "f64":
         from nds_tpu.engine.kernels import pallas_active, \
@@ -1247,6 +1271,7 @@ def agg_min(col: Column, gids, ngroups, is_max=False) -> Column:
 
 
 @functools.partial(jax.jit, static_argnums=(3,))
+@_trace.scoped("agg.avg")
 def _agg_avg_impl(data, valid, gids, ngroups):
     v = (jnp.ones(data.shape[0], dtype=bool) if valid is None else valid)
     d = jnp.where(v, data, 0.0)
@@ -1255,6 +1280,7 @@ def _agg_avg_impl(data, valid, gids, ngroups):
     return jnp.where(c > 0, s / jnp.maximum(c, 1.0), 0.0), c > 0
 
 
+@_trace.traced("agg", fn="avg")
 def agg_avg(col: Column, gids, ngroups) -> Column:
     col = plain_col(col)
     if is_dec(col.kind):
@@ -1293,6 +1319,7 @@ def agg_avg(col: Column, gids, ngroups) -> Column:
 
 
 @functools.partial(jax.jit, static_argnums=(3,))
+@_trace.scoped("agg.stddev")
 def _agg_stddev_impl(data, valid, gids, ngroups):
     v = (jnp.ones(data.shape[0], dtype=bool) if valid is None else valid)
     d = jnp.where(v, data, 0.0)
@@ -1304,6 +1331,7 @@ def _agg_stddev_impl(data, valid, gids, ngroups):
     return jnp.sqrt(jnp.maximum(var, 0.0)), c > 1
 
 
+@_trace.traced("agg", fn="stddev_samp")
 def agg_stddev_samp(col: Column, gids, ngroups) -> Column:
     col = plain_col(col)
     data = col.data.astype(jnp.float64)
@@ -1318,6 +1346,7 @@ def agg_stddev_samp(col: Column, gids, ngroups) -> Column:
 # ---------------------------------------------------------------------------
 
 
+@_trace.traced("filter")
 def filter_table(table: DeviceTable, predicate: Column) -> DeviceTable:
     """Keep rows where the predicate is true (SQL: null counts as false)."""
     mask = predicate.data.astype(bool)
@@ -1342,6 +1371,7 @@ def _mix64(x: jnp.ndarray) -> jnp.ndarray:
 
 
 @functools.partial(jax.jit, static_argnums=(2, 3))
+@_trace.scoped("join.key_hash")
 def _key_hash_impl(views, valids, side_salt: int, null_safe: bool, n_valid,
                    excluded=None):
     """64-bit composite hash of prepared key views (see :func:`_hash_views`).
@@ -1509,6 +1539,7 @@ def _probe_candidates(left_keys, right_keys, null_safe=False,
     return counts, lo, order, total
 
 
+@_trace.traced("join")
 def join_indices(left_keys, right_keys, how: str = "inner",
                  null_safe: bool = False,
                  n_left: int | None = None, n_right: int | None = None,
@@ -1599,6 +1630,7 @@ def join_indices(left_keys, right_keys, how: str = "inner",
 
 
 @jax.jit
+@_trace.scoped("semi_join")
 def _semi_sorted_impl(lv, lvalid, rv, rvalid, n_left, n_right):
     """Sort-based existence probe on directly comparable key views: dead
     right rows take the sentinel (never exposing their value), live rows
@@ -1621,6 +1653,7 @@ def _semi_sorted_impl(lv, lvalid, rv, rvalid, n_left, n_right):
     return hit & ok_l
 
 
+@_trace.traced("semi_join")
 def semi_join_mask(left_keys, right_keys, negate: bool = False,
                    null_safe: bool = False,
                    n_left: int | None = None,
@@ -1663,6 +1696,7 @@ _PK_SENTINEL = jnp.iinfo(jnp.int64).max
 
 
 @jax.jit
+@_trace.scoped("pk_gather")
 def _pk_gather_impl(fkey, fvalid, dkey, dvalid, n_fact, n_dim,
                     f_excl, d_excl):
     """Exact merge-probe of fact keys against a UNIQUE dimension key.
@@ -1745,6 +1779,7 @@ def _dense_dim_info(dim_key: Column, n_dim: int):
 
 
 @jax.jit
+@_trace.scoped("pk_gather.dense")
 def _pk_gather_dense_impl(fkey, fvalid, dkey, dvalid, pos_map, base,
                           n_fact, n_dim, f_excl, d_excl):
     """Dense-range merge probe: position-map gather instead of sort +
@@ -1773,6 +1808,7 @@ def _pk_gather_dense_impl(fkey, fvalid, dkey, dvalid, pos_map, base,
     return r_idx, hit & ok_f
 
 
+@_trace.traced("pk_gather")
 def pk_gather_join(fact_key: Column, dim_key: Column,
                    n_fact: int, n_dim: int, f_excl=None, d_excl=None):
     """Planner-facing wrapper of :func:`_pk_gather_impl`: prepares
@@ -1802,6 +1838,7 @@ _dim_span_cache: dict = {}
 
 
 @jax.jit
+@_trace.scoped("pk_gather.pack_keys")
 def _pack_keys_impl(views, valids, offsets, widths, spans):
     """Pack offset key codes into one int64, with a combined validity
     (per-key nulls AND in-range — a fact key outside the dim's span can
@@ -1819,6 +1856,7 @@ def _pack_keys_impl(views, valids, offsets, widths, spans):
     return packed, ok
 
 
+@_trace.traced("pk_gather")
 def pk_gather_join_multi(fact_keys, dim_keys, n_fact: int, n_dim: int,
                          f_excl=None, d_excl=None):
     """Composite-key merge probe against a UNIQUE key set (the fact/returns
@@ -1908,6 +1946,7 @@ def stream_fanout() -> int:
 
 
 @functools.partial(jax.jit, static_argnames=("cand",))
+@_trace.scoped("join.span_pairs")
 def _span_pair_indices(counts, lo, order, s, e, cand):
     """Candidate pair indices restricted to probe rows [s, e); padded to the
     static capacity ``cand`` (span boundaries are dynamic, so every span
@@ -2016,6 +2055,7 @@ def _exchange_inner_join(left, right, left_keys, right_keys, mesh,
     return matched
 
 
+@_trace.traced("join")
 def join_tables(left: DeviceTable, right: DeviceTable, left_on, right_on,
                 how: str = "inner", l_excl=None, r_excl=None,
                 residual_fn=None) -> DeviceTable:
@@ -2138,6 +2178,7 @@ def _concat_valids(cols):
 
 
 @jax.jit
+@_trace.scoped("concat")
 def _concat_cols_impl(parts_datas, parts_valids, part_nrows):
     """Fused concatenation of every column of a UNION ALL (plus the live
     mask) in one device dispatch. ``parts_valids`` entries are per-column
@@ -2158,6 +2199,7 @@ def _concat_cols_impl(parts_datas, parts_valids, part_nrows):
     return datas, tuple(valids), live
 
 
+@_trace.traced("concat")
 def concat_tables(tables) -> DeviceTable:
     """UNION ALL. Physical concatenation interleaves each part's pad rows, so
     the result is re-compacted back to prefix-padded form; the logical counts

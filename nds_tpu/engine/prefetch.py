@@ -49,6 +49,21 @@ shutdown path under real threads. Slice + encode + ``device_put`` are
 all sync-free by construction (numpy work plus an async upload), which
 is why the whole ingest step can leave the driver thread at all.
 
+What the worker spends its time on is measured all the same, by the ring
+itself: it times three stages per chunk — ``source`` (the iterator's
+``next``: slice + encode), ``prepare`` (flatten + ``device_put``) and
+``backpressure`` (the blocked ``put``) — parks ``(stage, ts_ns, dur_ns,
+chunk)`` on the ring instance under the lock that guards the worker's
+fault events, and the DRIVER re-records them at its next fetch / at
+``close()`` as ``prefetch.source`` / ``prefetch.prepare`` /
+``prefetch.backpressure`` spans under the scan's ``stream`` span
+(``obs.record_interval``, marked ``thread="worker"``): the
+``_drain_worker_faults`` pattern, so the thread-scoped rings stand and
+nothing lands unattributed. On the profiler's clock the worker opens
+``nds:prefetch.*`` annotations live on its own thread (an annotation is
+not a span: no ring, no counters). The inline pump records the same
+stages as ordinary spans on the driver.
+
 The driver-side fetch (:meth:`ChunkRing.next_chunk`) accumulates the
 time the driver spent BLOCKED waiting on the ring (``stall_ns``) — the
 number ``StreamEvent.prefetch_stall_ms`` surfaces per scan and
@@ -66,6 +81,7 @@ import threading
 import time
 
 from nds_tpu.engine import faults as _F
+from nds_tpu.obs import trace as _obs
 
 # sentinel kinds riding the queue (payloads are (kind, value) pairs)
 _ITEM = "item"
@@ -106,22 +122,29 @@ class _InlineRing:
     ``stall_ns`` then measures the inline host fetch (slice + encode +
     upload) so the differential against a live ring is observable."""
 
-    def __init__(self, it, prepare=None):
+    def __init__(self, it, prepare=None, start=0):
         self._it = iter(it)
         self._prepare = prepare
+        self._chunk = int(start)         # index of the next chunk fetched
         self.stall_ns = 0
 
     def next_chunk(self):
         t0 = time.perf_counter_ns()
         try:
-            item = next(self._it, None)
-            if item is None:
-                return None
+            # the worker's stages, on the driver (ordinary spans here)
+            with _obs.span("prefetch.source", chunk=self._chunk) as sp:
+                item = next(self._it, None)
+                if item is None:
+                    sp.drop()            # end of stream: no chunk, no span
+                    return None
             # same bounded-retry policy as the threaded worker (the
             # ``prefetch`` transient seam), on the driver thread — the
             # depth-0 pump stays bit-for-bit except under a real fault
-            return _F.with_retry(
-                "prefetch", lambda: _prepare_guarded(self._prepare, item))
+            with _obs.span("prefetch.prepare", chunk=self._chunk):
+                self._chunk += 1
+                return _F.with_retry(
+                    "prefetch",
+                    lambda: _prepare_guarded(self._prepare, item))
         finally:
             self.stall_ns += time.perf_counter_ns() - t0
 
@@ -143,7 +166,8 @@ class ChunkRing:
     """Bounded, ordered, single-worker prefetch ring over one chunk
     iterator. See the module docstring for the full contract."""
 
-    def __init__(self, it, prepare=None, depth=2, name="nds-prefetch"):
+    def __init__(self, it, prepare=None, depth=2, name="nds-prefetch",
+                 start=0):
         self._q: queue.Queue = queue.Queue(maxsize=max(int(depth), 1))
         self._stop = threading.Event()
         self._exhausted = False
@@ -155,8 +179,14 @@ class ChunkRing:
         # lock (the conc-audit classification)
         self._faults: list = []
         self._faults_lock = threading.Lock()
+        # worker-side stage timings, parked the same way (same lock) and
+        # re-recorded by the driver under the scan's "stream" span — read
+        # here, on the driver thread that builds the ring
+        self._stages: list = []
+        self._span_parent, self._span_qid = _obs.enclosing("stream")
         self._thread = threading.Thread(
-            target=self._work, args=(iter(it), prepare), daemon=True,
+            target=self._work, args=(iter(it), prepare, int(start)),
+            daemon=True,
             name=name)
         self._thread.start()
 
@@ -187,9 +217,37 @@ class ChunkRing:
             _F.record_fault_event(seam, action, attempt=attempt,
                                   detail=detail)
 
-    def _work(self, it, prepare) -> None:
+    def _note(self, name: str):
+        """Live ``nds:<name>`` profiler annotation on the worker thread,
+        naming the scan's ``stream`` span as its parent."""
+        return _obs.annotation(name, parent=self._span_parent,
+                               qid=self._span_qid)
+
+    def _stage(self, stage: str, t0: int, chunk: int) -> None:
+        """Park one finished worker stage for the driver to re-record."""
+        dur = time.perf_counter_ns() - t0
+        with self._faults_lock:
+            self._stages.append((stage, t0, dur, chunk))
+
+    def _drain_worker_stages(self) -> None:
+        """Re-record the worker's stage timings on the DRIVER thread, as
+        spans under the scan's ``stream`` span (thread="worker")."""
+        with self._faults_lock:
+            got, self._stages[:] = list(self._stages), []
+        for (stage, ts_ns, dur_ns, chunk) in got:
+            _obs.record_interval("prefetch." + stage, ts_ns, dur_ns,
+                                 parent=self._span_parent,
+                                 qid=self._span_qid, chunk=chunk)
+
+    def _work(self, it, prepare, n) -> None:
         try:
-            for item in it:
+            while True:
+                t0 = time.perf_counter_ns()
+                with self._note("prefetch.source"):
+                    item = next(it, _DONE)
+                if item is _DONE:
+                    break
+                self._stage("source", t0, n)
                 if self._stop.is_set():
                     return
                 # bounded deterministic retry of the prepare step (the
@@ -197,12 +255,20 @@ class ChunkRing:
                 # upload fault recovers in place; exhausted or
                 # non-transient errors ride the queue and re-raise at the
                 # driver's next fetch exactly like the inline path
-                payload = _F.with_retry(
-                    "prefetch",
-                    lambda i=item: _prepare_guarded(prepare, i),
-                    record=self._sink)
-                if not self._put((_ITEM, payload)):
+                t0 = time.perf_counter_ns()
+                with self._note("prefetch.prepare"):
+                    payload = _F.with_retry(
+                        "prefetch",
+                        lambda i=item: _prepare_guarded(prepare, i),
+                        record=self._sink)
+                self._stage("prepare", t0, n)
+                t0 = time.perf_counter_ns()
+                with self._note("prefetch.backpressure"):
+                    put = self._put((_ITEM, payload))
+                self._stage("backpressure", t0, n)
+                if not put:
                     return
+                n += 1
             self._put((_DONE, None))
         except BaseException as exc:  # propagate to the driver, always
             self._put((_ERR, exc))
@@ -219,6 +285,7 @@ class ChunkRing:
         kind, value = self._q.get()
         self.stall_ns += time.perf_counter_ns() - t0
         self._drain_worker_faults()
+        self._drain_worker_stages()
         if kind is _ITEM:
             return value
         self._exhausted = True
@@ -236,7 +303,8 @@ class ChunkRing:
         """Clean shutdown (idempotent): signal the worker, drain the
         queue so a backpressure-blocked put wakes, join the thread. Any
         worker-side recovery evidence still parked is re-recorded here
-        so a fault on the FINAL chunk is never lost."""
+        so a fault on the FINAL chunk is never lost; so are the worker's
+        parked stage timings."""
         self._stop.set()
         self._exhausted = True
         while True:
@@ -246,6 +314,7 @@ class ChunkRing:
                 break
         self._thread.join(timeout=60.0)
         self._drain_worker_faults()
+        self._drain_worker_stages()
 
     def __enter__(self):
         return self
@@ -255,13 +324,15 @@ class ChunkRing:
         return False
 
 
-def chunk_ring(it, prepare=None, depth=None, name="nds-prefetch"):
+def chunk_ring(it, prepare=None, depth=None, name="nds-prefetch", start=0):
     """The ONE ring constructor the drive loops use: a :class:`ChunkRing`
     when the (build-time) depth is positive, the inline pump otherwise.
     ``prepare`` runs on the worker thread — it must never host-read or
     open a span (``host-sync-in-prefetch-worker`` enforces this
-    statically)."""
+    statically). ``start`` is the index of the first chunk ``it`` yields
+    (the compiled drive loops convert chunk 0 themselves), so the stage
+    spans carry the scan's own chunk numbers."""
     d = prefetch_depth() if depth is None else int(depth)
     if d <= 0:
-        return _InlineRing(it, prepare)
-    return ChunkRing(it, prepare, depth=d, name=name)
+        return _InlineRing(it, prepare, start=start)
+    return ChunkRing(it, prepare, depth=d, name=name, start=start)

@@ -263,6 +263,7 @@ class CompiledQuery:
         stmt, log = self.stmt, self.log
         names, kinds, dicts, valided, plen, bound = self.out_template
 
+        @_obs.scoped("replay")
         def traced(flat, operands):
             # rebuild the catalog around the traced buffers
             cat = {}

@@ -25,8 +25,12 @@ from nds_tpu.sql.planner import ExecError, Planner
 class Result:
     """A materialized query result."""
 
-    def __init__(self, table: DeviceTable):
+    def __init__(self, table: DeviceTable, qid=None):
         self.table = table
+        # the statement's trace id (nds_tpu/obs): the fetch spans below run
+        # after Session.sql returned, outside the statement's span, and
+        # carry it so one statement stays one tree
+        self.qid = qid
 
     @property
     def num_rows(self) -> int:
@@ -40,15 +44,19 @@ class Result:
     def to_arrow(self) -> pa.Table:
         # the device->host result fetch: the "materialize" phase of the
         # query trace (collect() and the write path both land here)
-        with _obs.span("materialize"):
+        with _obs.span("materialize", qid=self.qid):
             return self.table.to_arrow()
 
     def collect(self):
         """Device -> host gather; returns list of row tuples (the reference's
         df.collect() contract; ref: nds/nds_power.py:125-135)."""
         arrow = self.to_arrow()
-        cols = [arrow.column(i).to_pylist() for i in range(arrow.num_columns)]
-        return list(zip(*cols)) if cols else []
+        # host-only row building: its own span so the call is covered end
+        # to end (a wide answer spends more here than in the fetch)
+        with _obs.span("collect", qid=self.qid):
+            cols = [arrow.column(i).to_pylist()
+                    for i in range(arrow.num_columns)]
+            return list(zip(*cols)) if cols else []
 
     def write(self, path: str, fmt: str = "parquet"):
         from nds_tpu.io.columnar import write_table
@@ -412,7 +420,17 @@ class Session:
         # scope this thread's trace ring (mirrors the thread-scoped
         # listener): a query-executing thread drains only its own spans
         _obs.attach()
-        stmt = parse(text)
+        # the root of the statement's span tree: parse, dispatch and the
+        # deferred checks all sit under it, and it draws the statement id
+        # (qid) every span below inherits and the Result keeps
+        with _obs.span(_obs.STATEMENT) as root:
+            out = self._sql_statement(text)
+        out.qid = root.qid
+        return out
+
+    def _sql_statement(self, text: str) -> Result:
+        with _obs.span("parse"):
+            stmt = parse(text)
         planner = Planner(self.catalog, base_tables=self.base_tables)
         # roofline accounting: bytes of every catalog table the statement
         # binds (read by the Power Run's per-query summaries)

@@ -563,6 +563,7 @@ class StreamPipeline:
 
         body_plen = self.body_plen
 
+        @_obs.scoped("stream.chunk")
         def traced(chunk_flat, n_dev, parts_flat, ops_flat, acc,
                    resid_flat, pids=None, part_id=None, live=None):
             acc_datas, acc_valids, acc_n, acc_ovf, acc_outer = acc
@@ -679,6 +680,7 @@ class StreamPipeline:
                 P = n_partitions
 
                 if scan_spec is not None:
+                    @_obs.scoped("stream.kernel")
                     def scanpid_fn(chunk_flat, n_dev, hist):
                         # ONE fused VMEM pass: predicates + partition
                         # hash; the histogram keeps its pre-filter
@@ -698,6 +700,7 @@ class StreamPipeline:
                                              donate_argnums=(2,))
                     return self
 
+                @_obs.scoped("stream.partition")
                 def pid_fn(chunk_flat, n_dev, hist):
                     h = jnp.full((chunk_cap,), 2166136261, dtype=jnp.uint32)
                     for s in key_slots:
@@ -713,6 +716,7 @@ class StreamPipeline:
                 # no host syncs anywhere in it
                 self._pid_jit = jax.jit(pid_fn, donate_argnums=(2,))
             elif scan_spec is not None:
+                @_obs.scoped("stream.kernel")
                 def scan_fn(chunk_flat, n_dev):
                     mask, _h = _K.fused_chunk_scan(chunk_flat, n_dev,
                                                    scan_spec, interp)
@@ -760,6 +764,7 @@ class StreamPipeline:
             P = n_partitions
 
             if scan_spec is not None:
+                @_obs.scoped("stream.kernel")
                 def scanpid_fn(chunk_flat, n_dev, hist):
                     s = jax.lax.axis_index(axis).astype(jnp.int64)
                     n_local = jnp.clip(n_dev - s * shard_plen, 0,
@@ -781,6 +786,7 @@ class StreamPipeline:
                 self._reduce_jit = self._make_reduce()
                 return self
 
+            @_obs.scoped("stream.partition")
             def pid_fn(chunk_flat, n_dev, hist):
                 s = jax.lax.axis_index(axis).astype(jnp.int64)
                 n_local = jnp.clip(n_dev - s * shard_plen, 0, shard_plen)
@@ -798,6 +804,7 @@ class StreamPipeline:
                                       (row, rep, row), (row, row))
             self._pid_jit = jax.jit(sm_pid, donate_argnums=(2,))
         elif scan_spec is not None:
+            @_obs.scoped("stream.kernel")
             def scan_fn(chunk_flat, n_dev):
                 s = jax.lax.axis_index(axis).astype(jnp.int64)
                 n_local = jnp.clip(n_dev - s * shard_plen, 0, shard_plen)
@@ -839,6 +846,7 @@ class StreamPipeline:
         scan_spec = self.scan_spec
         interp = _K._pallas_mode() == "interpret"
 
+        @_obs.scoped("stream.exchange")
         def exch_body(chunk_flat, n_dev, hist, ovf):
             s = jax.lax.axis_index(axis).astype(jnp.int64)
             n_local = jnp.clip(n_dev - s * shard_plen, 0, shard_plen)
@@ -918,6 +926,7 @@ class StreamPipeline:
         build_meta = [(self.part_specs[s][1], self.part_specs[s][2])
                       for s in self.build_slots]
 
+        @_obs.scoped("stream.reduce")
         def body(ns, flags, hist, *bitmaps):
             counts = all_gather_counted(ns, axis, tiled=True)     # (S, P)
             ovf = psum_counted(flags.astype(jnp.int32), axis)[0]  # (P,)
@@ -1081,15 +1090,18 @@ class StreamPipeline:
         # (NDS_TPU_PREFETCH_DEPTH=0) degrades to the inline pump, bit
         # for bit the old drive loop. The first chunk was already
         # converted by the record phase, so it prepares inline.
-        ring = _PF.chunk_ring(chunks, prepare=self._prepare_chunk)
+        ring = _PF.chunk_ring(chunks, prepare=self._prepare_chunk,
+                              start=1)
         n_chunks = 0
         h2d = 0
         try:
             # the first chunk prepares INLINE (the record phase already
             # converted it): same bounded-retry policy as the ring's
             # worker, on the driver (the device-put transient seam)
-            cur = _F.with_retry(
-                "device-put", lambda: self._prepare_chunk(first_chunk))
+            with _obs.span("prefetch.prepare", chunk=0):
+                cur = _F.with_retry(
+                    "device-put",
+                    lambda: self._prepare_chunk(first_chunk))
             while cur is not None:
                 flat, n_dev, nb = cur
                 # actual host->device prefetch bytes (buffer metadata,
@@ -1185,12 +1197,15 @@ class StreamPipeline:
         accs = [self.init_acc() for _ in range(P)]
         hist = jnp.zeros(P, dtype=jnp.int64)
         pid_consts = [jnp.asarray(p, dtype=jnp.int32) for p in range(P)]
-        ring = _PF.chunk_ring(chunks, prepare=self._prepare_chunk)
+        ring = _PF.chunk_ring(chunks, prepare=self._prepare_chunk,
+                              start=1)
         n_chunks = 0
         h2d = 0
         try:
-            cur = _F.with_retry(
-                "device-put", lambda: self._prepare_chunk(first_chunk))
+            with _obs.span("prefetch.prepare", chunk=0):
+                cur = _F.with_retry(
+                    "device-put",
+                    lambda: self._prepare_chunk(first_chunk))
             while cur is not None:
                 flat, n_dev, nb = cur
                 h2d += nb
@@ -1316,12 +1331,15 @@ def _run_sharded(pipe, chunks, first_chunk, parts_flat, resid_flat=(),
     # its OWN device (row-sharded device_put inside _prepare_chunk_
     # sharded), so the h2d bandwidth scales with the mesh instead of
     # funneling through one inline upload on the driver thread
-    ring = _PF.chunk_ring(chunks, prepare=pipe._prepare_chunk_sharded)
+    ring = _PF.chunk_ring(chunks, prepare=pipe._prepare_chunk_sharded,
+                          start=1)
     n_chunks = 0
     h2d = 0
     try:
-        cur = _F.with_retry(
-            "device-put", lambda: pipe._prepare_chunk_sharded(first_chunk))
+        with _obs.span("prefetch.prepare", chunk=0):
+            cur = _F.with_retry(
+                "device-put",
+                lambda: pipe._prepare_chunk_sharded(first_chunk))
         while cur is not None:
             flat, n_dev, nb = cur
             h2d += nb
@@ -1745,7 +1763,10 @@ def stream_execute(planner, parts, keep, join_preds, where_conjuncts,
     masked_sources[keep] = None
 
     chunk_iter = chunked.padded_chunks()
-    first = next(chunk_iter)
+    # chunk 0 is sliced and encoded here, on the driver (the ring takes
+    # over from chunk 1): the same stage name the ring's worker reports
+    with _obs.span("prefetch.source", chunk=0):
+        first = next(chunk_iter)
     chunk_spec = _chunk_signature(first, alias)
     chunk_cap = chunked.chunk_cap
     n_chunks = chunked.num_chunks()

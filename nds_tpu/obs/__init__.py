@@ -13,10 +13,19 @@ pays; ``tests/test_obs.py`` proves sync-count parity traced vs untraced).
 * :mod:`nds_tpu.obs.trace` — nestable spans with sync/wait/compile
   counters bridged from :mod:`nds_tpu.engine.ops`, ring-buffer bounded
   and thread-scoped with an explicit drain (the
-  ``drain_stream_events`` discipline).
+  ``drain_stream_events`` discipline). One statement is one tree
+  (``sid`` / ``parent`` / ``qid`` on every record: ``statement`` ->
+  ``parse`` / ``plan`` -> ``op.*`` / ``stream*`` / ``replay.*`` ->
+  ``sync:*``); every live span is also a ``jax.profiler``
+  ``TraceAnnotation`` (``nds:<name>``), and ``scoped`` / ``op`` put the
+  device scope ``nds.<primitive>`` on the jitted primitives.
 * :mod:`nds_tpu.obs.export` — Chrome ``trace_event`` export
   (``chrome://tracing`` / Perfetto) and the per-query rollup dict the
-  drivers merge into their JSON summaries.
+  drivers merge into their JSON summaries (per phase ``ms`` / ``count``
+  / ``syncs`` / ``selfMs`` / ``syncWaitMs`` / ``compileMs`` /
+  ``rootMs``).
+* :mod:`nds_tpu.obs.evidence` — the counter block a driver reads around
+  one statement (``begin()`` / ``end()``), once for all drivers.
 * :mod:`nds_tpu.obs.ledger` — the campaign evidence ledger: the
   schema-versioned, flush-per-query, append-only JSONL record both
   drivers write and every post-hoc tool (``tools/bench_compare.py``,
@@ -39,5 +48,5 @@ from nds_tpu.obs.metrics import (METRICS_VERSION, Registry,  # noqa: F401
                                  quantile_from_buckets)
 from nds_tpu.obs.metrics import default as default_registry  # noqa: F401
 from nds_tpu.obs.trace import (NULL_SPAN, SpanRecord, SyncSite,  # noqa: F401
-                               annotate, attach, drain_spans, on,
-                               set_enabled, span, unattributed)
+                               annotate, attach, drain_spans, on, op,
+                               scoped, set_enabled, span, unattributed)

@@ -14,7 +14,7 @@ from __future__ import annotations
 import json
 from collections import Counter
 
-from nds_tpu.obs.trace import SpanRecord, SyncSite
+from nds_tpu.obs.trace import STATEMENT, SpanRecord, SyncSite
 
 
 def to_chrome(records, query: str = "", pid: int = 0,
@@ -29,19 +29,25 @@ def to_chrome(records, query: str = "", pid: int = 0,
         if isinstance(r, SpanRecord):
             args = {"syncs": r.syncs,
                     "syncWaitMs": round(r.sync_wait_ns / 1e6, 3),
-                    "compileMs": round(r.compile_ns / 1e6, 3)}
+                    "compileMs": round(r.compile_ns / 1e6, 3),
+                    "sid": r.sid, "parent": r.parent, "qid": r.qid}
             args.update(r.attrs)
+            # a re-recorded ring-worker stage ran beside the driver: its
+            # own row in the viewer
             events.append({
                 "name": r.name, "cat": "query", "ph": "X",
                 "ts": r.ts_ns / 1e3, "dur": r.dur_ns / 1e3,
-                "pid": pid, "tid": tid, "args": args})
+                "pid": pid,
+                "tid": tid + 1 if r.thread == "worker" else tid,
+                "args": args})
         elif isinstance(r, SyncSite):
             events.append({
                 "name": f"sync:{r.tag}", "cat": "sync", "ph": "X",
                 "ts": r.ts_ns / 1e3 - r.wait_ns / 1e3,
                 "dur": max(r.wait_ns / 1e3, 1.0),
                 "pid": pid, "tid": tid,
-                "args": {"site": r.site, "syncs": r.syncs}})
+                "args": {"site": r.site, "syncs": r.syncs, "sid": r.sid,
+                         "parent": r.parent, "qid": r.qid}})
     return {"traceEvents": events,
             "displayTimeUnit": "ms",
             "nds": {"query": query,
@@ -58,22 +64,69 @@ def write_chrome_trace(path: str, records, query: str = "",
                   separators=(",", ":"))
 
 
+# the spans that dispatch a streamed scan's chunk program: the first of
+# them (chunk 0) ends a statement's lead-in
+DISPATCH_SPANS = ("stream.kernel", "stream.compile", "stream.drive")
+
+
+def _lead_in_ns(spans) -> int:
+    """Per statement (``qid``): from the ``statement`` span's start to
+    the start of the first span that dispatches chunk 0 of the
+    statement's first streamed scan; summed over the statements in
+    ``spans``, 0 where none streamed."""
+    starts = {r.qid: r.ts_ns for r in spans if r.name == STATEMENT}
+    first: dict = {}
+    for r in spans:
+        if r.name in DISPATCH_SPANS and r.attrs.get("chunk") == 0 \
+                and r.qid in starts:
+            first[r.qid] = min(first.get(r.qid, r.ts_ns), r.ts_ns)
+    return sum(t - starts[q] for q, t in first.items())
+
+
 def rollup(records, top_sites: int = 5) -> dict:
     """Per-query aggregate the drivers merge into their JSON summaries:
-    per-phase totals (ms/count/syncs, by span name), the top sync-charging
-    host-read sites, and any eager-fallback streamed scans with their
-    reason — the phase-attribution slice of the full trace."""
+    per-phase totals by span name, the top sync-charging host-read sites,
+    and any eager-fallback streamed scans with their reason — the
+    phase-attribution slice of the full trace.
+
+    Per phase: ``ms`` / ``count`` / ``syncs`` (inclusive, children
+    counted), ``selfMs`` (duration minus what the direct children OF THE
+    SAME THREAD cover, from ``parent``; a re-recorded worker stage ran
+    beside the driver and is taken out of nobody), ``syncWaitMs`` /
+    ``compileMs`` (the same self share of the span's counter deltas) and
+    ``rootMs`` (durations of the phase's parentless spans: what the tree
+    covers of a call's wall, by no span's name). ``phases["stream"]``
+    also carries ``leadInMs`` (:func:`_lead_in_ns`)."""
     phases: dict = {}
     sites: Counter = Counter()
     site_tag: dict = {}
     fallbacks = []
+    spans = [r for r in records if isinstance(r, SpanRecord)]
+    # what each span's same-thread direct children cover
+    child: dict = {}
+    for r in spans:
+        if r.parent is not None and r.thread == "driver":
+            c = child.setdefault(r.parent, [0, 0, 0])
+            c[0] += r.dur_ns
+            c[1] += r.sync_wait_ns
+            c[2] += r.compile_ns
     for r in records:
         if isinstance(r, SpanRecord):
-            p = phases.setdefault(r.name, {"ms": 0.0, "count": 0,
-                                           "syncs": 0})
+            p = phases.setdefault(r.name, {
+                "ms": 0.0, "count": 0, "syncs": 0, "selfMs": 0.0,
+                "syncWaitMs": 0.0, "compileMs": 0.0, "rootMs": 0.0})
             p["ms"] = round(p["ms"] + r.dur_ns / 1e6, 3)
             p["count"] += 1
             p["syncs"] += r.syncs
+            dur, wait, comp = child.get(r.sid, (0, 0, 0))
+            p["selfMs"] = round(
+                p["selfMs"] + max(r.dur_ns - dur, 0) / 1e6, 3)
+            p["syncWaitMs"] = round(
+                p["syncWaitMs"] + max(r.sync_wait_ns - wait, 0) / 1e6, 3)
+            p["compileMs"] = round(
+                p["compileMs"] + max(r.compile_ns - comp, 0) / 1e6, 3)
+            if r.parent is None:
+                p["rootMs"] = round(p["rootMs"] + r.dur_ns / 1e6, 3)
             if r.name == "stream" and r.attrs.get("path") == "eager":
                 fallbacks.append({
                     "table": r.attrs.get("table", "?"),
@@ -82,6 +135,8 @@ def rollup(records, top_sites: int = 5) -> dict:
         elif isinstance(r, SyncSite):
             sites[r.site] += r.syncs
             site_tag.setdefault(r.site, r.tag)
+    if "stream" in phases:
+        phases["stream"]["leadInMs"] = round(_lead_in_ns(spans) / 1e6, 3)
     out = {"phases": phases,
            "syncSites": [{"site": s, "tag": site_tag[s], "syncs": n}
                          for s, n in sites.most_common(top_sites)]}

@@ -10,6 +10,19 @@ events (:class:`SyncSite`) are emitted by ``ops.host_read`` itself when a
 fetch actually charged syncs, carrying the first-class call-site tag that
 ``tools/sync_profile.py`` used to recover by monkeypatching.
 
+One statement is one tree. Every record carries ``sid`` (a per-process
+id), ``parent`` (the ``sid`` of the innermost span open on the thread at
+enter) and ``qid`` (the statement's id: set by the root ``statement``
+span, inherited by everything under it, kept by the ``Result`` so the
+``materialize`` / ``collect`` spans that run after ``Session.sql``
+returned carry it too). Readers take self time from ``parent``
+(:func:`nds_tpu.obs.export.rollup`), never from interval containment.
+
+Every live span is also a ``jax.profiler.TraceAnnotation`` named
+``nds:<span name>`` (stats ``sid`` / ``parent`` / ``qid``): with a profile being
+taken the program's tree lies in the host plane on the device planes'
+clock; with none a ``TraceMe`` is a flag test.
+
 Scoping mirrors :class:`nds_tpu.listener.Manager`: records land in the
 ring of the thread that produced them (concurrent Throughput streams each
 drain only their own), and a span finished on a thread that never
@@ -29,14 +42,20 @@ Hazard guards:
 
 from __future__ import annotations
 
+import functools
+import itertools
 import os
 import threading
 import time
 from collections import deque
 
+import jax
+from jax.profiler import TraceAnnotation
+
 # ring capacity per thread: diagnostics, never unbounded. A >HBM scan
-# emits ~3 records per chunk, so the default keeps a full per-query
-# pipeline of ~2500 chunks; drivers drain per query. Read at ring-ATTACH
+# emits ~6 records per chunk (dispatch, stall, three ring-worker stages),
+# so the default keeps a full per-query pipeline of ~1300 chunks; drivers
+# drain per query. Read at ring-ATTACH
 # time (not import): a Throughput child that sets NDS_TPU_TRACE_RING
 # after import sizes its threads' rings from the live value.
 def _ring_max() -> int:
@@ -50,6 +69,19 @@ _enabled = os.environ.get("NDS_TPU_TRACE", "on").lower() not in (
     "off", "0", "false")
 
 _tls = threading.local()
+
+# per-process ids: span/sync-site ids and statement ids (next() on an
+# itertools.count is atomic under the GIL, so threads never share one)
+_sids = itertools.count(1)
+_qids = itertools.count(1)
+
+# the one vocabulary: span "op.join", annotation "nds:op.join", device
+# scope "nds.join"
+ANNOTATION_PREFIX = "nds:"
+SCOPE_PREFIX = "nds."
+# the root span of a statement's tree (Session.sql): entering it draws
+# the statement id everything under it inherits
+STATEMENT = "statement"
 
 # spans/sync events from threads with no attached ring (mirrors
 # Manager.unattributed: never fanned into another stream's drain)
@@ -123,15 +155,18 @@ class SyncSite:
     """One host_read fetch that charged host syncs: the first-class form
     of tools/sync_profile.py's call-site attribution."""
 
-    __slots__ = ("tag", "site", "syncs", "wait_ns", "ts_ns", "depth")
+    __slots__ = ("tag", "site", "syncs", "wait_ns", "ts_ns", "sid",
+                 "parent", "qid")
 
-    def __init__(self, tag, site, syncs, wait_ns, ts_ns, depth):
+    def __init__(self, tag, site, syncs, wait_ns, ts_ns, parent, qid):
         self.tag = tag            # host_read tag ("sync", "counts3", ...)
         self.site = site          # "file.py:lineno:function" above ops.py
         self.syncs = syncs
         self.wait_ns = wait_ns
         self.ts_ns = ts_ns
-        self.depth = depth
+        self.sid = next(_sids)
+        self.parent = parent      # sid of the innermost open span
+        self.qid = qid
 
     def __repr__(self):
         return (f"SyncSite({self.tag!r}, {self.site!r}, "
@@ -143,8 +178,10 @@ def note_sync(tag: str, syncs: int, wait_ns: int, site: str) -> None:
     only when ``syncs`` not already attributed by a nested read)."""
     _tls.attr_syncs = getattr(_tls, "attr_syncs", 0) + syncs
     _tls.attr_wait = getattr(_tls, "attr_wait", 0) + wait_ns
+    st = _stack()
+    top = st[-1] if st else None
     _emit(SyncSite(tag, site, syncs, wait_ns, time.perf_counter_ns(),
-                   len(_stack())))
+                   top.sid if top else None, top.qid if top else None))
 
 
 class SpanRecord:
@@ -153,10 +190,10 @@ class SpanRecord:
     included — it is a tree, readers subtract for self-time)."""
 
     __slots__ = ("name", "attrs", "ts_ns", "dur_ns", "syncs",
-                 "sync_wait_ns", "compile_ns", "depth", "dropped",
-                 "_s0", "_w0", "_c0")
+                 "sync_wait_ns", "compile_ns", "sid", "parent", "qid",
+                 "thread", "dropped", "_s0", "_w0", "_c0", "_note")
 
-    def __init__(self, name: str, attrs: dict):
+    def __init__(self, name: str, attrs: dict, qid=None):
         self.name = name
         self.attrs = attrs
         self.ts_ns = 0
@@ -164,7 +201,10 @@ class SpanRecord:
         self.syncs = 0
         self.sync_wait_ns = 0
         self.compile_ns = 0
-        self.depth = 0
+        self.sid = next(_sids)
+        self.parent = None        # sid of the innermost open span at enter
+        self.qid = qid            # statement id (explicit, else inherited)
+        self.thread = "driver"    # "worker": re-recorded ring-worker stage
         self.dropped = False
 
     def set(self, **kw) -> None:
@@ -182,8 +222,23 @@ class SpanRecord:
     def __enter__(self) -> "SpanRecord":
         E = _ops()
         st = _stack()
-        self.depth = len(st)
+        if st:
+            self.parent = st[-1].sid
+            if self.qid is None:
+                self.qid = st[-1].qid
+        if self.name == STATEMENT:
+            # the root of one statement's tree: a new statement id, also
+            # given to driver spans already open around the call (power's
+            # "query") so the whole drain groups under one id
+            self.qid = next(_qids)
+            for outer in st:
+                if outer.qid is None:
+                    outer.qid = self.qid
         st.append(self)
+        self._note = TraceAnnotation(ANNOTATION_PREFIX + self.name,
+                                     sid=self.sid, parent=self.parent or 0,
+                                     qid=self.qid or 0)
+        self._note.__enter__()
         self._s0 = E.sync_count()
         self._w0 = E.sync_wait_ns()
         self._c0 = E.compile_ns()
@@ -192,6 +247,7 @@ class SpanRecord:
 
     def __exit__(self, *exc) -> bool:
         self.dur_ns = time.perf_counter_ns() - self.ts_ns
+        self._note.__exit__(None, None, None)
         E = _ops()
         self.syncs = E.sync_count() - self._s0
         self.sync_wait_ns = E.sync_wait_ns() - self._w0
@@ -216,6 +272,7 @@ class _NullSpan:
     compile time, not run time)."""
 
     __slots__ = ()
+    qid = None
 
     def set(self, **kw) -> None:
         pass
@@ -233,18 +290,109 @@ class _NullSpan:
 NULL_SPAN = _NullSpan()
 
 
-def span(name: str, **attrs):
+def span(name: str, *, qid=None, **attrs):
     """Open a nestable span on the calling thread. Usage::
 
         with obs.span("stream.drive", chunk=i) as sp:
             ...
             sp.set(rows=n)
 
+    ``qid`` names the statement for a span opened outside its tree (the
+    ``Result`` of a returned ``Session.sql``); inside one it is inherited.
+
     Zero host syncs by construction: enter/exit read the host clock and
     the thread's existing sync/wait/compile counters, nothing else."""
     if not _enabled or _ops().replay_mode() == "replay":
         return NULL_SPAN
-    return SpanRecord(name, attrs)
+    return SpanRecord(name, attrs, qid)
+
+
+def op(primitive: str, **attrs):
+    """The engine-primitive boundary, for the Python wrappers of
+    ``engine/ops.py`` / ``engine/window.py`` and the planner's top-level
+    expression evaluation: span ``op.<primitive>`` when the wrapper runs
+    eagerly; under a replay re-trace (where a span is a no-op) the
+    device scope ``nds.<primitive>`` instead, so the eager operations a
+    wrapper issues between its jitted bodies carry the primitive's name
+    inside the replayed / chunk program too. Never inside a jitted body
+    (``span-in-jit``)."""
+    if _ops().replay_mode() == "replay":
+        return jax.named_scope(SCOPE_PREFIX + primitive)
+    return span("op." + primitive, **attrs)
+
+
+def traced(primitive: str, **attrs):
+    """Decorator form of :func:`op` for a wrapper that is one primitive
+    from its first line to its last."""
+    def deco(fn):
+        @functools.wraps(fn)
+        def wrapper(*a, **k):
+            with op(primitive, **attrs):
+                return fn(*a, **k)
+        return wrapper
+    return deco
+
+
+def scoped(primitive: str):
+    """Decorator for the BODY of a jitted implementation (placed under
+    the ``jax.jit`` decorator): the body runs under the device scope
+    ``nds.<primitive>`` (``jax.named_scope``), so every operation it
+    stages carries the name wherever the function is traced — its own
+    program when called eagerly, or inlined into a replayed / chunk
+    program. Trace-time only: it names operations (``op_name``
+    metadata) and adds none."""
+    def deco(fn):
+        @functools.wraps(fn)
+        def body(*a, **k):
+            with jax.named_scope(SCOPE_PREFIX + primitive):
+                return fn(*a, **k)
+        return body
+    return deco
+
+
+def annotation(name: str, parent=None, qid=None):
+    """Profiler-clock annotation ``nds:<name>`` alone: no ring record, no
+    counter read. For code that may not open a span — the prefetch
+    ring's worker thread (its stages come back as records through
+    :func:`record_interval`; it names the scan's ``stream`` span as
+    ``parent``) and the blocking fetch of ``host_read`` (child of the
+    innermost span open on the thread)."""
+    if not _enabled:
+        return NULL_SPAN
+    if parent is None:
+        st = getattr(_tls, "stack", None)
+        if st:
+            parent, qid = st[-1].sid, st[-1].qid
+    return TraceAnnotation(ANNOTATION_PREFIX + name, parent=parent or 0,
+                           qid=qid or 0)
+
+
+def enclosing(name: str):
+    """``(sid, qid)`` of the innermost OPEN span called ``name`` on this
+    thread, else of the innermost open span, else ``(None, None)``."""
+    st = getattr(_tls, "stack", None)
+    if not st:
+        return None, None
+    for s in reversed(st):
+        if s.name == name:
+            return s.sid, s.qid
+    return st[-1].sid, st[-1].qid
+
+
+def record_interval(name: str, ts_ns: int, dur_ns: int, parent=None,
+                    qid=None, **attrs) -> None:
+    """Record a finished interval another thread timed (a prefetch-ring
+    worker stage) into the CALLING thread's ring, under ``parent``: the
+    ``_drain_worker_faults`` pattern for spans. Marked
+    ``thread="worker"``: it ran beside the driver, so readers count it
+    in its own phase and never take it out of its parent's self time."""
+    if not _enabled:
+        return
+    rec = SpanRecord(name, attrs, qid)
+    rec.parent = parent
+    rec.thread = "worker"
+    rec.ts_ns, rec.dur_ns = ts_ns, dur_ns
+    _emit(rec)
 
 
 def annotate(**attrs) -> None:

@@ -198,6 +198,7 @@ def run_query_stream(input_prefix: str,
     from nds_tpu.parallel.admission import from_env as admission_from_env
     admission = admission_from_env()
 
+    from nds_tpu.obs import evidence as _obs_evidence
     from nds_tpu.obs import export as _obs_export
     from nds_tpu.obs import metrics as _obs_metrics
     from nds_tpu.obs import trace as _obs_trace
@@ -254,18 +255,23 @@ def run_query_stream(input_prefix: str,
             # analog of naming the query in the Spark UI via setJobGroup
             # (ref: nds/nds_power.py:257) plus a real profiler, which the
             # reference lacks (SURVEY.md §5.1)
+            # without the Python tracer (it records every Python call and
+            # slows the host the program shares): the program's own
+            # `nds:` annotations are the host side of the profile, read
+            # by tools/trace_report.py --profile
             import jax.profiler as _prof
-            trace_ctx = _prof.trace(os.path.join(profile_folder, query_name))
+            prof_options = _prof.ProfileOptions()
+            prof_options.python_tracer_level = 0
+            prof_options.host_tracer_level = 2
+            trace_ctx = _prof.trace(os.path.join(profile_folder, query_name),
+                                    profiler_options=prof_options)
             trace_ctx.__enter__()
         from nds_tpu.engine import ops as _ops
-        from nds_tpu.listener import drain_stream_events as _drain_stream
         _ops.enable_compile_meter()
-        _drain_stream()          # setup leftovers must not charge query 1
-        _obs_trace.drain_spans()  # same for trace records
-        syncs_before = _ops.sync_count()
-        wait_before = _ops.sync_wait_ns()
-        fetch_before = _ops.fetch_bytes()
-        compile_before = _ops.compile_ns()
+        # the statement's evidence window (nds_tpu/obs/evidence.py): set-up
+        # leftovers are cleared so they do not charge query 1, counters
+        # are read here and again after the call
+        evidence = _obs_evidence.begin()
         stats_before = device.memory_stats() or {}
         import contextlib
         slot_ctx = (admission.slot() if admission is not None
@@ -285,47 +291,41 @@ def run_query_stream(input_prefix: str,
         # rest of the wall overlaps dispatch with device compute; scanBytes
         # over wall time yields the effective scan bandwidth to hold
         # against the chip's HBM roofline
-        q_report.summary["hostSyncs"] = _ops.sync_count() - syncs_before
-        sync_ms = (_ops.sync_wait_ns() - wait_before) / 1e6
+        ev = evidence.end()
+        q_report.summary["hostSyncs"] = ev["hostSyncs"]
+        sync_ms = ev["syncWaitMs"]
         q_report.summary["syncWaitMs"] = round(sync_ms, 3)
-        q_report.summary["fetchBytes"] = _ops.fetch_bytes() - fetch_before
+        q_report.summary["fetchBytes"] = ev["fetchBytes"]
         # >HBM streamed scans (engine/stream.py): which path served each
         # ChunkedTable-bound scan — the compiled chunk pipeline or the
         # eager chunk loop — with chunk/sync counts, so a query blowing
         # the streamed sync budget names the scan (and fallback reason)
         # that charged it
-        stream_events = _drain_stream()
-        if stream_events:
-            from nds_tpu.listener import stream_event_json
-            q_report.summary["streamedScans"] = [
-                stream_event_json(e) for e in stream_events]
+        if ev["streamedScans"]:
+            q_report.summary["streamedScans"] = ev["streamedScans"]
         # fault-recovery evidence (engine/faults.py): retries, ladder
         # degradations and watchdog timeouts this query survived — the
         # reference's task-failure-listener idea applied to the
         # engine's own recovery paths, ridden into the ledger
-        from nds_tpu.engine.faults import (drain_fault_events,
-                                           fault_event_json)
-        fault_events = drain_fault_events()
+        fault_events = ev["faults"]
         if fault_events:
-            q_report.summary["faultEvents"] = [
-                fault_event_json(e) for e in fault_events]
+            q_report.summary["faultEvents"] = ev["faultEvents"]
         # per-phase trace rollup (nds_tpu/obs): where the query's wall
-        # went — plan, stream record/compile/drive, materialize — plus
-        # the top sync-charging host-read sites; the full span tree goes
-        # to --trace-dir as a Chrome trace_event file
-        trace_records = _obs_trace.drain_spans()
-        if trace_records:
-            roll = _obs_export.rollup(trace_records)
+        # went — statement, plan, op.*, stream record/compile/drive,
+        # materialize — plus the top sync-charging host-read sites; the
+        # full span tree goes to --trace-dir as a Chrome trace_event file
+        roll = ev["rollup"]
+        if ev["records"]:
             q_report.summary["trace"] = roll
             if trace_dir:
                 _obs_export.write_chrome_trace(
                     os.path.join(trace_dir, f"{query_name}.trace.json"),
-                    trace_records, query=query_name, roll=roll)
+                    ev["records"], query=query_name, roll=roll)
         # compile-vs-execute split (round-4 verdict missing #3): compileMs
         # is XLA backend compilation charged to this query's wall (zero on
         # a warm shape universe / persistent-cache hit); the remainder is
         # dispatch + device execution + host IO
-        compile_ms = (_ops.compile_ns() - compile_before) / 1e6
+        compile_ms = ev["compileMs"]
         q_report.summary["compileMs"] = round(compile_ms, 1)
         q_report.summary["execMs"] = round(max(elapsed - compile_ms, 0.0), 1)
         if admission is not None:

@@ -240,6 +240,9 @@ class Planner:
         # while a pipeline records, the residual keys the record phase
         # touched (registry hits included) — the pipeline's operand list
         self._residuals_touched: list | None = None
+        # True while an expression evaluates: only the top-level
+        # eval_expr of a tree opens the op.expr span (nds_tpu/obs)
+        self._in_expr = False
 
     # ------------------------------------------------------------------ query
 
@@ -1095,6 +1098,7 @@ class Planner:
             mask = mask & col.data.astype(bool) & col.valid_mask()
         return mask
 
+    @_obs.traced("filter")
     def _conjunct_mask(self, table: DeviceTable, conjuncts) -> jnp.ndarray:
         """Predicate mask over a plain table. Subquery-free conjunct sets
         evaluate inside ONE jitted program per (expressions, table
@@ -1209,9 +1213,14 @@ class Planner:
             encs = tuple(c.enc for c in cols)
             kinds = tuple(c.kind for c in cols)
             ev = Planner({}, base_tables=set())
+            # the fused body runs once, at jit-trace time: no op.expr
+            # span there (span-in-jit); its operations carry the device
+            # scope of what is fused instead
+            ev._in_expr = True
             meta: list = []
-            fn = jax.jit(build_impl(ev, names, kinds, dict_refs, encs,
-                                    meta))
+            fn = jax.jit(_obs.scoped(
+                "filter" if what == "predicate" else "expr")(
+                    build_impl(ev, names, kinds, dict_refs, encs, meta)))
             try:
                 out = fn(tuple(c.data for c in cols),
                          tuple(c.valid for c in cols))
@@ -2177,43 +2186,62 @@ class Planner:
             return
         contexts = {}
         for w in wins:
-            skey = (tuple(expr_key(p) for p in w.spec.partition_by),
-                    tuple((expr_key(e), d, nl) for e, d, nl in w.spec.order_by))
-            if skey not in contexts:
-                pcols = [self.eval_expr(p, ctx) for p in w.spec.partition_by]
-                ocols = [self.eval_expr(e, ctx) for e, _, _ in w.spec.order_by]
-                desc = [d for _, d, _ in w.spec.order_by]
-                nl = [n for _, _, n in w.spec.order_by]
-                contexts[skey] = WindowContext(pcols, ocols, desc, nl,
-                                               n_valid=ctx.table.nrows)
-            wc = contexts[skey]
-            fname = w.func.name
-            if fname == "row_number":
-                col = wc.row_number()
-            elif fname == "rank":
-                col = wc.rank()
-            elif fname == "dense_rank":
-                col = wc.dense_rank()
-            elif fname in ("sum", "avg", "min", "max", "count"):
-                arg = (self.eval_expr(w.func.args[0], ctx) if w.func.args
-                       else Column("i64", jnp.ones(ctx.table.plen, dtype=jnp.int64)))
-                frame = w.spec.frame
-                if frame is None and w.spec.order_by:
-                    # SQL default with ORDER BY: RANGE UNBOUNDED PRECEDING ..
-                    # CURRENT ROW (a running, not whole-partition, aggregate)
-                    frame = "range_unbounded_preceding"
-                if frame is not None and w.spec.order_by:
-                    col = wc.running_agg(arg, fname,
-                                         rows_frame=frame.startswith("rows"))
-                else:
-                    col = wc.partition_agg(arg, fname)
+            with _obs.op("window", fn=w.func.name):
+                self._eval_window(w, ctx, contexts)
+
+    def _eval_window(self, w, ctx: EvalCtx, contexts: dict) -> None:
+        """One window function; ``contexts`` shares the sort across the
+        functions of one (partition, order) spec."""
+        skey = (tuple(expr_key(p) for p in w.spec.partition_by),
+                tuple((expr_key(e), d, nl) for e, d, nl in w.spec.order_by))
+        if skey not in contexts:
+            pcols = [self.eval_expr(p, ctx) for p in w.spec.partition_by]
+            ocols = [self.eval_expr(e, ctx) for e, _, _ in w.spec.order_by]
+            desc = [d for _, d, _ in w.spec.order_by]
+            nl = [n for _, _, n in w.spec.order_by]
+            contexts[skey] = WindowContext(pcols, ocols, desc, nl,
+                                           n_valid=ctx.table.nrows)
+        wc = contexts[skey]
+        fname = w.func.name
+        if fname == "row_number":
+            col = wc.row_number()
+        elif fname == "rank":
+            col = wc.rank()
+        elif fname == "dense_rank":
+            col = wc.dense_rank()
+        elif fname in ("sum", "avg", "min", "max", "count"):
+            arg = (self.eval_expr(w.func.args[0], ctx) if w.func.args
+                   else Column("i64", jnp.ones(ctx.table.plen, dtype=jnp.int64)))
+            frame = w.spec.frame
+            if frame is None and w.spec.order_by:
+                # SQL default with ORDER BY: RANGE UNBOUNDED PRECEDING ..
+                # CURRENT ROW (a running, not whole-partition, aggregate)
+                frame = "range_unbounded_preceding"
+            if frame is not None and w.spec.order_by:
+                col = wc.running_agg(arg, fname,
+                                     rows_frame=frame.startswith("rows"))
             else:
-                raise ExecError(f"unsupported window function {fname}")
-            ctx.window_values[expr_key(w)] = col
+                col = wc.partition_agg(arg, fname)
+        else:
+            raise ExecError(f"unsupported window function {fname}")
+        ctx.window_values[expr_key(w)] = col
 
     # ----------------------------------------------------------- expressions
 
     def eval_expr(self, e, ctx: EvalCtx) -> Column:
+        """Evaluate one expression tree. The top-level call of a tree is
+        the engine-primitive boundary ``op.expr`` (the recursion and a
+        plain column lookup open nothing)."""
+        if self._in_expr or isinstance(e, A.ColumnRef):
+            return self._eval_expr(e, ctx)
+        self._in_expr = True
+        try:
+            with _obs.op("expr"):
+                return self._eval_expr(e, ctx)
+        finally:
+            self._in_expr = False
+
+    def _eval_expr(self, e, ctx: EvalCtx) -> Column:
         n = ctx.table.plen     # new columns are built at physical length
         k = expr_key(e)
         if ctx.window_values and k in ctx.window_values:

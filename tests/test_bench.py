@@ -264,7 +264,7 @@ def test_budget_enforcement_hung_child(tmp_path, monkeypatch, capsys):
     assert data.times() == {"query1": 100.0, "query3": 100.0}
     assert data.complete() and data.end["status"] == "completed"
     assert data.end["queries"] == 2 and data.end["platform"] == "tpu"
-    assert "query1" in open(tmp_path / "PERF.md").read()
+    assert "query1" in open(tmp_path / "chiprun_out" / "BENCH_PERF.md").read()
 
 
 def test_setup_timeout_circuit_breaker(monkeypatch, capsys):
@@ -363,7 +363,7 @@ def test_external_timeout_flushes_partial_geomean(tmp_path, monkeypatch,
     msg = json.loads(out.out.strip().splitlines()[-1])
     assert msg["n_queries"] == 1
     assert msg["value"] == pytest.approx(123.0)
-    perf_text = open(tmp_path / "PERF.md").read()
+    perf_text = open(tmp_path / "chiprun_out" / "BENCH_PERF.md").read()
     assert "query1" in perf_text and "platform: tpu." in perf_text
     # terminal ledger record: the kill is labeled, not inferred
     data = bench.ledger_mod().load_ledger(str(ledger_path))
@@ -487,7 +487,7 @@ def test_round_with_hang_and_sigterm_still_yields_ledger(
     assert data.queries["query2"]["status"] == "timeout"
     assert data.complete() and data.end["status"] == "aborted"
     assert data.end["reason"] == "signal" and data.end["queries"] == 2
-    perf_text = open(tmp_path / "PERF.md").read()
+    perf_text = open(tmp_path / "chiprun_out" / "BENCH_PERF.md").read()
     assert "query1" in perf_text and "query3" in perf_text
 
 
@@ -506,7 +506,7 @@ def test_write_perf_stamps_platform_and_streamed(tmp_path, monkeypatch):
         "query2": {"hostSyncs": 1, "syncWaitMs": 1.0},
     }
     bench.write_perf(times, perf, platform="tpu")
-    text = open(tmp_path / "PERF.md").read()
+    text = open(tmp_path / "chiprun_out" / "BENCH_PERF.md").read()
     assert "platform: tpu." in text
     assert "attached chip" not in text
     assert "Streamed >HBM scans: 2 (1 compiled chunk pipeline, "\

@@ -66,6 +66,13 @@ def test_tracing_adds_zero_syncs(tmp_path, monkeypatch):
 
     def run_arm(feed):
         s = make_session(np.random.default_rng(42))
+        # a DeviceCount left pending on this thread by whatever ran
+        # before (another test file on the same xdist worker) is drained
+        # — one batched transfer, one sync — by the first statement that
+        # resolves counts: it would charge the first arm alone, so the
+        # parity flipped with the worker's file order (ROADMAP Design
+        # item 12). Each arm starts from a drained thread.
+        E.resolve_counts()
         obs_trace.drain_spans()
         out = []
         for q in ab:
@@ -83,6 +90,13 @@ def test_tracing_adds_zero_syncs(tmp_path, monkeypatch):
                    status=lambda: {"syncs": E.sync_count()}, out=None)
     with hb:
         traced = run_arm(feed=True)
+    # the parity below covers the whole tree: the statement root, the
+    # engine-primitive spans, the ring worker's re-recorded stages and
+    # the sync sites (each a live TraceAnnotation too)
+    names = set(_span_names(obs_trace.drain_spans()))
+    assert {"statement", "parse", "plan", "stream", "prefetch.source",
+            "prefetch.prepare", "materialize", "collect"} <= names, names
+    assert any(n.startswith("op.") for n in names), names
     assert hb.beats > 0, "heartbeat must have fired during the arm"
     assert os.path.exists(live_file), \
         "heartbeat must have exported the live metrics snapshot"
@@ -458,7 +472,17 @@ def test_trace_report_kernel_arm_delta(tmp_path):
             {"ph": "X", "name": "stream.drive", "ts": 10 + kern_ms * 1000,
              "dur": 500, "args": {"chunk": 0}},
         ]
-        doc = {"traceEvents": events, "nds": {"query": "query9"}}
+        # the report prices phases from the file's rollup (selfMs from
+        # the spans' parent ids), not from interval containment
+        phases = {"stream": {"ms": stream_ms, "count": 1,
+                             "selfMs": stream_ms - kern_ms - 0.5,
+                             "rootMs": stream_ms},
+                  "stream.drive": {"ms": 0.5, "count": 1, "selfMs": 0.5}}
+        if kern_ms:
+            phases["stream.kernel"] = {"ms": kern_ms, "count": 1,
+                                       "selfMs": kern_ms}
+        doc = {"traceEvents": events,
+               "nds": {"query": "query9", "rollup": {"phases": phases}}}
         (tmp_path / name).write_text(json.dumps(doc))
 
     # the xla file sorts FIRST so the pallas row (with the
@@ -510,7 +534,12 @@ def test_trace_report_ledger_parity_on_byte_columns(tmp_path):
          "dur": 20_000, "args": {}},
     ]
     (tdir / "query3.trace.json").write_text(json.dumps(
-        {"traceEvents": events, "nds": {"query": "query3"}}))
+        {"traceEvents": events, "nds": {"query": "query3", "rollup": {
+            "phases": {
+                "stream": {"ms": 120.0, "count": 1, "selfMs": 100.0,
+                           "rootMs": 120.0},
+                "stream.materialize": {"ms": 20.0, "count": 1,
+                                       "selfMs": 20.0}}}}}))
 
     # the equivalent ledger record, legacy-shaped: NO derived
     # ``evidence`` field, only the per-scan streamedScans evidence
@@ -543,3 +572,286 @@ def test_trace_report_ledger_parity_on_byte_columns(tmp_path):
     assert t_row[-3] == "2.5"           # pf-stall ms
     # static columns engaged (a priced corpus name, not "-")
     assert t_row[-2] != "-" and t_row[-1] != "-"
+
+
+# ---------------------------------------------------------------------------
+# one statement, one tree: sid / parent / qid, self time, worker stages
+# ---------------------------------------------------------------------------
+
+
+def _spans(records):
+    return [r for r in records if isinstance(r, obs_trace.SpanRecord)]
+
+
+@pytest.fixture
+def two_statements():
+    """Two statements on the chunked session (a streamed star join, then
+    a streamed-fact filter), warm, drained together."""
+    queries, make_session = _synccount_fixtures()
+    s = make_session(np.random.default_rng(42))
+    for sql, _must in queries[:2]:
+        s.sql(sql).collect()                 # cold: record + compile
+    obs_trace.drain_spans()
+    obs_trace.unattributed.clear()
+    for sql, _must in queries[:2]:
+        assert s.sql(sql).collect()
+    return obs_trace.drain_spans()
+
+
+def test_two_statements_form_two_trees(two_statements):
+    """Every record carries sid / parent / qid; grouped by qid the spans
+    of a two-statement run are two trees with one ``statement`` root
+    each (the fetch spans that run after ``Session.sql`` returned are
+    parentless and carry the statement's qid)."""
+    records = two_statements
+    spans = _spans(records)
+    by_sid = {r.sid: r for r in spans}
+    assert len(by_sid) == len(spans), "sids must be unique"
+    assert not any(hasattr(r, "depth") for r in records)
+    qids = sorted({r.qid for r in records})
+    assert len(qids) == 2 and None not in qids
+    for qid in qids:
+        mine = [r for r in spans if r.qid == qid]
+        roots = [r for r in mine if r.parent is None]
+        assert [r.name for r in roots].count("statement") == 1
+        assert {r.name for r in roots} <= {"statement", "materialize",
+                                           "collect"}
+        statement = next(r for r in roots if r.name == "statement")
+        for r in mine:
+            if r.parent is None:
+                continue
+            # the chain of parents stays inside the statement and ends
+            # at its root
+            hops, cur = 0, r
+            while cur.parent is not None:
+                cur = by_sid[cur.parent]
+                assert cur.qid == qid
+                hops += 1
+                assert hops < 64
+            assert cur is statement
+        names = {r.name for r in mine}
+        assert {"parse", "plan", "stream", "materialize",
+                "collect"} <= names, names
+    # sync sites hang off the span that paid them
+    sites = [r for r in records if isinstance(r, obs_trace.SyncSite)]
+    assert sites and all(r.parent in by_sid and r.qid == by_sid[r.parent].qid
+                         for r in sites)
+    assert not obs_trace.unattributed, list(obs_trace.unattributed)
+
+
+def test_self_ms_plus_children_is_the_duration(two_statements):
+    """``selfMs`` of a span plus the durations of its direct children of
+    the same thread equals its duration, and over a drain the self times
+    of the driver's spans add up to what the roots cover."""
+    records = two_statements
+    spans = _spans(records)
+    roll = obs_export.rollup(records)["phases"]
+    for name in ("statement", "plan", "stream"):
+        parents = [r for r in spans if r.name == name]
+        sids = {r.sid for r in parents}
+        kids_ms = sum(r.dur_ns for r in spans
+                      if r.parent in sids and r.thread == "driver") / 1e6
+        assert roll[name]["selfMs"] + kids_ms == \
+            pytest.approx(roll[name]["ms"], abs=0.02), name
+        assert 0 <= roll[name]["selfMs"] <= roll[name]["ms"]
+    driver = {r.name for r in spans if r.thread == "driver"}
+    worker = {r.name for r in spans if r.thread == "worker"}
+    assert worker <= {"prefetch.source", "prefetch.prepare",
+                      "prefetch.backpressure"} and worker
+    # prefetch.* phases mix driver (chunk 0) and worker records: take
+    # the driver share from the records themselves
+    self_driver = sum(roll[n]["selfMs"] for n in driver - worker) + sum(
+        r.dur_ns / 1e6 for r in spans
+        if r.thread == "driver" and r.name in worker)
+    assert self_driver == pytest.approx(
+        sum(p["rootMs"] for p in roll.values()), abs=0.5)
+    # the self share of the blocked time adds up to the roots' total
+    assert sum(p["syncWaitMs"] for p in roll.values()) == pytest.approx(
+        sum(r.sync_wait_ns for r in spans if r.parent is None) / 1e6,
+        abs=0.05)
+    lead = roll["stream"]["leadInMs"]
+    assert 0 < lead < roll["statement"]["ms"]
+
+
+@pytest.mark.parametrize("depth", ["2", "0"], ids=["ring", "inline"])
+def test_worker_stages_come_back_under_the_stream_span(depth, monkeypatch):
+    """The ring worker's per-chunk stages are re-recorded on the driver's
+    ring with the scan's ``stream`` span as parent (ring on: marked
+    thread="worker"; depth 0: ordinary driver spans), in the statement's
+    own drain, and nothing lands in ``unattributed``."""
+    monkeypatch.setenv("NDS_TPU_PREFETCH_DEPTH", depth)
+    queries, make_session = _synccount_fixtures()
+    s = make_session(np.random.default_rng(42))
+    s.sql(queries[0][0]).collect()
+    obs_trace.drain_spans()
+    obs_trace.unattributed.clear()
+    assert s.sql(queries[0][0]).collect()
+    spans = _spans(obs_trace.drain_spans())
+    (stream,) = [r for r in spans if r.name == "stream"]
+    for stage in ("prefetch.source", "prefetch.prepare"):
+        got = [r for r in spans if r.name == stage]
+        # 10 chunks: chunk 0 on the driver, 1..9 through the ring
+        assert sorted(r.attrs["chunk"] for r in got) == list(range(10))
+        assert all(r.qid == stream.qid for r in got)
+        ring = [r for r in got if r.attrs["chunk"] > 0]
+        if depth == "0":
+            assert all(r.thread == "driver" for r in got)
+        else:
+            assert all(r.parent == stream.sid for r in got)
+            assert all(r.thread == "worker" for r in ring)
+            assert all(stream.ts_ns <= r.ts_ns and
+                       r.ts_ns + r.dur_ns <= stream.ts_ns + stream.dur_ns
+                       for r in ring)
+    back = [r for r in spans if r.name == "prefetch.backpressure"]
+    assert (len(back) == 9) == (depth != "0")
+    assert not obs_trace.unattributed, list(obs_trace.unattributed)
+
+
+def test_profile_holds_the_statement_tree(tmp_path):
+    """With a profile being taken the program's spans lie in the host
+    plane as ``nds:`` annotations (sid / parent / qid as stats), and
+    ``tools/trace_report.py --profile`` reads the capture."""
+    import jax
+    spec = importlib.util.spec_from_file_location(
+        "trace_report_prof", os.path.join(REPO, "tools", "trace_report.py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    queries, make_session = _synccount_fixtures()
+    s = make_session(np.random.default_rng(42))
+    s.sql(queries[0][0]).collect()
+    obs_trace.drain_spans()
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    options.host_tracer_level = 2
+    with jax.profiler.trace(str(tmp_path), profiler_options=options):
+        assert s.sql(queries[0][0]).collect()
+    spans = _spans(obs_trace.drain_spans())
+    (path,) = [os.path.join(d, f) for d, _dirs, files in os.walk(tmp_path)
+               for f in files if f.endswith(".xplane.pb")]
+    notes = mod.read_profile(path)["notes"]
+    names = {n["name"] for n in notes}
+    assert {"statement", "plan", "parse", "stream", "materialize",
+            "collect", "sync:stream_final"} <= names, names
+    assert any(n.startswith("op.") for n in names), names
+    # the worker's live annotations, and the ids of the ring's records
+    assert "prefetch.source" in names
+    by_sid = {r.sid: r for r in spans}
+    tagged = [n for n in notes if n["sid"]]
+    # (a dropped span, the ring's end-of-stream probe, annotates but
+    # leaves no record)
+    assert {n["name"] for n in tagged if n["sid"] not in by_sid} <= \
+        {"stream.prefetch"}
+    tagged = [n for n in tagged if n["sid"] in by_sid]
+    assert tagged and all(by_sid[n["sid"]].name == n["name"] and
+                          by_sid[n["sid"]].qid == n["qid"] and
+                          by_sid[n["sid"]].parent == n["parent"]
+                          for n in tagged)
+    lines = mod.profile_report(str(tmp_path))
+    text = "\n".join(lines)
+    assert "device time by scope" in text and "idle gaps over" in text
+    (window,) = mod.profile_statements({"notes": notes, "ops": []})
+    assert window[3] == spans[0].qid
+
+
+# ---------------------------------------------------------------------------
+# tools/trace_report.py --profile: the parts a CPU capture cannot exercise
+# ---------------------------------------------------------------------------
+
+
+def _pb(fields):
+    """Protobuf wire bytes of ``[(field number, int | bytes | str)]``."""
+    def varint(n):
+        out = bytearray()
+        while True:
+            b = n & 0x7F
+            n >>= 7
+            out.append(b | (0x80 if n else 0))
+            if not n:
+                return bytes(out)
+    out = b""
+    for no, val in fields:
+        if isinstance(val, int):
+            out += varint(no << 3) + varint(val)
+        else:
+            raw = val.encode() if isinstance(val, str) else val
+            out += varint(no << 3 | 2) + varint(len(raw)) + raw
+    return out
+
+
+def _trace_report():
+    spec = importlib.util.spec_from_file_location(
+        "trace_report_units", os.path.join(REPO, "tools", "trace_report.py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def test_profile_reader_takes_op_names_from_event_metadata(tmp_path):
+    """A TPU capture keeps an operation's ``op_name`` (stat ``tf_op``) and
+    ``program_id`` on the event's METADATA entry: the reader decodes just
+    those fields of the file, per plane, name and program."""
+    mod = _trace_report()
+    stat_names = [_pb([(1, 1), (2, _pb([(1, 1), (2, "tf_op")]))]),
+                  _pb([(1, 2), (2, _pb([(1, 2), (2, "program_id")]))]),
+                  _pb([(1, 3), (2, _pb([(1, 3), (2, "flops")]))])]
+
+    def event_meta(mid, name, op_name, program):
+        stats = [(5, _pb([(1, 3), (3, 99)])),
+                 (5, _pb([(1, 2), (3, program)]))]
+        if op_name:
+            stats.append((5, _pb([(1, 1), (5, op_name)])))
+        return _pb([(1, mid), (2, _pb([(1, mid), (2, name)] + stats))])
+    deep = ("jit(traced)/nds.stream.chunk/nds.join/jit(_key_hash_impl)/"
+            "nds.join.key_hash/xor:")
+    plane = _pb([(2, "/device:TPU:0")]
+                + [(4, event_meta(1, "%fusion.35 = u32[8]{0} fusion()",
+                                  deep, 7))]
+                + [(4, event_meta(2, "%fusion.35 = u32[8]{0} fusion()",
+                                  "jit(f)/nds.sort/sort:", 8))]
+                + [(4, event_meta(3, "%copy-done.4 = s32[8]{0} copy-done()",
+                                  None, 7))]
+                + [(5, s) for s in stat_names])
+    host = _pb([(2, "/host:CPU")])
+    path = tmp_path / "t.xplane.pb"
+    path.write_bytes(_pb([(1, plane), (1, host)]))
+    meta = mod._event_metadata(str(path))
+    assert meta == {"/device:TPU:0": {
+        "%fusion.35 = u32[8]{0} fusion()": {7: deep,
+                                            8: "jit(f)/nds.sort/sort:"}}}
+    assert mod.scope_of(deep) == ["stream.chunk", "join", "join.key_hash"]
+    assert mod.scope_of("jit(cumsum)/cumsum:") == [] == mod.scope_of(None)
+    assert mod.short_hlo(
+        "%while.4 = (u32[]{:S(2)}, s32[4194304]{0:T(1024)}) while((u32[], "
+        "s32[4194304]) %tuple), condition=%c, body=%b") == \
+        "%while.4 while (u32[], s32[4194304])"
+
+
+def test_profile_reader_self_time_and_gap_attribution():
+    """Device time by scope adds each operation's SELF time (a ``while``
+    holds its body's operations), and an idle gap's time goes to the
+    innermost ``nds:`` annotation open on the driver's thread, children
+    found by ``parent`` id; a ring-worker annotation (another thread
+    than its parent) is listed beside."""
+    mod = _trace_report()
+    ops = [("d", "%while.4", 0, 100, [], "jit__pk_gather_impl"),
+           ("d", "%fusion.35", 10, 30, ["pk_gather"], "jit__pk_gather_impl"),
+           ("d", "%fusion.35", 50, 30, ["pk_gather"], "jit__pk_gather_impl"),
+           ("d", "%fusion.1", 200, 50, ["gather"], "jit__gather_cols_impl")]
+    assert mod._self_ns(ops) == [40, 30, 30, 50]
+    main, worker = ("/host:CPU", 1), ("/host:CPU", 2)
+
+    def note(name, start, end, sid, parent, line=main):
+        return {"name": name, "start": start, "end": end, "sid": sid,
+                "parent": parent, "qid": 1, "line": line}
+    notes = [note("statement", 0, 1000, 1, None),
+             note("plan", 10, 900, 2, 1),
+             note("op.filter", 100, 300, 3, 2),
+             note("sync:counts1", 250, 290, None, 3),
+             note("stream", 400, 900, 4, 2),
+             note("prefetch.source", 420, 600, None, 4, line=worker)]
+    driver, beside = mod._host_during(notes, 200, 500)
+    assert dict(driver) == {"op.filter": 60, "sync:counts1": 40,
+                            "plan": 100, "stream": 100}
+    assert dict(beside) == {"prefetch.source": 80}
+    assert mod.profile_statements({"notes": notes, "ops": ops}) == \
+        [("qid 1", 0, 1000, 1)]

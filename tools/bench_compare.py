@@ -399,9 +399,10 @@ def inject_drift(b, threshold):
 
 
 def emit_perf(b, out_path):
-    """PERF.md as a derived artifact: render round B through bench.py's
-    own deterministic renderer (one renderer, whether the table comes
-    from a live campaign or a committed ledger)."""
+    """The campaign roofline table as a derived artifact: render round B
+    through bench.py's own deterministic renderer (one renderer, whether
+    the table comes from a live campaign or a committed ledger) into
+    ``out_path`` (empty: ``bench.bench_perf_path()``)."""
     bench = _load_by_path("_bench_for_perf", "bench.py")
     perf = {q: {k: rec[k] for k in bench.PERF_KEYS if k in rec}
             for q, rec in b["perf"].items()}
@@ -413,6 +414,8 @@ def emit_perf(b, out_path):
     scale = b["meta"].get("scale", "unknown")
     text = bench.perf_text(b["times"], perf, platform=platform,
                            scale=scale)
+    out_path = out_path or bench.bench_perf_path()
+    os.makedirs(os.path.dirname(os.path.abspath(out_path)), exist_ok=True)
     with open(out_path, "w") as f:
         f.write(text)
     return text
@@ -443,10 +446,7 @@ def record_ab(path):
     meta record so ``--audit-ab`` can rebuild the same MemModel."""
     import numpy as np
 
-    from nds_tpu.engine import ops as E
-    from nds_tpu.listener import drain_stream_events, stream_event_json
-    from nds_tpu.obs import export as obs_export
-    from nds_tpu.obs import trace as obs_trace
+    from nds_tpu.obs import evidence as obs_evidence
     from nds_tpu.obs.ledger import Ledger
 
     mod = _load_ab_module()
@@ -455,27 +455,19 @@ def record_ab(path):
         session = mod._chunked_star_session(np.random.default_rng(42))
         ledger = Ledger(path, driver="bench-compare-ab", platform="cpu",
                         rowBounds=_session_row_bounds(session))
-        drain_stream_events()
-        obs_trace.drain_spans()
         for i, (sql, _must) in enumerate(queries):
             session.sql(sql).collect()       # cold: record+compile
-            drain_stream_events()
-            obs_trace.drain_spans()
+            evidence = obs_evidence.begin()
             t0 = time.perf_counter()
-            s0 = E.sync_count()
-            w0 = E.sync_wait_ns()
             rows = session.sql(sql).collect()
-            used = E.sync_count() - s0
             ms = (time.perf_counter() - t0) * 1e3
-            events = drain_stream_events()
-            roll = obs_export.rollup(obs_trace.drain_spans())
+            ev = evidence.end()
             ledger.query(f"ab{i + 1}", status="ok", ms=round(ms, 3),
-                         hostSyncs=used, outRows=len(rows), sight="warm",
-                         syncWaitMs=round(
-                             (E.sync_wait_ns() - w0) / 1e6, 3),
-                         tracePhases=roll,
-                         streamedScans=[stream_event_json(e)
-                                        for e in events])
+                         hostSyncs=ev["hostSyncs"], outRows=len(rows),
+                         sight="warm",
+                         syncWaitMs=round(ev["syncWaitMs"], 3),
+                         tracePhases=ev["rollup"],
+                         streamedScans=ev["streamedScans"])
     # sharded mini-sweep: the collective evidence
     import jax
     with mod._forced_stream_partitions():
@@ -483,23 +475,20 @@ def record_ab(path):
             if len(jax.local_devices()) >= n_shards:
                 session = mod._chunked_star_session(
                     np.random.default_rng(42))
-                drain_stream_events()
                 for i in getattr(mod, "_STREAM_AB_SHARDED", ()):
                     sql, _must = queries[i]
                     session.sql(sql).collect()
-                    drain_stream_events()
+                    evidence = obs_evidence.begin()
                     t0 = time.perf_counter()
-                    s0 = E.sync_count()
                     rows = session.sql(sql).collect()
-                    used = E.sync_count() - s0
                     ms = (time.perf_counter() - t0) * 1e3
-                    events = drain_stream_events()
+                    ev = evidence.end()
                     ledger.query(f"ab{i + 1}@sharded", status="ok",
-                                 ms=round(ms, 3), hostSyncs=used,
+                                 ms=round(ms, 3),
+                                 hostSyncs=ev["hostSyncs"],
                                  outRows=len(rows), sight="warm",
                                  shardsForced=n_shards,
-                                 streamedScans=[stream_event_json(e)
-                                                for e in events])
+                                 streamedScans=ev["streamedScans"])
     ledger.close("completed", queries=len(queries))
     return path
 
@@ -877,9 +866,12 @@ def main(argv=None) -> int:
                     help="self-test: synthetically regress round B (or "
                     "zero the audit bounds under --audit-ab) and REQUIRE "
                     "the gate to fail")
-    ap.add_argument("--emit-perf", metavar="PATH",
-                    help="regenerate PERF.md from the (single) given "
-                    "ledger — deterministic, same renderer as bench.py")
+    ap.add_argument("--emit-perf", metavar="PATH", nargs="?", const="",
+                    help="regenerate the campaign roofline table from "
+                    "the (single) given ledger — deterministic, same "
+                    "renderer as bench.py (default: "
+                    "chiprun_out/BENCH_PERF.md; never the repo's PERF.md "
+                    "unless named)")
     ap.add_argument("--record-ab", metavar="PATH",
                     help="run the pinned A/B template mini-sweep (CPU) "
                     "and write its evidence ledger to PATH")
@@ -1000,13 +992,14 @@ def main(argv=None) -> int:
               "(model drift or engine regression)")
         return 1
 
-    if args.emit_perf:
+    if args.emit_perf is not None:
         if len(args.rounds) != 1:
             ap.error("--emit-perf takes exactly one ledger round")
         b = load_round(args.rounds[0])
         emit_perf(b, args.emit_perf)
-        print(f"# PERF.md regenerated from {args.rounds[0]} -> "
-              f"{args.emit_perf} ({len(b['times'])} queries)")
+        print(f"# roofline table regenerated from {args.rounds[0]} -> "
+              f"{args.emit_perf or 'chiprun_out/BENCH_PERF.md'} "
+              f"({len(b['times'])} queries)")
         return 0
 
     if len(args.rounds) > 2:

@@ -31,7 +31,7 @@ Usage:
     python tools/campaign.py --matrix arms.json --dir out/  # custom matrix
     python tools/campaign.py --preset sf10-full --gate BASELINE.jsonl
     python tools/campaign.py --preset sf10-full --audit-ab --audit-perf
-    python tools/campaign.py --preset sf10-full --emit-perf PERF.md
+    python tools/campaign.py --preset sf10-full --emit-perf
 """
 
 import argparse
@@ -322,9 +322,9 @@ def main(argv=None) -> int:
                     help="cross-validate each arm's A/B ledger against "
                     "the perf_audit static cost model")
     ap.add_argument("--emit-perf", metavar="PATH", nargs="?",
-                    const=os.path.join(REPO, "PERF.md"),
-                    help="regenerate PERF.md from the primary arm's "
-                    "ledger (default: repo PERF.md)")
+                    const="",
+                    help="regenerate the roofline table from the primary "
+                    "arm's ledger (default: chiprun_out/BENCH_PERF.md)")
     args = ap.parse_args(argv)
     C = campaign_mod()
 
@@ -384,13 +384,13 @@ def main(argv=None) -> int:
         rc = max(rc, run_gate(arms, campaign_dir, args.gate,
                               args.threshold))
 
-    if args.emit_perf:
+    if args.emit_perf is not None:
         ledger = C.arm_paths(campaign_dir, primary)["ledger"]
         if os.path.exists(ledger):
             bc = _bench_compare()
             bc.emit_perf(bc.load_round(ledger), args.emit_perf)
-            print(f"# PERF.md regenerated from arm {primary} -> "
-                  f"{args.emit_perf}")
+            print(f"# roofline table regenerated from arm {primary} -> "
+                  f"{args.emit_perf or 'chiprun_out/BENCH_PERF.md'}")
         else:
             print(f"# --emit-perf: primary arm {primary} has no ledger "
                   "yet", file=sys.stderr)

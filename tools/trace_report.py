@@ -5,8 +5,10 @@ table PERF.md needs.
 Reads every ``*.trace.json`` a driver wrote (``nds_power.py --trace-dir``
 / ``NDS_BENCH_TRACE_DIR``) and prints:
 
-1. the per-query phase breakdown — self-time per phase (a parent span's
-   time minus its children), host-sync count, the compile-vs-drive
+1. the per-query phase breakdown — self-time per phase (the rollup's
+   ``selfMs``: a span's time minus its direct children of the same
+   thread, from the spans' ``parent`` ids), host-sync count, the
+   compile-vs-drive
    split of the streamed chunk pipeline, the collective time of a
    SHARDED pipeline (``stream.exchange`` — the per-chunk hash-exchange
    pass — as its own phase column, with the cross-shard reduce inside
@@ -44,17 +46,26 @@ The input may be a ``--trace-dir`` of per-query Chrome traces OR a
 campaign evidence ledger file (``nds_tpu/obs/ledger.py`` — bench.py
 resume / ``nds_power.py --ledger``): ledger query records carry the
 same ``tracePhases`` rollup and streamed-scan evidence, so post-hoc
-analysis works on any completed round without re-running it. Ledger
-rows price phases from the recorded rollup (inclusive span times, not
-self-times) and use uploaded (encoded) bytes as the logical volume.
+analysis works on any completed round without re-running it. Both
+inputs price phases from the same recorded rollup (``selfMs``); ledger
+rows use uploaded (encoded) bytes as the logical volume.
+
+``--profile DIR`` reads a profiler capture instead (``nds_power.py
+--profile DIR``, one ``<query>/`` capture each, or any directory
+holding ``*.xplane.pb``): per statement, device busy time by engine
+scope (``nds.*``), the top device operations with their scope beside
+their HLO name, and every idle gap over 1 ms with the innermost ``nds:``
+span open on the host at that instant. See :func:`profile_report`.
 
 Usage: python tools/trace_report.py TRACE_DIR_OR_LEDGER [--top N]
+       python tools/trace_report.py --profile PROFILE_DIR [--top N]
 """
 
 import argparse
 import glob
 import json
 import os
+import re
 import sys
 from collections import Counter, defaultdict
 
@@ -81,37 +92,60 @@ ROOFLINE_ICI_GBS = float(os.environ.get("NDS_TPU_ROOFLINE_ICI_GBS", "186"))
 # predicates + routing hash in ONE VMEM-resident launch — it REPLACES
 # stream.partition when the fused arm engages), priced as its own column
 # so the kernels are priced by the same report the campaign reads.
-PHASES = ("plan", "replay.record", "replay.compile", "replay.drive",
-          "stream.record", "stream.compile", "stream.kernel",
+# "ops" is every engine-primitive span (op.join, op.sort, ...) folded
+# into one column; "stream" is the umbrella's own self time (cache
+# lookup, part flattening: what its stream.* children do not cover).
+PHASES = ("statement", "parse", "plan", "ops",
+          "replay.record", "replay.compile", "replay.drive",
+          "stream", "stream.record", "stream.compile", "stream.kernel",
           "stream.partition",
           "stream.exchange", "stream.prefetch", "stream.drive",
           "stream.eager", "stream.overflow-rerun", "stream.materialize",
-          "materialize")
+          "materialize", "collect")
+# the prefetch ring's stages: mostly worker-thread time that ran BESIDE
+# the driver (their own table row would exceed the wall), so they are
+# kept out of the per-query phase sum and "other"
+BESIDE = ("prefetch.source", "prefetch.prepare", "prefetch.backpressure")
 
 
-def self_times(events):
-    """Per-event self duration: each X event's ``dur`` minus the dur of
-    its directly nested children (ts/dur containment on one thread)."""
-    spans = [dict(e) for e in events if e.get("ph") == "X"]
-    spans.sort(key=lambda e: (e["ts"], -e["dur"]))
-    stack = []
-    for e in spans:
-        e["self"] = e["dur"]
-        while stack and stack[-1]["ts"] + stack[-1]["dur"] <= e["ts"]:
-            stack.pop()
-        e["top"] = not stack          # not contained in any other span
-        if stack:
-            stack[-1]["self"] -= e["dur"]
-        stack.append(e)
-    return spans
+def phase_self_ms(phases):
+    """``{column: self ms}`` and the wall the span tree covers (sum of
+    the parentless spans, ``rootMs``) from one rollup's ``phases`` — the
+    ONE self-time source of this report, for trace files and ledger
+    records alike. A rollup that predates ``selfMs`` prices inclusive
+    ``ms`` (its umbrellas then double-count; re-run to get the tree)."""
+    cols = defaultdict(float)
+    root_ms = 0.0
+    for name, p in phases.items():
+        root_ms += p.get("rootMs", 0.0)
+        if name in BESIDE:
+            continue
+        col = "ops" if name.startswith("op.") else \
+            name if name in PHASES else "other"
+        cols[col] += p.get("selfMs", p.get("ms", 0.0))
+    return cols, root_ms
+
+
+def _add_unit_costs(agg, phases):
+    """Feed the run's compiled-path unit costs (per-chunk drive, one
+    materialize) from one statement's rollup."""
+    for name, key in (("stream.drive", "drive"),
+                      ("stream.materialize", "mat")):
+        p = phases.get(name)
+        if p:
+            agg[key + "_ms"] += p.get("selfMs", p.get("ms", 0.0))
+            agg[key + "_n"] += p.get("count", 0)
 
 
 def load_trace(path):
+    """(query, events, rollup phases) of one Chrome trace file."""
     with open(path) as f:
         doc = json.load(f)
-    query = (doc.get("nds") or {}).get("query") or \
+    nds = doc.get("nds") or {}
+    query = nds.get("query") or \
         os.path.basename(path).split(".trace.json")[0]
-    return query, doc.get("traceEvents") or []
+    return (query, doc.get("traceEvents") or [],
+            (nds.get("rollup") or {}).get("phases") or {})
 
 
 def _new_agg():
@@ -147,7 +181,7 @@ def collect_from_traces(trace_dir):
     site_tag = agg["site_tag"]
     fallbacks = agg["fallbacks"]
     for path in files:
-        query, events = load_trace(path)
+        query, events, phases = load_trace(path)
 
         def is_sync(e):
             return e.get("cat") == "sync" or e["name"].startswith("sync:")
@@ -162,17 +196,18 @@ def collect_from_traces(trace_dir):
                 query_syncs += args.get("syncs", 0)
                 query_sync_ms += e.get("dur", 0.0) / 1e3
                 site_tag.setdefault(site, e["name"].split("sync:")[-1])
-        # sync slices are excluded from the span tree: their blocked time
-        # belongs to the phase span that paid it, not to an "other" row
-        spans = self_times([e for e in events if not is_sync(e)])
-        row = {"total_ms": 0.0, "syncs": 0, "phases": defaultdict(float),
+        # sync slices are no spans: their blocked time belongs to the
+        # phase span that paid it (its selfMs), not to an "other" row
+        spans = [e for e in events
+                 if e.get("ph") == "X" and not is_sync(e)]
+        cols, root_ms = phase_self_ms(phases)
+        row = {"total_ms": root_ms, "syncs": 0, "phases": cols,
                "h2d": 0, "logical": 0, "stream_ms": 0.0, "ici": 0,
                "sync_ms": 0.0, "pf_stall": 0.0}
+        _add_unit_costs(agg, phases)
         for e in spans:
             name = e["name"]
             args = e.get("args") or {}
-            row["phases"][name if name in PHASES else "other"] += \
-                e["self"] / 1e3
             if name == "stream":
                 # driver ms BLOCKED on the prefetch ring, measured per
                 # scan (StreamEvent.prefetch_stall_ms riding the stream
@@ -195,12 +230,6 @@ def collect_from_traces(trace_dir):
                 row["stream_ms"] += e["dur"] / 1e3
                 ici = args.get("bytesIci", 0) or 0
                 row["ici"] += max(ici, 0)
-            if name == "stream.drive":
-                agg["drive_ms"] += e["self"] / 1e3
-                agg["drive_n"] += 1
-            if name == "stream.materialize":
-                agg["mat_ms"] += e["self"] / 1e3
-                agg["mat_n"] += 1
             if name == "stream" and args.get("path") == "eager":
                 fb = fallbacks[args.get("reason", "?")]
                 fb["queries"] += 1
@@ -212,13 +241,11 @@ def collect_from_traces(trace_dir):
                 # span's remainder is the WASTED compiled-pipeline work
                 fb = fallbacks[args.get("reason", "bound-bucket overflow")]
                 fb["rerun_ms"] += e["dur"] / 1e3
-        # wall from the top-level (non-contained) spans only, so nested
-        # phases never double-count into the query total; syncs from the
+        # wall from the parentless spans only (rootMs), so nested phases
+        # never double-count into the query total; syncs from the
         # attributed sync-site slices — each charged sync appears on
         # exactly one slice, including syncs paid BETWEEN spans that no
-        # top-level span's delta would cover
-        tops = [e for e in spans if e["top"]]
-        row["total_ms"] = sum(e["dur"] for e in tops) / 1e3
+        # root span's delta would cover
         row["syncs"] = query_syncs
         row["sync_ms"] = query_sync_ms
         per_query[query] = row
@@ -231,8 +258,8 @@ def collect_from_ledger(path):
     counts / syncs, top sync sites, fallbacks) and the streamed-scan
     evidence (bytesH2d/bytesIci) — enough for the phase table, roofline
     columns and bottleneck ranking without the original trace dir.
-    Phase times are the rollup's INCLUSIVE span totals (children
-    included), and uploaded bytes stand in for logical volume."""
+    Phase times are the rollup's ``selfMs`` (:func:`phase_self_ms`, as
+    for a trace dir); uploaded bytes stand in for logical volume."""
     sys.path.insert(0, REPO)
     from tools._ledger_load import ledger_mod   # stdlib-only: no jax
     data = ledger_mod().load_ledger(path)
@@ -249,32 +276,9 @@ def collect_from_ledger(path):
                "phases": defaultdict(float), "h2d": 0, "logical": 0,
                "stream_ms": 0.0, "ici": 0,
                "sync_ms": rec.get("syncWaitMs", 0.0), "pf_stall": 0.0}
-        # rollup phase times are INCLUSIVE, so the umbrella spans —
-        # 'query' (wraps everything) and 'stream' (wraps the chunk
-        # pipeline) — must not fold into columns next to their own
-        # children: that would double-count the whole wall into
-        # 'other'. 'plan' IS a column, so approximate its self-time by
-        # subtracting its known direct children (the stream umbrella
-        # and the replay phases).
-        incl = {n: p.get("ms", 0.0) for n, p in phases.items()}
-        plan_children = incl.get("stream", 0.0) + sum(
-            incl.get(n, 0.0) for n in ("replay.record", "replay.compile",
-                                       "replay.drive"))
-        for pname, p in phases.items():
-            ms = p.get("ms", 0.0)
-            if pname == "stream":
-                row["stream_ms"] += ms
-            if pname in ("query", "stream"):
-                continue                 # umbrellas: time is in children
-            if pname == "plan":
-                ms = max(ms - plan_children, 0.0)
-            row["phases"][pname if pname in PHASES else "other"] += ms
-            if pname == "stream.drive":
-                agg["drive_ms"] += ms
-                agg["drive_n"] += p.get("count", 0)
-            if pname == "stream.materialize":
-                agg["mat_ms"] += ms
-                agg["mat_n"] += p.get("count", 0)
+        row["phases"], _root_ms = phase_self_ms(phases)
+        row["stream_ms"] = (phases.get("stream") or {}).get("ms", 0.0)
+        _add_unit_costs(agg, phases)
         # driver-measured XLA compile (the jax monitoring meter): richer
         # than the span phases when the compile happened outside a
         # stream/replay compile span (e.g. eager table-at-a-time ops)
@@ -573,6 +577,377 @@ def metrics_report_lines(path):
     return lines
 
 
+
+# ---------------------------------------------------------------------------
+# --profile: the program's reader of a profiler capture
+# ---------------------------------------------------------------------------
+#
+# What a v5e's capture holds (found on the chip, PR 26): the device plane
+# ``/device:TPU:n`` has a line ``XLA Ops`` (one event per executed HLO
+# operation, named by its whole HLO text) and a line ``XLA Modules`` (one
+# event per program run, named ``jit_<function>(<program id>)``). The
+# operation's ``op_name`` — the ``jax.named_scope`` path, where the
+# engine's ``nds.*`` scopes live — IS recorded, as the stat ``tf_op``
+# beside ``program_id``, but on the event's METADATA entry, which
+# ``jax.profiler.ProfileData`` does not hand out (it gives the per-event
+# stats only: offsets and durations). So events, lines and the host
+# plane's ``nds:`` annotations (with their ``sid`` / ``parent`` / ``qid``
+# stats) are read through ``ProfileData``, and the metadata stats through
+# ``_event_metadata``, a reader of just those fields of the file.
+
+ANNOTATION_PREFIX = "nds:"
+SCOPE_PREFIX = "nds."
+OP_LINE, MODULE_LINE = "XLA Ops", "XLA Modules"
+GAP_MIN_NS = 1_000_000
+
+
+def _wire_fields(buf):
+    """(field number, wire type, value) of one protobuf message: varints
+    as ints, length-delimited fields as memoryviews; nothing else is
+    decoded."""
+    i, n = 0, len(buf)
+
+    def varint(i):
+        out = shift = 0
+        while True:
+            b = buf[i]
+            i += 1
+            out |= (b & 0x7F) << shift
+            shift += 7
+            if b < 0x80:
+                return out, i
+    while i < n:
+        key, i = varint(i)
+        no, wt = key >> 3, key & 7
+        if wt == 0:
+            v, i = varint(i)
+        elif wt == 2:
+            ln, i = varint(i)
+            v, i = buf[i:i + ln], i + ln
+        elif wt == 1:
+            v, i = buf[i:i + 8], i + 8
+        elif wt == 5:
+            v, i = buf[i:i + 4], i + 4
+        else:
+            raise ValueError(f"wire type {wt}")
+        yield no, wt, v
+
+
+def _event_metadata(path):
+    """``{plane name: {event name: {program id: op_name}}}`` from the
+    XEventMetadata stats ``tf_op`` / ``program_id`` of an ``.xplane.pb``
+    (XSpace.planes=1; XPlane.name=2, event_metadata=4, stat_metadata=5;
+    map entries key=1 value=2; XEventMetadata.name=2, stats=5;
+    XStatMetadata.name=2; XStat.metadata_id=1, uint64=3, int64=4,
+    str=5, ref=7)."""
+    with open(path, "rb") as f:
+        space = memoryview(f.read())
+    out = {}
+    for no, _wt, plane in _wire_fields(space):
+        if no != 1:
+            continue
+        pname, metas, stat_names = "", [], {}
+        for f1, _w, v in _wire_fields(plane):
+            if f1 == 2:
+                pname = bytes(v).decode()
+            elif f1 == 4:
+                metas += [v2 for f2, _w2, v2 in _wire_fields(v) if f2 == 2]
+            elif f1 == 5:
+                key = name = None
+                for f2, _w2, v2 in _wire_fields(v):
+                    if f2 == 1:
+                        key = v2
+                    elif f2 == 2:
+                        for f3, _w3, v3 in _wire_fields(v2):
+                            if f3 == 2:
+                                name = bytes(v3).decode()
+                stat_names[key] = name
+        by_name = {}
+        for em in metas:
+            ename, tf_op, program = "", None, None
+            for f1, _w, v in _wire_fields(em):
+                if f1 == 2:
+                    ename = bytes(v).decode(errors="replace")
+                elif f1 == 5:
+                    sid = sval = None
+                    for f2, _w2, v2 in _wire_fields(v):
+                        if f2 == 1:
+                            sid = v2
+                        elif f2 in (3, 4):
+                            sval = v2
+                        elif f2 == 5:
+                            sval = bytes(v2).decode(errors="replace")
+                        elif f2 == 7:
+                            sval = stat_names.get(v2, "")
+                    if stat_names.get(sid) == "tf_op":
+                        tf_op = sval
+                    elif stat_names.get(sid) == "program_id":
+                        program = sval
+            if tf_op:
+                by_name.setdefault(ename, {})[program] = tf_op
+        if by_name:
+            out[pname] = by_name
+    return out
+
+
+def scope_of(op_name):
+    """The engine scopes of an operation's ``op_name``, outermost first
+    (``jit(traced)/nds.stream.chunk/nds.join/jit(_key_hash_impl)/
+    nds.join.key_hash/xor`` -> ``["stream.chunk", "join",
+    "join.key_hash"]``)."""
+    return [c[len(SCOPE_PREFIX):] for c in (op_name or "").split("/")
+            if c.startswith(SCOPE_PREFIX)]
+
+
+_HLO_HEAD = re.compile(r"^(%[\w.\-]+) = (.*?[\}\)\]]) ([\w\-]+)\(")
+
+
+def short_hlo(name, limit=96):
+    """``%while.4 while (u32[], s32[4194304], ...)`` from a whole HLO
+    line: result name, opcode, result type without layouts."""
+    m = _HLO_HEAD.match(name)
+    if not m:
+        return name[:limit]
+    lhs, rtype, opcode = m.groups()
+    rtype = re.sub(r"\{[^{}]*\}", "", rtype)
+    return f"{lhs} {opcode} {rtype}"[:limit]
+
+
+def read_profile(path):
+    """One ``.xplane.pb`` as plain data:
+
+    ``{"ops": [(plane, name, start_ns, dur_ns, scopes, module)],
+    "notes": [{"name", "start", "end", "sid", "parent", "qid", "line"}]}``
+
+    ``scopes`` from the operation's ``op_name`` (see the note above);
+    ``module`` is the ``jit_<function>`` of the program run that holds
+    the operation. Where a capture has no device plane (the CPU
+    backend) the host lines' events that carry an ``hlo_module`` stat
+    stand in for the operations, named by module alone."""
+    import bisect
+    import warnings
+
+    from jax.profiler import ProfileData
+    # iterating an event's stats warns about the binding's own type
+    warnings.filterwarnings("ignore", category=DeprecationWarning,
+                            message=".*event_stats.*")
+    data = ProfileData.from_file(path)
+    meta = _event_metadata(path)
+    dev = re.compile(r"^/device:(TPU|GPU):\d+$")
+    ops, notes, host_ops = [], [], []
+    for plane in data.planes:
+        if dev.match(plane.name):
+            lines = {ln.name: ln for ln in plane.lines}
+            mods = sorted((int(e.start_ns), int(e.start_ns + e.duration_ns),
+                           e.name) for e in lines[MODULE_LINE].events) \
+                if MODULE_LINE in lines else []
+            starts = [m[0] for m in mods]
+            by_name = meta.get(plane.name, {})
+            for e in (lines[OP_LINE].events if OP_LINE in lines else ()):
+                start, dur = int(e.start_ns), int(e.duration_ns)
+                k = bisect.bisect_right(starts, start) - 1
+                module, program = "", None
+                if k >= 0 and start < mods[k][1]:
+                    m = re.match(r"^(.*)\((-?\d+)\)$", mods[k][2])
+                    module = m.group(1) if m else mods[k][2]
+                    program = int(m.group(2)) if m else None
+                cands = by_name.get(e.name) or {}
+                op_name = cands.get(program)
+                if op_name is None and len(cands) == 1:
+                    op_name = next(iter(cands.values()))
+                ops.append((plane.name, e.name, start, dur,
+                            scope_of(op_name), module))
+            continue
+        for k, ln in enumerate(plane.lines):
+            # a host thread is a line; the OS name of a Python thread is
+            # "python" for all of them, so a line is known by its place
+            thread = (plane.name, k)
+            for e in ln.events:
+                if e.name.startswith(ANNOTATION_PREFIX):
+                    st = {key: val for key, val in e.stats}
+                    notes.append({
+                        "name": e.name[len(ANNOTATION_PREFIX):],
+                        "start": int(e.start_ns),
+                        "end": int(e.start_ns + e.duration_ns),
+                        "sid": int(st.get("sid", 0)) or None,
+                        "parent": int(st.get("parent", 0)) or None,
+                        "qid": int(st.get("qid", 0)) or None,
+                        "line": thread})
+                elif not ops:
+                    st = {key: val for key, val in e.stats}
+                    if "hlo_module" in st:
+                        host_ops.append((plane.name, e.name,
+                                         int(e.start_ns),
+                                         int(e.duration_ns), [],
+                                         str(st["hlo_module"])))
+    return {"ops": ops or host_ops, "notes": notes}
+
+
+def _self_ns(events):
+    """Per event, its duration minus the events nested in it on the same
+    line (a ``while`` holds its body's operations): what a by-scope sum
+    may add up without counting a nanosecond twice."""
+    order = sorted(range(len(events)),
+                   key=lambda i: (events[i][0], events[i][2], -events[i][3]))
+    self_ns = [e[3] for e in events]
+    stack = []
+    for i in order:
+        start, end = events[i][2], events[i][2] + events[i][3]
+        if stack and events[stack[-1][0]][0] != events[i][0]:
+            stack = []                   # the next device plane
+        while stack and stack[-1][1] <= start:
+            stack.pop()
+        if stack and end <= stack[-1][1]:
+            self_ns[stack[-1][0]] -= events[i][3]
+        stack.append((i, end))
+    return self_ns
+
+
+def _merge(intervals):
+    merged = []
+    for s, e in sorted(i for i in intervals if i[1] > i[0]):
+        if merged and s <= merged[-1][1]:
+            merged[-1][1] = max(merged[-1][1], e)
+        else:
+            merged.append([s, e])
+    return merged
+
+
+def _host_during(notes, g0, g1):
+    """What the host was doing in ``[g0, g1)``: ``(driver, beside)``,
+    each ``[(annotation name, ns)]`` longest first. An annotation's share
+    is its overlap with the gap minus its children's (by ``parent`` id)
+    on the same host thread, so the innermost open span gets the time.
+    ``beside``: annotations that ran on another thread than their parent
+    (the prefetch ring's worker)."""
+    def overlap(n):
+        return max(min(n["end"], g1) - max(n["start"], g0), 0)
+    by_sid = {n["sid"]: n for n in notes if n["sid"]}
+    own = {}
+    beside = {}
+    for n in notes:
+        ov = overlap(n)
+        if not ov:
+            continue
+        parent = by_sid.get(n["parent"])
+        if parent is not None and parent["line"] != n["line"]:
+            beside[n["name"]] = beside.get(n["name"], 0) + ov
+            continue
+        own[id(n)] = own.get(id(n), 0) + ov
+        if parent is not None:
+            own[id(parent)] = own.get(id(parent), 0) - ov
+    names = {}
+    for n in notes:
+        ns = own.get(id(n), 0)
+        if ns > 0:
+            names[n["name"]] = names.get(n["name"], 0) + ns
+
+    def ranked(d):
+        return sorted(d.items(), key=lambda kv: -kv[1])
+    return ranked(names), ranked(beside)
+
+
+def profile_statements(prof):
+    """The capture cut into statements: ``[(label, start, end, qid)]``
+    from the ``nds:`` annotations of each ``qid`` (statement, then its
+    materialize / collect), in time order; the whole capture as one
+    window where it holds no statement."""
+    spans = {}
+    for n in prof["notes"]:
+        if n["qid"] and n["sid"]:
+            lo, hi = spans.get(n["qid"], (n["start"], n["end"]))
+            spans[n["qid"]] = (min(lo, n["start"]), max(hi, n["end"]))
+    if spans:
+        return [(f"qid {q}", lo, hi, q)
+                for q, (lo, hi) in sorted(spans.items(),
+                                          key=lambda kv: kv[1][0])]
+    if not prof["ops"]:
+        return []
+    lo = min(o[2] for o in prof["ops"])
+    hi = max(o[2] + o[3] for o in prof["ops"])
+    return [("capture", lo, hi, None)]
+
+
+def profile_report_lines(path, label, top=10):
+    prof = read_profile(path)
+    ops, notes = prof["ops"], prof["notes"]
+    lines = [f"# profile {label}: {len(ops)} device operations, "
+             f"{len(notes)} nds: annotations"]
+    if not ops:
+        return lines + ["  no device operation in this capture"]
+    self_ns = _self_ns(ops)
+    for wlabel, lo, hi, qid in profile_statements(prof):
+        idx = [i for i, o in enumerate(ops) if lo <= o[2] < hi]
+        planes = sorted({ops[i][0] for i in idx}) or [""]
+        busy = sum(e - s for pl in planes for s, e in _merge(
+            [[ops[i][2], min(ops[i][2] + ops[i][3], hi)]
+             for i in idx if ops[i][0] == pl])) / len(planes)
+        window = hi - lo
+        lines.append(f"## {wlabel}: window {window / 1e6:.1f} ms, device "
+                     f"busy {busy / 1e6:.1f} ms "
+                     f"({100.0 * busy / max(window, 1):.1f}%)")
+        by_scope = {}
+        by_op = {}
+        for i in idx:
+            _pl, name, _s, dur, scopes, module = ops[i]
+            key = scopes[-1] if scopes else (module or "?")
+            by_scope[key] = by_scope.get(key, 0) + self_ns[i]
+            where = " > ".join(scopes) if scopes else ""
+            okey = (short_hlo(name), where, module)
+            agg = by_op.setdefault(okey, [0, 0])
+            agg[0] += dur
+            agg[1] += 1
+        lines.append("  device time by scope (self time of each "
+                     "operation; a scope, else the jitted function):")
+        for key, ns in sorted(by_scope.items(),
+                              key=lambda kv: -kv[1])[:top]:
+            lines.append(f"    {ns / 1e6:10.2f} ms  {key}")
+        lines.append(f"  top {top} device operations (inclusive):")
+        for (hlo, where, module), (ns, n) in sorted(
+                by_op.items(), key=lambda kv: -kv[1][0])[:top]:
+            named = where or "-"
+            lines.append(f"    {ns / 1e6:10.2f} ms  x{n:<5d} "
+                         f"{named}  [{module or '?'}]  {hlo}")
+        gaps = []
+        for pl in planes:
+            merged = _merge([[max(ops[i][2], lo),
+                              min(ops[i][2] + ops[i][3], hi)]
+                             for i in idx if ops[i][0] == pl])
+            edges = [lo] + [x for se in merged for x in se] + [hi]
+            gaps += [(g0, g1) for g0, g1 in zip(edges[0::2], edges[1::2])
+                     if g1 - g0 >= GAP_MIN_NS]
+        mine = [n for n in notes if qid is None or n["qid"] == qid]
+        lines.append(f"  idle gaps over {GAP_MIN_NS / 1e6:.0f} ms: "
+                     f"{len(gaps)}, "
+                     f"{sum(g1 - g0 for g0, g1 in gaps) / 1e6:.1f} ms")
+        for g0, g1 in sorted(gaps, key=lambda g: g[0] - g[1])[:top]:
+            driver, beside = _host_during(mine, g0, g1)
+            what = ", ".join(f"{n} {ns / 1e6:.1f}" for n, ns in driver[:4]) \
+                or "no nds: span open"
+            if beside:
+                what += "; beside: " + ", ".join(
+                    f"{n} {ns / 1e6:.1f}" for n, ns in beside[:3])
+            lines.append(f"    {(g1 - g0) / 1e6:8.2f} ms at "
+                         f"+{(g0 - lo) / 1e6:.1f} ms  host: {what}")
+    return lines
+
+
+def profile_report(profile_dir, top=10):
+    """Lines of the ``--profile`` report over every ``*.xplane.pb`` under
+    ``profile_dir`` (``nds_power.py --profile`` writes one capture
+    per query under ``<dir>/<query>/``)."""
+    files = sorted(glob.glob(os.path.join(profile_dir, "**", "*.xplane.pb"),
+                             recursive=True))
+    if not files:
+        return [f"# no *.xplane.pb under {profile_dir}"]
+    lines = []
+    for path in files:
+        rel = os.path.relpath(path, profile_dir)
+        label = rel.split(os.sep)[0] if os.sep in rel else rel
+        lines += profile_report_lines(path, label, top=top)
+    return lines
+
+
 def report(source, top=10):
     """Aggregate a --trace-dir (directory) or a campaign evidence ledger
     (file); returns the printable lines."""
@@ -596,14 +971,27 @@ def main(argv=None) -> int:
         "ledger file) into the per-phase breakdown table (PERF.md), "
         "roofline columns, top sync sites, fallback costs and the "
         "ranked next-bottleneck summary")
-    ap.add_argument("trace_dir", help="directory of *.trace.json files "
+    ap.add_argument("trace_dir", nargs="?",
+                    help="directory of *.trace.json files "
                     "written by nds_power.py --trace-dir, OR a campaign "
                     "evidence ledger file (bench.py resume JSONL / "
                     "nds_power.py --ledger)")
+    ap.add_argument("--profile", metavar="DIR",
+                    help="read a profiler capture instead (nds_power.py "
+                    "--profile DIR): device time by engine scope, "
+                    "top operations with their scope, idle gaps with the "
+                    "host span open in them")
     ap.add_argument("--top", type=int, default=10,
-                    help="sync sites to list (default 10)")
+                    help="sync sites / scopes / operations / gaps to "
+                    "list (default 10)")
     args = ap.parse_args(argv)
-    for ln in report(args.trace_dir, top=args.top):
+    if args.profile:
+        lines = profile_report(args.profile, top=args.top)
+    elif args.trace_dir:
+        lines = report(args.trace_dir, top=args.top)
+    else:
+        ap.error("give a trace dir / ledger, or --profile DIR")
+    for ln in lines:
         print(ln)
     return 0
 
