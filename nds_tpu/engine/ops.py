@@ -692,23 +692,23 @@ def compact_table(table: DeviceTable, mask: jnp.ndarray,
     :class:`DeviceCount`. Downstream joins/aggregations are pad-tolerant,
     so only an output-shaping consumer ever resolves it, batched.
 
-    ``shrink=True`` is the legacy eager mode — one (batched) host sync,
-    re-bucketing to the tight capacity — for callers about to hold many
-    compacted tables at once (load-time filters, chunk accumulation)."""
+    Past ``NDS_TPU_LAZY_SHRINK_ROWS`` (outside a stream-bounds region), and
+    always with ``shrink=True`` (callers about to hold many compacted
+    tables at once: load-time filters, chunk accumulation), the count is
+    read FIRST — one batched host sync — and sizes the indices, so the row
+    gather runs at the survivors' bucket and not at the producer's."""
     m = mask & live_mask(table.plen, table.nrows)
-    if shrink:
-        n = host_sync(jnp.sum(m))
-        return take_padded(table, compact_indices(m, n), n)
     cap = min(bucket_len(count_bound(table.nrows)), bucket_len(table.plen))
-    idx = jnp.nonzero(m, size=cap, fill_value=max(table.plen, 1))[0]
-    n = DeviceCount(jnp.sum(m), min(count_bound(table.nrows), cap))
-    out = take_padded(table, idx, n)
-    if cap > lazy_shrink_rows() and not stream_bounds_on():
-        # adaptive: past this bucket size the downstream sorts/segment ops a
-        # fat bucket drags through cost more than one (batched) round trip,
-        # so resolve now — the transfer still drains the whole pending batch
-        return resolve_table(out)
-    return out
+    bound = min(count_bound(table.nrows), cap)
+    if shrink or (cap > lazy_shrink_rows() and not stream_bounds_on()):
+        # adaptive: past this bucket size the gather here and the
+        # downstream sorts/segment ops a fat bucket drags through cost more
+        # than one (batched) round trip, so resolve now — the transfer
+        # still drains the whole pending batch
+        n = DeviceCount(jnp.sum(m), bound).to_int()
+        return take_padded(table, compact_indices(m, n), n)
+    idx = compact_indices(m, cap)         # cap is a bucket: its own width
+    return take_padded(table, idx, DeviceCount(jnp.sum(m), bound))
 
 
 def resolve_table(table: DeviceTable, shrink: bool = True) -> DeviceTable:
@@ -747,8 +747,12 @@ def gather_table_rows(table: DeviceTable, idx: jnp.ndarray,
     from dataclasses import replace as _replace
     names = table.column_names
     cols = [table.columns[n] for n in names]
-    datas, valids = _gather_cols_impl(
-        idx, tuple(c.data for c in cols), tuple(c.valid for c in cols))
+    datas = tuple(c.data for c in cols)
+    valids = tuple(c.valid for c in cols)
+    # what the gather moves, from host-known shapes: index width x arrays
+    _trace.annotate(cells=int(idx.shape[0])
+                    * (len(datas) + sum(v is not None for v in valids)))
+    datas, valids = _gather_cols_impl(idx, datas, valids)
     out = {n: _replace(c, data=d, valid=v)
            for n, c, d, v in zip(names, cols, datas, valids)}
     return DeviceTable(out, nrows, plen=int(idx.shape[0]))

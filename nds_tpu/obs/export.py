@@ -96,7 +96,9 @@ def rollup(records, top_sites: int = 5) -> dict:
     ``compileMs`` (the same self share of the span's counter deltas) and
     ``rootMs`` (durations of the phase's parentless spans: what the tree
     covers of a call's wall, by no span's name). ``phases["stream"]``
-    also carries ``leadInMs`` (:func:`_lead_in_ns`)."""
+    also carries ``leadInMs`` (:func:`_lead_in_ns`), and a phase whose
+    spans state the ``cells`` they move (``op.gather``: index width x
+    arrays gathered) carries their sum."""
     phases: dict = {}
     sites: Counter = Counter()
     site_tag: dict = {}
@@ -127,6 +129,8 @@ def rollup(records, top_sites: int = 5) -> dict:
                 p["compileMs"] + max(r.compile_ns - comp, 0) / 1e6, 3)
             if r.parent is None:
                 p["rootMs"] = round(p["rootMs"] + r.dur_ns / 1e6, 3)
+            if "cells" in r.attrs:
+                p["cells"] = p.get("cells", 0) + r.attrs["cells"]
             if r.name == "stream" and r.attrs.get("path") == "eager":
                 fallbacks.append({
                     "table": r.attrs.get("table", "?"),

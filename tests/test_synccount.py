@@ -19,6 +19,7 @@ import jax.numpy as jnp
 
 from nds_tpu.engine import ops as E
 from nds_tpu.engine.session import Session
+from nds_tpu.obs import export as obs_export
 
 
 def _syncs():
@@ -48,20 +49,23 @@ def star_session(rng):
     return s
 
 
-def test_star_join_sync_budget(star_session):
-    """Filter + star join + group + order by on base tables: the PK-gather
-    star fold is sync-free, filters defer or compact lazily, and the
-    aggregation/output resolves batched — the whole query must fit the
-    <=3-sync budget DESIGN.md targets (vs 10-25 before lazy counts)."""
-    before = _syncs()
-    rows = star_session.sql("""
+_STAR_Q = """
         select d_year, i_brand_id, sum(ss_ext_sales_price) s
         from store_sales, date_dim, item
         where ss_sold_date_sk = d_date_sk and ss_item_sk = i_item_sk
           and d_moy = 11
         group by d_year, i_brand_id
         order by d_year, s desc
-    """).collect()
+    """
+
+
+def test_star_join_sync_budget(star_session):
+    """Filter + star join + group + order by on base tables: the PK-gather
+    star fold is sync-free, filters defer or compact lazily, and the
+    aggregation/output resolves batched — the whole query must fit the
+    <=3-sync budget DESIGN.md targets (vs 10-25 before lazy counts)."""
+    before = _syncs()
+    rows = star_session.sql(_STAR_Q).collect()
     used = _syncs() - before
     assert rows, "query unexpectedly empty"
     assert used <= 3, f"star query used {used} host syncs (budget 3)"
@@ -88,6 +92,114 @@ def test_lazy_compact_exact(rng):
     assert res.plen == E.bucket_len(len(expect))
     np.testing.assert_array_equal(np.asarray(res["v"].data)[:res.nrows],
                                   expect)
+
+
+_COMPACT_ROWS = 5_000                     # input bucket 8192
+
+
+def _compact_fixture(rng):
+    """A device table with a plain, a nullable and a string column, its
+    Arrow twin, and the plain column's values."""
+    n = _COMPACT_ROWS
+    v = rng.integers(0, 100, n)
+    w = rng.integers(0, 1000, n)
+    w_null = rng.random(n) < 0.2
+    arrow = pa.table({
+        "v": pa.array(v, pa.int64()),
+        "w": pa.array([None if z else int(x) for x, z in zip(w, w_null)],
+                      pa.int64()),
+        "s": pa.array([f"name{i % 37}" for i in range(n)]),
+    })
+    s = Session()
+    s.create_temp_view("t", arrow)
+    return s.catalog["t"], arrow, v
+
+
+@pytest.mark.parametrize("keep", ["none", "few", "half", "all"])
+@pytest.mark.parametrize("arm", ["count_first", "lazy", "stream_bounds"])
+def test_compact_counts_before_it_gathers(rng, monkeypatch, arm, keep):
+    """Past NDS_TPU_LAZY_SHRINK_ROWS compact_table reads the count it was
+    going to read anyway BEFORE it builds indices, so the row gather runs
+    at the survivors' bucket: one counted sync, a host count, and the
+    ``op.gather`` span's ``cells`` follow ``bucket_len(n)``, not the input
+    bucket. Under the threshold, and inside a stream-bounds region whatever
+    the threshold, the lazy arm is what it was: no read, a DeviceCount, the
+    producer's bucket. Rows and their order equal NumPy's on every arm."""
+    from nds_tpu.obs import trace as obs_trace
+    dt, arrow, v = _compact_fixture(rng)
+    in_bucket = E.bucket_len(_COMPACT_ROWS)
+    monkeypatch.setenv(
+        "NDS_TPU_LAZY_SHRINK_ROWS",
+        str(in_bucket if arm == "lazy" else in_bucket // 2))
+    cut = {"none": -1, "few": 0, "half": 49, "all": 100}[keep]
+    mask = dt["v"].data <= cut
+    expect = arrow.filter(pa.array(v <= cut))
+    n = expect.num_rows
+    E.resolve_counts()                    # start from a drained thread
+    obs_trace.drain_spans()
+    before = _syncs()
+    with (E.stream_bounds() if arm == "stream_bounds"
+          else contextlib.nullcontext()):
+        out = E.compact_table(dt, mask)
+    used = _syncs() - before
+    gathers = [r for r in obs_trace.drain_spans()
+               if isinstance(r, obs_trace.SpanRecord)
+               and r.name == "op.gather"]
+    arrays = sum(1 + (c.valid is not None) for c in dt.columns.values())
+    assert len(gathers) == 1
+    if arm == "count_first":
+        assert used == 1, f"count-first compact made {used} syncs"
+        assert out.nrows == n and isinstance(out.nrows, int)
+        assert out.plen == E.bucket_len(n)
+    else:
+        assert used == 0, "lazy compact must not sync"
+        assert isinstance(out.nrows, E.DeviceCount)
+        assert out.plen == in_bucket
+    assert gathers[0].attrs["cells"] == out.plen * arrays
+    assert obs_export.rollup(gathers)["phases"]["op.gather"]["cells"] \
+        == out.plen * arrays
+    res = E.resolve_table(out)
+    assert res.nrows == n and res.plen == E.bucket_len(n)
+    got = res.to_arrow()
+    assert got.equals(expect.cast(got.schema))
+
+
+def test_count_first_compact_replays_with_sync_parity(star_session,
+                                                      monkeypatch):
+    """The star statement with the threshold under the fact's bucket, so
+    the chain's compaction takes the count-first arm: eager, recorded and
+    replayed executions give equal rows; the eager and the recorded one
+    make the same reads outside the recorder's own (``dense_dim``), one of
+    them at the compaction, inside the star budget; the recording compiles
+    (the index shape follows the logged count, no ReplayMismatch) and the
+    replayed executions make the one result read."""
+    from nds_tpu.obs import trace as obs_trace
+    monkeypatch.setenv("NDS_TPU_REPLAY", "force")
+    monkeypatch.setenv("NDS_TPU_LAZY_SHRINK_ROWS", "1024")
+    s = star_session
+    fact = s.catalog["store_sales"]
+    E.resolve_counts()
+    obs_trace.drain_spans()
+    runs = []
+    for _ in range(4):                    # eager, record + compile, replay x2
+        before = _syncs()
+        rows = s.sql(_STAR_Q).collect()
+        roll = obs_export.rollup(obs_trace.drain_spans(), top_sites=20)
+        own = {(x["site"], x["tag"]): x["syncs"] for x in roll["syncSites"]
+               if x["tag"] != "dense_dim"}
+        runs.append((rows, _syncs() - before, own, roll["phases"]))
+    (r0, n0, own0, ph0), (r1, _n1, own1, ph1), (r2, n2, _, ph2), \
+        (r3, n3, _, _) = runs
+    assert r0 and r0 == r1 == r2 == r3
+    assert n0 <= 3, f"star query used {n0} host syncs (budget 3)"
+    assert own0 == own1 and sum(own0.values()) == n0
+    assert [n for (site, _t), n in own0.items() if "_join_parts" in site] \
+        == [1], own0
+    assert "replay.compile" in ph1 and s._replay_cache
+    assert "replay.drive" in ph2 and n2 == n3 <= 1
+    # the compaction gathered at the survivors' bucket in both tiers
+    assert ph0["op.gather"]["cells"] == ph1["op.gather"]["cells"]
+    assert ph0["op.gather"]["cells"] < fact.plen * 2 * len(fact.columns)
 
 
 def test_batched_resolution_is_one_sync():
