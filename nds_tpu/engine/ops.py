@@ -1496,6 +1496,17 @@ def ordered_codes_merged(a: Column, b: Column):
         jnp.take(jnp.asarray(b_map), b.data)
 
 
+@functools.partial(jax.jit, static_argnames="side")
+@_trace.scoped("join.probe")
+def _probe_search_impl(rh_sorted, lh, side):
+    """One binary search of the probe side's hashes against the hash-sorted
+    build side. A jitted body so that the searches, the largest device item
+    of a fact-to-fact join, carry a scope name when the eager arm issues
+    them; one program a side, as the bare ``jnp.searchsorted`` calls were
+    (both sides in one body cost a chain join 0.2 s more on a v5e)."""
+    return jnp.searchsorted(rh_sorted, lh, side=side)
+
+
 def _probe_candidates(left_keys, right_keys, null_safe=False,
                       n_left=None, n_right=None, l_excl=None, r_excl=None):
     """Hash-probe phase shared by the monolithic and chunked joins: returns
@@ -1536,11 +1547,17 @@ def _probe_candidates(left_keys, right_keys, null_safe=False,
         return hi - lo, lo, order, None
     lh = _key_hash_impl(lviews, lvalids, 0, null_safe, count_arr(n_left),
                         l_excl)
-    lo = jnp.searchsorted(rh_sorted, lh, side="left")
-    hi = jnp.searchsorted(rh_sorted, lh, side="right")
+    lo = _probe_search_impl(rh_sorted, lh, side="left")
+    hi = _probe_search_impl(rh_sorted, lh, side="right")
     counts = hi - lo
     total = host_sync(jnp.sum(counts))                 # host sync 1
     return counts, lo, order, total
+
+
+def _key_cells(keys) -> int:
+    """Physical length x arrays (data and validity) of a list of key
+    columns: what a join reads of one side, from host-known shapes."""
+    return sum(len(c) * (1 + (c.valid is not None)) for c in keys)
 
 
 @_trace.traced("join")
@@ -1630,6 +1647,12 @@ def join_indices(left_keys, right_keys, how: str = "inner",
     if miss_r is not None:
         n_rx = n_rx.to_int()
         r_extra = compact_indices(miss_r, n_rx)
+    # what the join touches, from host-known shapes: both sides' key arrays
+    # at their buckets, the pair indices and the unmatched-row indices out
+    _trace.annotate(cells=_key_cells(left_keys) + _key_cells(right_keys)
+                    + 2 * int(l_idx.shape[0])
+                    + sum(int(x.shape[0]) for x in (l_extra, r_extra)
+                          if x is not None))
     return l_idx, r_idx, n_pairs, l_extra, n_lx, r_extra, n_rx
 
 
@@ -1685,12 +1708,18 @@ def semi_join_mask(left_keys, right_keys, negate: bool = False,
         if lview is not None:
             plen_r = len(rk)
             n_r = plen_r if n_right is None else n_right
+            # both sides' key arrays at their buckets and the mask out
+            _trace.annotate(cells=_key_cells(left_keys)
+                            + _key_cells(right_keys) + plen_l)
             matched = _semi_sorted_impl(lview, lk.valid, rview, rk.valid,
                                         count_arr(n_left), count_arr(n_r))
             out = ~matched if negate else matched
             return out & live_mask(plen_l, n_left)
     l_idx, _, _, _, _, _, _ = join_indices(
         left_keys, right_keys, "inner", null_safe, n_left, n_right)
+    # the mask out alone: the op.join span just closed counted the keys
+    # and the pair indices
+    _trace.annotate(cells=plen_l)
     matched = jnp.zeros(plen_l, dtype=bool).at[l_idx].set(True, mode="drop")
     out = ~matched if negate else matched
     return out & live_mask(plen_l, n_left)

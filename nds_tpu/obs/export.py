@@ -98,7 +98,8 @@ def rollup(records, top_sites: int = 5) -> dict:
     covers of a call's wall, by no span's name). ``phases["stream"]``
     also carries ``leadInMs`` (:func:`_lead_in_ns`), and a phase whose
     spans state the ``cells`` they move (``op.gather``: index width x
-    arrays gathered) carries their sum."""
+    arrays gathered; ``op.join`` / ``op.semi_join``: both sides' key
+    arrays and the indices or mask out) carries their sum."""
     phases: dict = {}
     sites: Counter = Counter()
     site_tag: dict = {}

@@ -8,6 +8,7 @@ import json
 import os
 import threading
 
+import jax.numpy as jnp
 import numpy as np
 import pyarrow as pa
 import pytest
@@ -855,3 +856,103 @@ def test_profile_reader_self_time_and_gap_attribution():
     assert dict(beside) == {"prefetch.source": 80}
     assert mod.profile_statements({"notes": notes, "ops": ops}) == \
         [("qid 1", 0, 1000, 1)]
+
+
+# ---------------------------------------------------------------------------
+# op.join / op.semi_join state the cells they touch; the probe's scope (PR 28)
+# ---------------------------------------------------------------------------
+
+_CELLS_QUERIES = {
+    # a general hash join between two tables on a two-column key (full
+    # outer: the unmatched-row indices of both sides are counted too)
+    "op.join": "select count(*), sum(a.v), sum(b.w) from ta a full outer "
+               "join tb b on (a.k = b.k and a.j = b.j)",
+    # one integer key: the sort arm, no op.join inside
+    "op.semi_join": "select count(*) from ta where exists "
+                    "(select * from tb where tb.k = ta.k)",
+    # a two-column key: the hash arm, which opens an op.join
+    "op.semi_join-hash": "select count(*) from ta where exists "
+                         "(select * from tb where tb.k = ta.k "
+                         "and tb.j = ta.j)",
+}
+
+
+def _cells_session():
+    rng = np.random.default_rng(28)
+    s = Session()
+    s.create_temp_view("ta", pa.table({
+        "k": pa.array(rng.integers(0, 50, 300), pa.int64()),
+        "j": pa.array(rng.integers(0, 4, 300), pa.int64()),
+        "v": pa.array(rng.integers(0, 1000, 300), pa.int64())}))
+    s.create_temp_view("tb", pa.table({
+        "k": pa.array(rng.integers(25, 75, 100), pa.int64()),
+        "j": pa.array(rng.integers(0, 4, 100), pa.int64()),
+        "w": pa.array(rng.integers(0, 1000, 100), pa.int64())}))
+    return s
+
+
+@pytest.mark.parametrize("case", sorted(_CELLS_QUERIES))
+def test_join_and_semi_join_spans_state_cells_without_a_sync(case):
+    """The attribute is built from host-known shapes alone: the rollup sums
+    it, the statement's rows and its count of host reads are the same with
+    tracing off, and the number is what the shapes give."""
+    s = _cells_session()
+    ta, tb = s.catalog["ta"], s.catalog["tb"]
+    q = _CELLS_QUERIES[case]
+    phase = case.split("-")[0]
+
+    def run():
+        E.resolve_counts()                # start from a drained thread
+        obs_trace.drain_spans()
+        before = E.sync_count()
+        rows = s.sql(q).collect()
+        return rows, E.sync_count() - before, obs_trace.drain_spans()
+
+    rows_on, syncs_on, records = run()
+    obs_trace.set_enabled(False)
+    try:
+        rows_off, syncs_off, nothing = run()
+    finally:
+        obs_trace.set_enabled(True)
+    assert rows_on == rows_off and rows_on
+    assert syncs_on == syncs_off and not nothing
+    phases = obs_export.rollup(records)["phases"]
+    stated = [r.attrs["cells"] for r in records
+              if isinstance(r, obs_trace.SpanRecord) and r.name == phase
+              and "cells" in r.attrs]
+    cells = phases[phase]["cells"]
+    assert len(stated) == 1 and cells == stated[0]
+    if case == "op.join":
+        # stated once, by the span that builds the pair indices (the
+        # materialising span around it leaves its gathers to op.gather):
+        # both sides' two key columns at their buckets, the pair indices
+        # (two arrays at the candidates' bucket) and the unmatched rows
+        keys = 2 * ta.plen + 2 * tb.plen
+        assert cells > keys and (cells - keys) % E.bucket_len(0) == 0
+    elif case == "op.semi_join":
+        # the probe side's key and the mask out at its bucket, the build
+        # side's key at the bucket the subquery's rows came out at
+        build = cells - 2 * ta.plen
+        assert E.bucket_len(0) <= build <= tb.plen and not build & (build - 1)
+        assert "op.join" not in phases
+    else:
+        # the hash arm: the mask out alone, its keys and pair indices are
+        # counted once, by the op.join span it opens
+        assert cells == ta.plen
+        assert phases["op.join"]["cells"] > 2 * ta.plen
+
+
+def test_the_join_probes_searches_run_under_their_scope():
+    """Each binary search of ``_probe_candidates``' eager arm is a jitted
+    body named ``nds.join.probe`` (a by-scope reader of the device trace
+    sees it), with the offsets ``searchsorted`` gives."""
+    rng = np.random.default_rng(28)
+    build = jnp.sort(jnp.asarray(rng.integers(0, 100, 64), jnp.uint32))
+    probe = jnp.asarray(rng.integers(0, 100, 128), jnp.uint32)
+    for side in ("left", "right"):
+        text = E._probe_search_impl.lower(build, probe, side=side).as_text(
+            debug_info=True)
+        assert "nds.join.probe" in text
+        assert np.array_equal(
+            E._probe_search_impl(build, probe, side=side),
+            np.searchsorted(np.asarray(build), np.asarray(probe), side))

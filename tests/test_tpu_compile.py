@@ -78,6 +78,15 @@ SEGMENT_CASES = [
     ("sum", jnp.float32, CHUNK, 1024),
     ("sum", jnp.float32, MI, 1024),
     ("sum", jnp.float32, 1 << 23, 2048),
+    # the multi-fact mix at SF1 (PR 28): query10's six count(*) over the
+    # customers that pass its EXISTS, at the survivors' bucket (32 Ki for
+    # seed 4242; the neighbouring buckets for other seeds) under the group
+    # ceiling. It is the mix's only segment-kernel call: query25 (4096
+    # groups), query51 (256 Ki and 1 Mi) and query97 are past max_groups()
+    # and past exact_sum_supported, and take the XLA segment ops
+    ("sum", jnp.float32, 1 << 14, 2048),
+    ("sum", jnp.float32, 1 << 15, 2048),
+    ("sum", jnp.float32, 1 << 16, 2048),
     # the exact int64 decimal sum: query56's streamed union (1024 x 1024)
     # and resident scan (1 Mi x 256), and both corners of
     # exact_sum_supported (rows * groups <= 3e8, rows < 2^23) — the most
