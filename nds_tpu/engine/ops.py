@@ -1507,12 +1507,60 @@ def _probe_search_impl(rh_sorted, lh, side):
     return jnp.searchsorted(rh_sorted, lh, side=side)
 
 
+# the build side's prefix bitmap: a power of two of slots, at least 8x the
+# build bucket (a live build row sets one slot in eight at most), capped
+_PRESENT_BITS_MAX = 26
+# a probe-side sentinel (side tag 2, REAL bit clear: _key_hash_impl's
+# layout) for the pad slots of a narrowed probe: equals no build hash
+_PROBE_PAD = np.uint64(2)
+
+
+@functools.partial(jax.jit, static_argnames="bits")
+@_trace.scoped("join.candidates")
+def _probe_mask_impl(rh, lh, bits):
+    """Which probe rows can have a candidate, at the probe bucket, with no
+    search: the row's hash is REAL (not a pad, an excluded row or, unless
+    null-safe, a null key) AND its top ``bits`` bits are those of some REAL
+    build hash (``present``, one scatter at the build bucket, one gather
+    here). Equal hashes share a prefix, so a row this drops has count 0
+    under the full search too; a row it keeps may still have none."""
+    real = jnp.uint64(4)
+    shift = jnp.uint64(64 - bits)
+    slot = jnp.where((rh & real) != 0, (rh >> shift).astype(jnp.int32),
+                     1 << bits)
+    present = jnp.zeros(1 << bits, dtype=bool).at[slot].set(
+        True, mode="drop")
+    return ((lh & real) != 0) & jnp.take(present,
+                                         (lh >> shift).astype(jnp.int32))
+
+
+@jax.jit
+@_trace.scoped("join.candidates")
+def _probe_narrow_impl(lh, idx):
+    """The candidates' hashes at their own bucket (``idx`` from
+    :func:`compact_indices`; pad slots search for :data:`_PROBE_PAD`)."""
+    return jnp.take(lh, idx, mode="fill", fill_value=_PROBE_PAD)
+
+
+@functools.partial(jax.jit, static_argnames="plen")
+@_trace.scoped("join.candidates")
+def _probe_widen_impl(idx, counts, lo, plen):
+    """A narrowed search's counts and offsets back at the probe bucket:
+    zeros on every row that was not searched (pad slots drop)."""
+    return (jnp.zeros(plen, counts.dtype).at[idx].set(counts, mode="drop"),
+            jnp.zeros(plen, lo.dtype).at[idx].set(lo, mode="drop"))
+
+
 def _probe_candidates(left_keys, right_keys, null_safe=False,
                       n_left=None, n_right=None, l_excl=None, r_excl=None):
     """Hash-probe phase shared by the monolithic and chunked joins: returns
     ``(counts, lo, order, total)`` — per-left-row candidate counts, start
     offsets into the hash-sorted right side, the right-side sort order, and
-    the total candidate-pair count (host sync)."""
+    the total candidate-pair count (host sync). Eagerly, past
+    ``NDS_TPU_LAZY_SHRINK_ROWS`` probe rows, the two searches run over the
+    rows that can match alone (one more batched read, the candidates'
+    count): ``counts`` and ``lo`` are then 0 on every other row; the full
+    search's ``counts`` is 0 there too, so the pairs are the same."""
     plen_l = len(left_keys[0])
     plen_r = len(right_keys[0])
     n_left = plen_l if n_left is None else n_left
@@ -1547,9 +1595,27 @@ def _probe_candidates(left_keys, right_keys, null_safe=False,
         return hi - lo, lo, order, None
     lh = _key_hash_impl(lviews, lvalids, 0, null_safe, count_arr(n_left),
                         l_excl)
+    idx = None
+    if plen_l > lazy_shrink_rows():
+        # past the bucket where compact_table reads its count first, so
+        # does the probe: the two searches cost 20 dependent gathers each
+        # at the width they run at, and after the pk chain's deferred
+        # masks few probe rows can match. One batched read of the
+        # candidates' count; the searches then run at the survivors'
+        # bucket, or at full width where that is no smaller
+        bits = min((8 * max(plen_r, 1) - 1).bit_length(), _PRESENT_BITS_MAX)
+        mask = _probe_mask_impl(rh, lh, bits=bits)
+        n_cand = DeviceCount(jnp.sum(mask), plen_l).to_int()
+        if bucket_len(n_cand) < plen_l:
+            idx = compact_indices(mask, n_cand)
+            lh = _probe_narrow_impl(lh, idx)
     lo = _probe_search_impl(rh_sorted, lh, side="left")
     hi = _probe_search_impl(rh_sorted, lh, side="right")
     counts = hi - lo
+    # the bucket the two searches ran at (under plen_l: narrowed)
+    _trace.annotate(probeRows=int(lh.shape[0]))
+    if idx is not None:
+        counts, lo = _probe_widen_impl(idx, counts, lo, plen=plen_l)
     total = host_sync(jnp.sum(counts))                 # host sync 1
     return counts, lo, order, total
 

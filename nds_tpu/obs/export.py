@@ -99,7 +99,10 @@ def rollup(records, top_sites: int = 5) -> dict:
     also carries ``leadInMs`` (:func:`_lead_in_ns`), and a phase whose
     spans state the ``cells`` they move (``op.gather``: index width x
     arrays gathered; ``op.join`` / ``op.semi_join``: both sides' key
-    arrays and the indices or mask out) carries their sum."""
+    arrays and the indices or mask out) carries their sum, as does
+    ``probeRows`` of ``op.join``: the bucket each join's two binary
+    searches ran at (under the probe side's bucket: narrowed to its
+    candidates)."""
     phases: dict = {}
     sites: Counter = Counter()
     site_tag: dict = {}
@@ -130,8 +133,9 @@ def rollup(records, top_sites: int = 5) -> dict:
                 p["compileMs"] + max(r.compile_ns - comp, 0) / 1e6, 3)
             if r.parent is None:
                 p["rootMs"] = round(p["rootMs"] + r.dur_ns / 1e6, 3)
-            if "cells" in r.attrs:
-                p["cells"] = p.get("cells", 0) + r.attrs["cells"]
+            for k in ("cells", "probeRows"):
+                if k in r.attrs:
+                    p[k] = p.get(k, 0) + r.attrs[k]
             if r.name == "stream" and r.attrs.get("path") == "eager":
                 fallbacks.append({
                     "table": r.attrs.get("table", "?"),

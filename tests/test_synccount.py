@@ -202,6 +202,224 @@ def test_count_first_compact_replays_with_sync_parity(star_session,
     assert ph0["op.gather"]["cells"] < fact.plen * 2 * len(fact.columns)
 
 
+def test_narrowed_probe_replays_with_sync_parity(star_session, monkeypatch):
+    """A fact-to-fact hash join with the threshold under the probe side's
+    bucket, so the probe reads its candidates' count and searches at their
+    bucket: eager, recorded and replayed executions give equal rows, the
+    rows of the full-width search; the eager and the recorded one make the
+    same reads; the recording compiles (the narrowed shapes follow the
+    logged count, no ReplayMismatch)."""
+    from nds_tpu.obs import trace as obs_trace
+    q = """
+        select a.ss_item_sk, count(*) c, sum(b.ss_ext_sales_price) s
+        from store_sales a, store_sales b
+        where a.ss_sold_date_sk = b.ss_sold_date_sk
+          and a.ss_item_sk = b.ss_item_sk and a.ss_ext_sales_price < 500
+        group by a.ss_item_sk order by a.ss_item_sk
+    """
+    s = star_session
+    fact = s.catalog["store_sales"]
+    want = s.sql(q).collect()             # full width: default threshold
+    monkeypatch.setenv("NDS_TPU_REPLAY", "force")
+    monkeypatch.setenv("NDS_TPU_LAZY_SHRINK_ROWS", "1024")
+    E.resolve_counts()
+    obs_trace.drain_spans()
+    runs = []
+    for _ in range(4):                    # eager, record + compile, replay x2
+        before = _syncs()
+        rows = s.sql(q).collect()
+        roll = obs_export.rollup(obs_trace.drain_spans(), top_sites=20)
+        own = {(x["site"], x["tag"]): x["syncs"] for x in roll["syncSites"]
+               if x["tag"] != "dense_dim"}
+        runs.append((rows, _syncs() - before, own, roll["phases"]))
+    (r0, n0, own0, ph0), (r1, _n1, own1, ph1), (r2, n2, _, ph2), \
+        (r3, n3, _, _) = runs
+    assert want and want == r0 == r1 == r2 == r3
+    assert own0 == own1 and sum(own0.values()) == n0
+    # the join's site: the candidates' count, then the candidate total
+    assert [n for (site, _t), n in own0.items() if "_join_parts" in site] \
+        == [2], own0
+    assert "replay.compile" in ph1 and s._replay_cache
+    assert "replay.drive" in ph2 and n2 == n3 <= 1
+    # the two searches ran under the fact's bucket in both tiers
+    assert ph0["op.join"]["probeRows"] == ph1["op.join"]["probeRows"] \
+        < fact.plen
+
+
+_PROBE_ROWS, _BUILD_ROWS = 5_000, 700     # buckets 8192 and 1024
+
+
+def _probe_tables(rng, keys, dense, build_rows):
+    """A probe and a build table with duplicate keys on both sides (device
+    tables, and the probe side's bucket). ``keys``: ``int``, ``nullable``
+    (a fifth of each side's keys null) or ``int+str`` (a two-column key
+    whose second column is a string pair over different dictionaries).
+    ``dense``: every probe key is one of the build side's 300, so no
+    candidate bucket is under the probe's; else one in about sixty is."""
+    n, m = _PROBE_ROWS, build_rows
+    lk = rng.integers(0, 300 if dense else 20_000, n)
+    rk = rng.integers(0, 300, m)
+
+    def key(v):
+        if keys != "nullable":
+            return pa.array(v, pa.int64())
+        return pa.array([None if z else int(x) for x, z in
+                         zip(v, rng.random(len(v)) < 0.2)], pa.int64())
+    left = {"lk": key(lk), "lv": pa.array(np.arange(n), pa.int64())}
+    right = {"rk": key(rk), "rv": pa.array(np.arange(m), pa.int64())}
+    if keys == "int+str":
+        left["ls"] = pa.array([f"s{x % 7}" for x in lk])
+        right["rs"] = pa.array([f"s{x % 5}" for x in rk])
+    s = Session()
+    s.create_temp_view("l", pa.table(left))
+    s.create_temp_view("r", pa.table(right))
+    return s.catalog["l"], s.catalog["r"]
+
+
+def _excl(rng, plen, which):
+    return {"none": None, "all": jnp.ones(plen, dtype=bool),
+            "some": jnp.asarray(rng.random(plen) < 0.4)}[which]
+
+
+# (id, how, null_safe, keys, l_excl, r_excl, dense, build rows, what runs)
+_PROBE_CASES = [
+    ("inner", "inner", False, "int", "none", "none", False, _BUILD_ROWS, ""),
+    ("left", "left", False, "int", "some", "none", False, _BUILD_ROWS, ""),
+    ("right", "right", False, "int", "none", "some", False, _BUILD_ROWS, ""),
+    ("full", "full", False, "int", "some", "some", False, _BUILD_ROWS, ""),
+    ("nulls", "full", False, "nullable", "none", "none", False, _BUILD_ROWS,
+     ""),
+    ("null_safe", "inner", True, "nullable", "none", "none", False,
+     _BUILD_ROWS, ""),
+    ("l_excl_all", "left", False, "int", "all", "none", False, _BUILD_ROWS,
+     ""),
+    ("r_excl_all", "full", False, "int", "none", "all", False, _BUILD_ROWS,
+     ""),
+    ("empty_build", "left", False, "int", "none", "none", False, 0, ""),
+    ("string_pair", "inner", False, "int+str", "some", "none", False,
+     _BUILD_ROWS, "semi"),
+    ("chunked", "inner", False, "int", "none", "none", False, _BUILD_ROWS,
+     "chunked"),
+    # every probe row is a candidate: bucket_len(n_cand) == plen_l, so the
+    # searches stay at full width and the read is the only cost
+    ("full_width", "inner", False, "int", "none", "none", True, _BUILD_ROWS,
+     ""),
+    ("full_width_outer", "full", False, "int", "none", "none", True,
+     _BUILD_ROWS, ""),
+]
+
+
+@pytest.mark.parametrize(
+    "how,null_safe,keys,l_excl,r_excl,dense,build_rows,extra",
+    [c[1:] for c in _PROBE_CASES], ids=[c[0] for c in _PROBE_CASES])
+def test_probe_searches_only_its_candidates(
+        rng, monkeypatch, how, null_safe, keys, l_excl, r_excl, dense,
+        build_rows, extra):
+    """With NDS_TPU_LAZY_SHRINK_ROWS under the probe side's bucket
+    ``_probe_candidates`` reads its candidates' count first and runs its two
+    searches at their bucket (at full width where that is no smaller): one
+    more counted sync a probe, and ``counts`` / ``total`` / ``order``, the
+    pair indices of ``join_indices`` in their order, the rows of
+    ``join_tables`` and ``semi_join_mask``'s hash arm are what the full
+    search gives with the threshold over the bucket. No read at all inside
+    a stream-bounds region. ``op.join`` states ``probeRows``, the bucket
+    searched, and the rollup sums it."""
+    from nds_tpu.obs import trace as obs_trace
+    left, right = _probe_tables(rng, keys, dense, max(build_rows, 1))
+    names = {"int": ("lk",), "nullable": ("lk",),
+             "int+str": ("lk", "ls")}[keys]
+    l_on, r_on = list(names), ["r" + n[1:] for n in names]
+    lkeys, rkeys = [left[n] for n in l_on], [right[n] for n in r_on]
+    plen_l = left.plen
+    lx, rx = _excl(rng, plen_l, l_excl), _excl(rng, right.plen, r_excl)
+    if extra == "chunked":
+        monkeypatch.setenv("NDS_TPU_PAIR_BUDGET", "64")
+    kw = dict(n_left=left.nrows, n_right=build_rows, l_excl=lx, r_excl=rx)
+
+    def counted(fn):
+        E.resolve_counts()                # start from a drained thread
+        obs_trace.drain_spans()
+        before = _syncs()
+        out = fn()
+        used = _syncs() - before
+        spans = [r for r in obs_trace.drain_spans()
+                 if isinstance(r, obs_trace.SpanRecord)
+                 and r.name == "op.join"]
+        return out, used, spans
+
+    def everything():
+        probe, s_probe, _ = counted(lambda: E._probe_candidates(
+            lkeys, rkeys, null_safe, **kw))
+        idx, s_idx, sp_idx = counted(lambda: E.join_indices(
+            lkeys, rkeys, how, null_safe, **kw))
+        pairs = [None if x is None else
+                 np.asarray(x) if hasattr(x, "shape") else E.count_int(x)
+                 for x in idx]
+        got = {"counts": np.asarray(probe[0]), "lo": np.asarray(probe[1]),
+               "order": np.asarray(probe[2]), "total": probe[3],
+               "pairs": pairs, "syncs": [s_probe, s_idx],
+               "probeRows": [sp_idx[0].attrs["probeRows"]],
+               "spans": list(sp_idx)}
+        if not null_safe:
+            right_t = type(right)(right.columns, build_rows,
+                                  plen=right.plen)
+            tab, s_tab, sp_tab = counted(lambda: E.resolve_table(
+                E.join_tables(left, right_t, l_on, r_on, how,
+                              l_excl=lx, r_excl=rx)))
+            got["rows"] = tab.to_arrow()
+            got["syncs"].append(s_tab)
+            got["probeRows"].append(
+                sum(r.attrs.get("probeRows", 0) for r in sp_tab))
+            got["spans"] += sp_tab
+        if extra == "semi":
+            mask, s_semi, _ = counted(lambda: E.semi_join_mask(
+                lkeys, rkeys, n_left=left.nrows, n_right=build_rows))
+            got["semi"] = np.asarray(mask)
+            got["syncs"].append(s_semi)
+        return got
+
+    monkeypatch.setenv("NDS_TPU_LAZY_SHRINK_ROWS", str(plen_l))
+    full = everything()
+    monkeypatch.setenv("NDS_TPU_LAZY_SHRINK_ROWS", str(plen_l // 2))
+    narrow = everything()
+    with E.stream_bounds():
+        bound, s_bound, _ = counted(lambda: E._probe_candidates(
+            lkeys, rkeys, null_safe, **kw))
+
+    live = full["counts"] > 0
+    assert np.array_equal(narrow["counts"], full["counts"])
+    assert np.array_equal(narrow["lo"][live], full["lo"][live])
+    assert np.array_equal(narrow["order"], full["order"])
+    assert narrow["total"] == full["total"] == int(full["counts"].sum())
+    assert narrow["counts"].dtype == full["counts"].dtype
+    assert narrow["lo"].dtype == full["lo"].dtype
+    for a, b in zip(narrow["pairs"], full["pairs"]):
+        assert np.array_equal(a, b)       # pairs, extras: bit for bit
+    if "rows" in full:
+        assert narrow["rows"].equals(full["rows"])
+        if extra == "chunked":
+            assert full["total"] > E.pair_budget()
+    if "semi" in full:
+        assert np.array_equal(narrow["semi"], full["semi"])
+    # one more counted read a probe past the threshold, none under it,
+    # none inside a stream-bounds region
+    assert [n - f for n, f in zip(narrow["syncs"], full["syncs"])] \
+        == [1] * len(full["syncs"])
+    assert full["syncs"][0] == 1 and s_bound == 0 and bound[3] is None
+    assert np.array_equal(np.asarray(bound[0]), full["counts"])
+    # probeRows: the bucket the two searches ran at
+    assert set(full["probeRows"]) == {plen_l}
+    n_cand = int((full["counts"] > 0).sum())
+    if dense:
+        assert set(narrow["probeRows"]) == {plen_l}
+    else:
+        (searched,) = set(narrow["probeRows"])
+        assert E.bucket_len(n_cand) <= searched < plen_l
+        assert not searched & (searched - 1)
+    assert obs_export.rollup(narrow["spans"])["phases"]["op.join"][
+        "probeRows"] == sum(narrow["probeRows"])
+
+
 def test_batched_resolution_is_one_sync():
     """N pending DeviceCounts resolve in ONE counted transfer."""
     a = E.DeviceCount(jnp.asarray(3), 10)

@@ -31,6 +31,7 @@ from jax.sharding import SingleDeviceSharding
 
 import nds_tpu  # noqa: F401  (turns x64 on, as the engine runs)
 from nds_tpu.engine import kernels as K
+from nds_tpu.engine import ops as E
 
 # the streamed phase's chunk capacity at SF1 (NDS_TPU_STREAM_CHUNK_ROWS
 # in chip_smoke.py) and the 1 Mi-row shape the refusals were first seen at
@@ -118,6 +119,32 @@ def test_segment_kernel_compiles_for_v5e(one_chip, kernel, dtype, rows,
         one_chip, lambda g, v: fn(g, v, groups, False),
         ((rows,), jnp.int32), ((rows,), dtype))
     assert "tpu_custom_call" in compiled.as_text()
+
+
+# the narrowed join probe at the multi-fact cell's shapes: store_sales'
+# 4 Mi probe bucket against store_returns' 512 Ki build bucket (a 4 Mi
+# bitmap), survivors at 64 Ki (the REAL bit alone, query25) and 8 Ki
+PROBE_CASES = [
+    ("mask", E._probe_mask_impl,
+     [((1 << 19,), jnp.uint64), ((1 << 22,), jnp.uint64)], {"bits": 22}),
+    ("narrow", E._probe_narrow_impl,
+     [((1 << 22,), jnp.uint64), ((1 << 16,), jnp.int64)], {}),
+    ("widen", E._probe_widen_impl,
+     [((1 << 13,), jnp.int64), ((1 << 13,), jnp.int32),
+      ((1 << 13,), jnp.int32)], {"plen": 1 << 22}),
+]
+
+
+@pytest.mark.parametrize("fn,shapes,static", [c[1:] for c in PROBE_CASES],
+                         ids=[c[0] for c in PROBE_CASES])
+def test_narrowed_probe_compiles_for_v5e(one_chip, fn, shapes, static):
+    """The three jitted bodies the join probe adds around its searches
+    compile for a v5e at SF1's buckets, under their scope name."""
+    args = [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+            for shape, dtype in shapes]
+    lowered = fn.lower(*args, **static)
+    assert "nds.join.candidates" in lowered.as_text(debug_info=True)
+    lowered.compile()
 
 
 def test_fused_scan_int64_lane_is_refused(one_chip):
