@@ -264,19 +264,6 @@ class Smoke:
             return "replay"
         return "eager"
 
-    def fused_arm(self, name, query):
-        """kernelArm of the query's stream spans (fused scan/probe arm),
-        from the Chrome trace nds_power.py wrote."""
-        path = os.path.join(self.out, name, "traces", f"{query}.trace.json")
-        try:
-            with open(path) as f:
-                events = json.load(f)["traceEvents"]
-        except OSError:
-            return None
-        arms = {e["args"]["kernelArm"] for e in events
-                if "kernelArm" in e.get("args", {})}
-        return "/".join(sorted(arms)) or None
-
     def chip_phase(self, phase, extra_env=None, strict_stream=False,
                    want_shards=None, two_pass=True):
         """Cold pass then second pass (two processes) of one phase on the
@@ -337,7 +324,6 @@ class Smoke:
                 cold_compile_ms=cold and c.get("compileMs"),
                 second_compile_ms=w.get("compileMs"),
                 hostSyncs=w.get("hostSyncs"), path=self.executed_path(w),
-                fused_kernel_arm=self.fused_arm(phase, q),
                 peak_bytes_in_use=w.get("peakHbmCumulativeBytes"),
                 scans=[{k: s[k] for k in ("table", "chunks", "path",
                                           "partitions", "shards",

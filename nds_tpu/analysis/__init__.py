@@ -22,9 +22,8 @@ with no device in the loop:
 * :mod:`nds_tpu.analysis.perf_audit` — the static byte/roofline cost
   model over the same decomposition: exact h2d upload bytes (the padded
   encoded-chunk closed form), per-stage HBM traffic, sharded ICI wire
-  bytes from the collective-budget shapes, the fused-kernel launch band,
-  and a roofline lower-bound wall with a ranked bottleneck tag per
-  statement. Exactness is differentially checked against runtime
+  bytes from the collective-budget shapes, and a roofline lower-bound
+  wall with a ranked bottleneck tag per statement. Exactness is differentially checked against runtime
   ``StreamEvent`` byte evidence by ``tools/perf_audit_diff.py``.
 * :mod:`nds_tpu.analysis.driver_audit` — driver-level hygiene for the
   top-level CLIs and ``tools/``: swallowed exceptions, shell-injection
@@ -40,7 +39,7 @@ with no device in the loop:
 * :mod:`nds_tpu.analysis.num_audit` — value-range/precision abstract
   interpreter over the same decomposition: proves per statement that
   every FOR/dict codec fits its priced narrow width, every encoded
-  compare's ``lit - base`` rebase and kernel threshold stays in int64,
+  compare's ``lit - base`` rebase threshold stays in int64,
   no SUM/COUNT/AVG accumulator exceeds int64 / f64-exact-integer range
   through join fan-out, decimal scale is preserved exactly, and the
   hash partition+shard route bits fit the mixed 32-bit width — plus

@@ -193,8 +193,7 @@ _ENGINE_MODULES = ("engine/stream.py", "sql/planner.py", "engine/ops.py",
                    "engine/kernels.py", "engine/exprs.py",
                    "engine/column.py", "engine/table.py",
                    "engine/window.py", "parallel/exchange.py",
-                   "analysis/mem_audit.py", "analysis/kernel_spec.py",
-                   "io/columnar.py")
+                   "analysis/mem_audit.py", "io/columnar.py")
 
 # knobs that are deliberately not pipeline-key members; every entry is a
 # reviewed claim the stress differential can falsify
@@ -214,9 +213,6 @@ _PIPELINE_EXEMPT = {
     "key members (enc_key per column)",
     "NDS_TPU_STREAM_CHUNK_ROWS": "chunk capacity is a key member "
     "(chunk_cap) — the knob only feeds table construction",
-    "NDS_TPU_PALLAS_SMOKE": "build-time smoke-probe toggle: flips "
-    "_pallas_broken, which scan_kernels_active()/_pallas_mode() (key "
-    "members) already reflect",
     "NDS_TPU_MIN_BUCKET": "deliberately import-frozen process-wide "
     "shape contract (ops._MIN_BUCKET, suppressed env-freeze): "
     "mem_audit's live read equals the frozen value under the contract, "
@@ -269,7 +265,6 @@ CACHE_REGISTRY = {
             "NDS_TPU_PALLAS_MAX_GROUPS": "same: group-count gate of "
             "segment kernels, unreachable from scalar expressions",
             "NDS_TPU_EXACT_ONEHOT_BUDGET": "same segment-kernel gate",
-            "NDS_TPU_PALLAS_SMOKE": "same segment-kernel arm surface",
             "NDS_TPU_PAIR_BUDGET": "join-probe bucket budget: joins "
             "never trace inside scalar-expression fusion",
             "NDS_TPU_GROUP_PACK_MIN": "group-by packing: no grouping "

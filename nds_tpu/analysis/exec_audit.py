@@ -243,20 +243,6 @@ class ScanVerdict:
     #                            hashable equi keys)
     coll_final: int = 0        # collective budget at the materializing
     #                            sync: the one cross-shard reduce's ops
-    kernel_scan_chunk: int = 0  # fused Pallas scan-pass launches per
-    #                            chunk (EXACT: 1 iff the shared
-    #                            eligibility rule lowers >=1 chunk-local
-    #                            conjunct under an explicit
-    #                            NDS_TPU_PALLAS mode) — checked against
-    #                            StreamEvent.kernel_launches
-    kernel_stages: int = 0     # fused stages per scan launch (EXACT:
-    #                            eligible conjuncts + the routing-hash
-    #                            stage) == kernel_fused_stages
-    kernel_probe_chunk: int = 0  # UPPER bound on fused join-probe
-    #                            launches per chunk-program dispatch
-    #                            (the graph's hash batches; the probe
-    #                            may decline per batch — f64 keys,
-    #                            oversized dimension)
 
 
 @dataclass
@@ -288,10 +274,7 @@ class ExecReport:
                        "mechanisms": list(s.mechanisms),
                        "shards": s.shards,
                        "a2a_chunk": s.a2a_chunk,
-                       "coll_final": s.coll_final,
-                       "kernel_scan_chunk": s.kernel_scan_chunk,
-                       "kernel_stages": s.kernel_stages,
-                       "kernel_probe_chunk": s.kernel_probe_chunk}
+                       "coll_final": s.coll_final}
                       for s in self.scans],
             "detail": self.detail,
         }
@@ -1050,18 +1033,12 @@ class ExecAuditor:
             n_resid = sum(len(_subquery_nodes(c)) for c in subq)
             shards, a2a_chunk, coll_final = self._collective_budget(
                 parts, keep, conjuncts, cost)
-            k_scan, k_stages, k_probe = self._kernel_budget(
-                parts, keep, filters[keep], conjuncts, mechanisms,
-                hash_batches, len(subq), cost)
             v = ScanVerdict(parts[keep].alias, parts[keep].source or "?",
                             True, (), gate_bound=1,
                             first_sight=len(pk_dims) + 1,
                             mechanisms=tuple(mechanisms),
                             shards=shards, a2a_chunk=a2a_chunk,
-                            coll_final=coll_final,
-                            kernel_scan_chunk=k_scan,
-                            kernel_stages=k_stages,
-                            kernel_probe_chunk=k_probe)
+                            coll_final=coll_final)
             cost.fixed += 1 + subq_cost.fixed + n_resid
             cost.first_sight += v.first_sight + subq_cost.first_sight
         else:
@@ -1095,70 +1072,6 @@ class ExecAuditor:
                 local_scans.append(w)
                 verdicts.append(w)
         return verdicts
-
-    def _kernel_budget(self, parts, keep, chunk_filters, conjuncts,
-                       mechanisms, hash_batches, n_subq, cost):
-        """``(kernel_scan_chunk, kernel_stages, kernel_probe_chunk)`` of
-        one compiled streamed scan — the static fused-Pallas-kernel
-        prediction (DESIGN.md "Fused chunk kernels", sync model: every
-        kernel pass is DEVICE-ONLY, zero host syncs — launches never
-        move any sync bound).
-
-        The scan-pass prediction is EXACT by construction: eligibility
-        is the ONE shared rule (``analysis/kernel_spec.eligible_
-        conjunct``) the runtime lowering applies to the same chunk-local
-        conjuncts, and the hash stage mirrors the executor's partition
-        trigger (forced count + hashable equi keys surviving the column
-        pruning). ``tools/exec_audit_diff.py`` fails when drained
-        ``StreamEvent.kernel_fused_stages`` differs or
-        ``kernel_launches`` falls outside
-        ``[scan x chunks, (scan + probe x P) x chunks]``.
-
-        Predictions are live only under an EXPLICIT ``NDS_TPU_PALLAS``
-        mode (``interpret``/``tpu``): ``auto`` resolves against the
-        backend at runtime, which a host-only auditor cannot see, and a
-        wrong guess would be model drift by construction. Outer-join
-        graphs keep the whole XLA chain (the executor never splits
-        their pre/post conjuncts), mirrored here."""
-        mode = os.environ.get("NDS_TPU_PALLAS", "auto")
-        if mode not in ("interpret", "tpu"):
-            return 0, 0, 0
-        # probe bound: every bound-bucket join in the per-chunk program
-        # may take the fused probe — the plain hash batches, plus one
-        # per deferred outer-BUILD (its matched-pair inner join probes
-        # per dispatch) and one per subquery conjunct (a residual pair
-        # probe, the q16-class EXISTS shape)
-        n_builds = sum(1 for p in parts if p.outer_mech == "outer-build")
-        probe = hash_batches + n_builds + n_subq
-        if any(m in ("outer-gather", "outer-build") for m in mechanisms):
-            return 0, 0, probe
-        from nds_tpu.analysis.kernel_spec import count_eligible
-        rel = parts[keep]
-
-        def class_of(ref):
-            bare = rel.owns(ref)
-            return None if bare is None else rel.classes.get(bare)
-
-        n = count_eligible(chunk_filters, class_of)
-        if n == 0:
-            return 0, 0, probe
-        # hash stage: the executor attaches key slots when the pipeline
-        # partitions (forced count + stream_partition_keys surviving
-        # projection pruning) — same rule shape as _collective_budget
-        from nds_tpu.analysis.mem_audit import (stream_partition_keys,
-                                                stream_partitions_env)
-        hash_stage = 0
-        forced = stream_partitions_env()
-        if forced is not None and forced > 1:
-            part_cols = [{c for cols in p.cols.values() for c in cols}
-                         for p in parts]
-            sources = [p.source for p in parts]
-            keys = stream_partition_keys(part_cols, sources, keep,
-                                         conjuncts)
-            if keys and (cost.needed is None
-                         or all(k in cost.needed for k in keys)):
-                hash_stage = 1
-        return 1, n + hash_stage, probe
 
     def _collective_budget(self, parts, keep, conjuncts, cost):
         """``(shards, a2a_chunk, coll_final)`` of one compiled streamed

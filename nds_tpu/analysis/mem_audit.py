@@ -91,15 +91,6 @@ The lint gate (``hbm-capacity``, ``tools/lint.py``) fails any
 device-resident statement whose peak bound exceeds the configured
 capacity, and any streamed statement whose accumulator bound exceeds it;
 ``--mem-report`` prints the per-statement table.
-
-**Fused Pallas chunk kernels change NOTHING here by design** (DESIGN.md
-"Fused chunk kernels"): the fused scan pass only pre-masks rows the
-recorded graph would have filtered anyway — survivors are a subset, the
-proof-sized accumulators, partition shares and shard slices are reused
-unchanged, and encoded widths stay the priced widths (the kernel
-evaluates predicates ON the codes). ``tools/mem_audit_diff.py``'s
-kernel sweep re-checks every bound on the ``NDS_TPU_PALLAS=interpret``
-arm so this invariant is measured, not assumed.
 """
 
 from __future__ import annotations

@@ -3,10 +3,9 @@
 
 The encoded execution path does all of its hot arithmetic in deliberately
 narrow integer spaces — int16/int32 frame-of-reference offsets, sorted-dict
-codes, ``lit - base`` literal rebasing folded at trace time, Fraction-exact
-threshold math baked into the fused scan kernel, int64 accumulators over
-SF-scale row counts — and every failure mode there is *silent* wraparound,
-not a crash. This module is the sixth abstract interpreter over the
+codes, ``lit - base`` literal rebasing folded at trace time, int64
+accumulators over SF-scale row counts — and every failure mode there is
+*silent* wraparound, not a crash. This module is the sixth abstract interpreter over the
 planner's decomposition (sibling to plan/exec/mem/conc/perf) and proves,
 host-only and per statement:
 
@@ -59,7 +58,7 @@ in comments is an executable check here (:func:`kernel_claim_checks` /
 Lockstep (the standing rule): ``tools/num_audit_diff.py`` builds
 adversarial boundary-value tables (FOR spans at the 2^15/2^31 edges,
 4096-distinct dictionaries, max-scale decimals, hot hash keys), drives
-the A/B sweep across base/kernel/sharded/encoded-off arms demanding
+the A/B sweep across base/sharded/encoded-off arms demanding
 bit-for-bit equality with the plain-width reference, and requires exact
 agreement between these static verdicts and the runtime overflow-flag
 evidence (``StreamEvent.reason``); ``tools/bench_compare.py --audit-num``
@@ -73,11 +72,12 @@ import os
 from dataclasses import dataclass
 from fractions import Fraction
 
+import numpy as np
+
 from nds_tpu.analysis import Finding
 from nds_tpu.analysis.exec_audit import (CLASS_COMPILED, CLASS_UNKNOWN,
                                          ExecAuditor, _AUDIT_SEED,
                                          _conjuncts_of, _has_subquery)
-from nds_tpu.analysis.kernel_spec import parse_days, value_cmp
 from nds_tpu.analysis.mem_audit import (ROW_BOUND_DOMAINS, SPEC_INT_DOMAINS,
                                         MemAuditor, MemModel, _batch_unique_side,
                                         _bucket, _equi_sides, _table_pk,
@@ -99,10 +99,56 @@ I64_MAX = (1 << 63) - 1        # int64 accumulators / threshold scalars
 F64_EXACT = 1 << 53            # largest range where every int is exact f64
 FOR16_SPAN = 1 << 15           # plan_column_codec: int16 FOR iff span < 2^15
 FOR32_SPAN = (1 << 31) - 1     # int32 FOR iff span < 2^31 - 1 (8 B logical)
-HASH_BITS = 32                 # hash_mix produces a uint32
+HASH_BITS = 32                 # stream._hash_mix produces a uint32
 # mirror of engine/exprs._MAX_DEC_SCALE (jax-free here by design; the
 # lockstep unit test pins the two constants equal)
 MAX_DEC_SCALE = 10
+
+
+# ---------------------------------------------------------------------------
+# exact threshold math (value space -> stored/encoded space)
+# ---------------------------------------------------------------------------
+#
+# Ordered comparisons against a rational boundary q reduce to integer
+# thresholds on the stored representation:
+#
+#   v <  q   <=>   v <= ceil(q) - 1
+#   v <= q   <=>   v <= floor(q)
+#   v >  q   <=>   v >= floor(q) + 1
+#   v >= q   <=>   v >= ceil(q)
+#   v =  q   <=>   v == q     (only when q is integral, else FALSE)
+#   v <> q   <=>   v != q     (only when q is integral, else TRUE)
+
+
+def value_cmp(op: str, q: Fraction):
+    """Entry kind + integer threshold of ``value OP q`` in VALUE space:
+    ``("ieq"|"ine"|"ile"|"ige", T)`` or ``("true",)`` / ``("false",)``."""
+    if op == "=":
+        return ("ieq", int(q)) if q.denominator == 1 else ("false",)
+    if op == "<>":
+        return ("ine", int(q)) if q.denominator == 1 else ("true",)
+    if op == "<":
+        return ("ile", math.ceil(q) - 1)
+    if op == "<=":
+        return ("ile", math.floor(q))
+    if op == ">":
+        return ("ige", math.floor(q) + 1)
+    if op == ">=":
+        return ("ige", math.ceil(q))
+    raise ValueError(f"not a comparison op: {op}")
+
+
+_EPOCH = np.datetime64("1970-01-01", "D")
+
+
+def parse_days(text: str) -> int | None:
+    """Days-since-epoch of a date string, or None when unparseable —
+    numerically identical to ``engine/exprs._parse_date`` (both go
+    through ``np.datetime64``)."""
+    try:
+        return int((np.datetime64(str(text), "D") - _EPOCH).astype(int))
+    except Exception:
+        return None
 
 
 # ---------------------------------------------------------------------------
@@ -791,8 +837,8 @@ class NumAuditor:
         return None
 
     def _lit_fraction(self, lit, scale: int):
-        """Scaled-space Fraction of a literal (the exact boundary the
-        kernel lowering rebases), or None for non-numeric literals."""
+        """Scaled-space Fraction of a literal (the exact boundary an
+        encoded compare rebases), or None for non-numeric literals."""
         if isinstance(lit, A.DateLiteral):
             d = parse_days(lit.text)
             return None if d is None else Fraction(d) * 10 ** scale
@@ -812,9 +858,8 @@ class NumAuditor:
     def _check_rebase(self, table: str, col: str, iv: IVal, op: str,
                       q: Fraction) -> None:
         """Prove the FOR-rebased threshold arithmetic exact: the
-        value-space threshold (kernel_spec.value_cmp) and its worst-case
-        rebase ``T - base`` (base ∈ [lo, hi]) must fit int64 — the scalar
-        the fused kernel compares int64-widened codes against, and the
+        value-space threshold (:func:`value_cmp`) and its worst-case
+        rebase ``T - base`` (base ∈ [lo, hi]) must fit int64 — the
         bound under which the saturating trace-time fold
         (``exprs._encoded_compare_views``) is exact."""
         entry = value_cmp(op, q)
@@ -1183,7 +1228,6 @@ def kernel_claim_checks() -> list:
     """Executable versions of ``engine/kernels.py``'s numeric-safety
     claims (host arithmetic only — no jax import). Each failed check is a
     ``num-claim`` finding: the comment would be lying."""
-    import numpy as np
     checks = []
 
     def claim(subject, ok, detail):
@@ -1251,7 +1295,6 @@ def kernel_claim_checks() -> list:
 def codec_claim_checks() -> list:
     """Executable versions of ``io/columnar.py``'s codec claims, driven
     through the REAL ``plan_column_codec`` on boundary-value arrays."""
-    import numpy as np
     import pyarrow as pa
 
     from nds_tpu.io.columnar import DICT_MAX_VALUES, plan_column_codec
@@ -1343,7 +1386,6 @@ def audit_num_template_text(text: str, file: str,
                             auditor: NumAuditor | None = None) -> list:
     """Instantiate one template (pinned seed, shared with the other
     auditors) and prove each statement; returns NumReports."""
-    import numpy as np
     auditor = auditor or NumAuditor()
     sql = instantiate_template(text, np.random.default_rng(_AUDIT_SEED))
     stmts = [s for s in sql.split(";") if s.strip()]

@@ -33,11 +33,8 @@ statement
    program, stage by stage (scan / filter / partition / probe /
    exchange / accumulate). Each per-chunk dispatch re-reads the chunk
    (one mask+compact pass, one hash pass when partitioned, one read per
-   extra partition dispatch); the fused-kernel arm collapses the filter
-   and partition re-reads into the single VMEM scan pass (the PR 12
-   stage model), which is why the arm exists. This is a *model* (XLA
-   fusion may do better) — it feeds the roofline wall, not an equality
-   check.
+   extra partition dispatch). This is a *model* (XLA fusion may do
+   better) — it feeds the roofline wall, not an equality check.
 
 3. **Predicted ICI bytes** — exact from the collective budget's shapes
    (``parallel/exchange.py`` accounts trace-time aval bytes; this
@@ -61,11 +58,10 @@ statement
 Lockstep (the standing rule): every prediction that maps to runtime
 evidence is differentially checked. ``tools/perf_audit_diff.py``
 replays the ``tests/test_synccount.py`` A/B sweep — base, forced-
-partition, 2-shard, fused-kernel and encoded-off arms — and fails when
-measured ``StreamEvent.bytes_h2d`` / ``bytes_ici`` /
-``kernel_launches`` disagree with the static prediction (equality for
-exact predictions, band membership for bounds); ``--inject-drift``
-must fail. ``tools/bench_compare.py --audit-perf`` re-checks a
+partition, 2-shard and encoded-off arms — and fails when measured
+``StreamEvent.bytes_h2d`` / ``bytes_ici`` disagree with the static
+prediction (equality for exact predictions, band membership for
+bounds); ``--inject-drift`` must fail. ``tools/bench_compare.py --audit-perf`` re-checks a
 campaign ledger's recorded evidence against the same predictions, so
 every Power Run lands pre-wired to its static denominator.
 """
@@ -157,8 +153,6 @@ class ScanCost:
     ici_exact: bool = False    # False when outer-build bitmaps ride the
     #                            reduce (priced 0: lower bound) or widths
     #                            are the static stand-ins
-    kernel_min: int = 0        # fused-kernel launch band the measured
-    kernel_max: int = 0        # StreamEvent.kernel_launches must fit
     stages: dict = field(default_factory=dict)  # stage -> HBM bytes
 
     @property
@@ -178,8 +172,6 @@ class ScanCost:
             "partitions": int(self.partitions), "shards": int(self.shards),
             "exchange": self.exchange, "cap_ex": int(self.cap_ex),
             "bytes_ici": int(self.bytes_ici), "ici_exact": self.ici_exact,
-            "kernel_min": int(self.kernel_min),
-            "kernel_max": int(self.kernel_max),
             "stages": {k: int(v) for k, v in self.stages.items()},
             "bytes_hbm": int(self.bytes_hbm),
         }
@@ -281,8 +273,8 @@ def wire_column_widths(table, canonical_types: dict | None = None) -> dict:
 class PerfAuditor:
     """Host-only static cost model over the planner's decomposition.
 
-    Composes :class:`ExecAuditor` (routing, shards, collective/kernel
-    budgets) and :class:`MemAuditor` (row bounds, partition plan, widths)
+    Composes :class:`ExecAuditor` (routing, shards, collective budgets)
+    and :class:`MemAuditor` (row bounds, partition plan, widths)
     rather than walking the AST a third time: one decomposition, three
     interpretations. ``wire_cols`` optionally maps a table name to its
     REAL per-column wire widths (:func:`wire_column_widths`) — the
@@ -438,25 +430,15 @@ class PerfAuditor:
             # composed walk does not surface — priced 0 (lower bound)
             cost.ici_exact = exact_w and n_builds == 0
 
-        # -- fused-kernel launch band ------------------------------------
-        if sv.compiled:
-            cost.kernel_min = sv.kernel_scan_chunk * n_chunks
-            cost.kernel_max = (sv.kernel_scan_chunk
-                               + sv.kernel_probe_chunk * P) * n_chunks
-
         # -- HBM stage model (roofline denominator) ----------------------
         stages = dict.fromkeys(STAGES, 0)
         if sv.compiled:
-            fused = sv.kernel_scan_chunk > 0
             stages["scan"] = n_chunks * chunk_bytes
-            # mask + compact re-read per chunk, folded into the fused
-            # VMEM pass on the Pallas arm (the PR 12 stage collapse)
-            stages["filter"] = 0 if fused else n_chunks * chunk_bytes
+            # mask + compact re-read per chunk
+            stages["filter"] = n_chunks * chunk_bytes
             if P > 1:
-                # radix hash pass re-reads the chunk (fused arm: the
-                # hash stage rides the same VMEM pass)
-                stages["partition"] = 0 if fused \
-                    else n_chunks * chunk_bytes
+                # radix hash pass re-reads the chunk
+                stages["partition"] = n_chunks * chunk_bytes
                 # every extra per-partition dispatch re-reads the chunk
                 stages["probe"] = (P - 1) * n_chunks * chunk_bytes
             if cost.exchange:

@@ -25,10 +25,8 @@ MXU; the engine's exact int64 decimal path keeps using the XLA fallback
 
 from __future__ import annotations
 
-import contextlib
 import functools
 import os
-import threading
 
 import jax
 import jax.numpy as jnp
@@ -443,604 +441,3 @@ def segment_minmax_fused(values, gids, num_segments: int):
     maxs = jax.ops.segment_max(jnp.where(live, v, -_F32_MAX), safe,
                                num_segments=num_segments)
     return mins, maxs
-
-
-# ---------------------------------------------------------------------------
-# fused chunk-scan pass (decode -> filter -> hash -> partition/shard ids)
-# ---------------------------------------------------------------------------
-#
-# The streamed per-chunk program used to evaluate its chunk-local
-# predicates, the _hash_mix partition hash, and the survivor mask as a
-# chain of generic XLA elementwise ops — each stage re-reading the chunk
-# from HBM. fused_chunk_scan makes ONE VMEM-resident pass over each
-# padded chunk tile: FOR/sorted-dict decode stays IMPLICIT (ordered
-# predicates are rebased into encoded space at lower time, so the kernel
-# compares raw stored codes; only the float lane decodes), every lowered
-# conjunct evaluates on the tile in VMEM, and the same pass folds the
-# partition hash whose low bits pick the partition and next bits the
-# destination shard — the ids the exchange consumes unchanged. The
-# TPU-native analogue of operating directly on compressed data inside
-# the kernel ("GPU Acceleration of SQL Analytics on Compressed Data",
-# PAPERS.md).
-#
-# The spec (engine/exprs.lower_scan_spec) is extracted ONCE at pipeline
-# record time from the chunk-local WHERE conjuncts, so the kernel is
-# chunk-invariant and pipeline-cacheable; eligibility is the shared rule
-# in analysis/kernel_spec.py (the exec_audit lockstep). The XLA op chain
-# stays the always-available fallback (NDS_TPU_PALLAS=off, non-lowerable
-# conjuncts fall back per-conjunct), bit-for-bit A/B'd under
-# NDS_TPU_STREAM_STRICT=1.
-#
-# Entry opcodes (one entry per lowered conjunct; thresholds already in
-# STORED space — analysis/kernel_spec.py does the exact rational math):
-#
-#   ("ieq"|"ine"|"ile"|"ige", ci, T)     int lane, raw stored codes
-#   ("irange", ci, lo, hi)               BETWEEN (negated: "nrange")
-#   ("iin"|"inotin", ci, values)         IN-list membership
-#   ("isnull"|"notnull", ci)             validity only
-#   ("true"|"false", ci)                 constant contribution (& valid)
-#   ("feq"|"fne"|"flt"|"fle"|"fgt"|"fge", ci, L)
-#                                        float lane: decode per col meta
-#                                        (_as_f64 semantics), compare f64
-#   ("fin"|"fnotin", ci, values)         float-lane IN-list membership
-#   ("frange"|"fnrange", ci, lo, hi)     float-lane BETWEEN (f64 columns
-#                                        / float bounds)
-
-
-class ScanSpec:
-    """Chunk-invariant description of one fused scan pass.
-
-    ``cols`` holds per-referenced-column metadata
-    ``(data_slot, valid_slot, fmode, base, tbl_idx, sdiv)`` — slots index
-    the pipeline's flattened chunk buffers (valid_slot -1 = no mask);
-    ``fmode``/``base``/``tbl_idx``/``sdiv`` describe the float-lane
-    decode ("id" | "for" | "dict", FOR base, dict table index, the
-    10**scale divisor). ``tables`` are the sorted dict value tables the
-    float lane gathers (host arrays, chunk-invariant like string
-    dictionaries). ``key_slots`` are the chunk buffers the partition
-    hash folds (empty = no hash output)."""
-
-    __slots__ = ("entries", "n_conjuncts", "cols", "tables", "key_slots")
-
-    def __init__(self, entries, cols, tables=(), key_slots=(),
-                 n_conjuncts=None):
-        self.entries = tuple(entries)
-        # a conjunct may lower to SEVERAL entries (mixed-lane BETWEEN),
-        # so the stage count tracks CONJUNCTS, matching the static
-        # prediction's count_eligible
-        self.n_conjuncts = len(self.entries) if n_conjuncts is None \
-            else n_conjuncts
-        self.cols = tuple(cols)
-        self.tables = tuple(tables)
-        self.key_slots = tuple(key_slots)
-
-    def stages(self) -> int:
-        """Fused stage count of one launch: one per lowered conjunct
-        plus the hash stage — the number ``StreamEvent.kernel_fused_
-        stages`` reports and exec_audit predicts."""
-        return self.n_conjuncts + (1 if self.key_slots else 0)
-
-
-def hash_mix(h, data):
-    """Fold one key column into the per-row partition hash (uint32) —
-    THE partition/shard routing hash (moved here from engine/stream.py
-    so the fused kernel and the XLA partition pass share one
-    definition; any drift would route rows differently per arm).
-    Dictionary codes hash as their int32 codes (the whole-table encoding
-    makes them value-stable across chunks); floats hash their bit
-    pattern. Multiplicative mixing — any chunk-row partitioning keeps
-    the per-partition bound valid, the hash only evens the shares.
-    The 32 mixed bits are split into DISJOINT route windows (low
-    ``log2(P)`` bits pick the partition, the next ``log2(S)`` bits the
-    shard — engine/stream.py); both env knobs are clamped so the two
-    windows always fit: checked per statement (``hash-bits``) and at the
-    clamp itself by ``analysis/num_audit.kernel_claim_checks``."""
-    if jnp.issubdtype(data.dtype, jnp.floating):
-        data = jax.lax.bitcast_convert_type(
-            data, jnp.int64 if data.dtype.itemsize == 8 else jnp.int32)
-    x = data.astype(jnp.int64)
-    lo = (x & jnp.int64(0xffffffff)).astype(jnp.uint32)
-    hi = ((x >> 32) & jnp.int64(0xffffffff)).astype(jnp.uint32)
-    h = (h ^ lo) * jnp.uint32(2654435761)
-    h = h ^ (h >> 16)
-    h = (h ^ hi) * jnp.uint32(2246822519)
-    return h ^ (h >> 13)
-
-
-def _eval_entries(spec: ScanSpec, datas, valids, tables):
-    """Survivor mask of one tile (or whole buffer): AND of every lowered
-    conjunct's contribution. Shared by the Pallas kernel body and the
-    pure-jnp reference (scan_reference), so the two arms cannot drift.
-    ``datas``/``valids`` are per-spec-col arrays (valids[i] None when the
-    column has no mask); all boolean logic mirrors the eager engine's
-    ``mask & data & valid_mask`` WHERE contract exactly."""
-    shape = datas[0].shape
-    m = jnp.ones(shape, dtype=bool)
-
-    def vmask(ci):
-        v = valids[ci]
-        return jnp.ones(shape, dtype=bool) if v is None else v
-
-    for e in spec.entries:
-        kind, ci = e[0], e[1]
-        if kind == "false":
-            m = jnp.zeros(shape, dtype=bool)
-            continue
-        if kind == "true":
-            m = m & vmask(ci)
-            continue
-        if kind == "isnull":
-            m = m & ~vmask(ci)
-            continue
-        if kind == "notnull":
-            m = m & vmask(ci)
-            continue
-        if kind[0] == "i":
-            x = datas[ci].astype(jnp.int64)
-            if kind == "ieq":
-                c = x == e[2]
-            elif kind == "ine":
-                c = x != e[2]
-            elif kind == "ile":
-                c = x <= e[2]
-            elif kind == "ige":
-                c = x >= e[2]
-            elif kind == "irange":
-                c = (x >= e[2]) & (x <= e[3])
-            elif kind == "iin":
-                c = jnp.zeros(shape, dtype=bool)
-                for v in e[2]:
-                    c = c | (x == v)
-            elif kind == "inotin":
-                c = jnp.ones(shape, dtype=bool)
-                for v in e[2]:
-                    c = c & (x != v)
-            else:
-                raise ValueError(f"unknown scan entry {kind!r}")
-            m = m & c & vmask(ci)
-            continue
-        if kind == "nrange":
-            x = datas[ci].astype(jnp.int64)
-            m = m & ~((x >= e[2]) & (x <= e[3])) & vmask(ci)
-            continue
-        if kind[0] == "f":
-            _ds, _vs, fmode, base, tbl, sdiv = spec.cols[ci]
-            d = datas[ci]
-            if fmode == "for":
-                val = (d.astype(jnp.int64) + base).astype(jnp.float64)
-            elif fmode == "dict":
-                val = jnp.take(tables[tbl], d, mode="clip").astype(
-                    jnp.float64)
-            else:
-                val = d.astype(jnp.float64)
-            if sdiv != 1.0:
-                val = val / sdiv
-            if kind == "fin" or kind == "fnotin":
-                c = jnp.zeros(shape, dtype=bool)
-                for v in e[2]:
-                    c = c | (val == v)
-                if kind == "fnotin":
-                    c = ~c
-                m = m & c & vmask(ci)
-                continue
-            if kind == "frange" or kind == "fnrange":
-                c = (val >= e[2]) & (val <= e[3])
-                if kind == "fnrange":
-                    c = ~c
-                m = m & c & vmask(ci)
-                continue
-            L = e[2]
-            if kind == "feq":
-                c = val == L
-            elif kind == "fne":
-                c = val != L
-            elif kind == "flt":
-                c = val < L
-            elif kind == "fle":
-                c = val <= L
-            elif kind == "fgt":
-                c = val > L
-            else:
-                c = val >= L
-            m = m & c & vmask(ci)
-            continue
-        raise ValueError(f"unknown scan entry {kind!r}")
-    return m
-
-
-def _fold_hash(keybufs):
-    h = jnp.full(keybufs[0].shape, 2166136261, dtype=jnp.uint32)
-    for kb in keybufs:
-        h = hash_mix(h, kb)
-    return h
-
-
-# scan-pass row tile (lane-width multiple; the pass is pure VPU)
-_TR_SCAN = 512
-
-
-def _scan_inputs(chunk_flat, spec: ScanSpec):
-    """(datas, valids, keybufs, tables) pulled from the pipeline's
-    flattened chunk buffers per the spec's slots."""
-    datas = [chunk_flat[c[0]] for c in spec.cols]
-    valids = [None if c[1] < 0 else chunk_flat[c[1]] for c in spec.cols]
-    keybufs = [chunk_flat[s] for s in spec.key_slots]
-    import numpy as np
-    tables = [jnp.asarray(np.asarray(t)) for t in spec.tables]
-    return datas, valids, keybufs, tables
-
-
-def scan_reference(chunk_flat, n_dev, spec: ScanSpec):
-    """Pure-jnp twin of :func:`fused_chunk_scan` (same shared entry
-    evaluation, no Pallas): the parity oracle the kernel unit tests pin,
-    and the documentation of exactly what the kernel computes."""
-    datas, valids, keybufs, tables = _scan_inputs(chunk_flat, spec)
-    plen = datas[0].shape[0]
-    mask = _eval_entries(spec, datas, valids, tables)
-    mask = mask & (jnp.arange(plen) < n_dev)
-    h = _fold_hash(keybufs) if keybufs else None
-    return mask, h
-
-
-@_trace.scoped("kernel.chunk_scan")
-def fused_chunk_scan(chunk_flat, n_dev, spec: ScanSpec, interpret: bool):
-    """ONE Pallas pass over the padded chunk: every referenced buffer
-    crosses HBM->VMEM once, the lowered conjuncts and the partition hash
-    evaluate on the resident tile, and the survivor mask (+ uint32 hash
-    when the graph partitions/exchanges) come back for the compaction
-    scatter. Traced inside the pipeline's jitted pre-pass — zero host
-    syncs by construction (the `host-read-in-pallas` lint rule polices
-    the kernel bodies).
-
-    Mosaic refuses this kernel as written (int64 lanes, the 64-bit casts
-    of the hash fold, the 1-D dict gather — see ``scan_kernels_active``),
-    so it runs in interpret mode only."""
-    datas, valids, keybufs, tables = _scan_inputs(chunk_flat, spec)
-    plen = datas[0].shape[0]
-    n_pad = max(_ceil_to(plen, _TR_SCAN), _TR_SCAN)
-
-    def pad(x):
-        if x is None:
-            return None
-        y = jnp.zeros(n_pad, dtype=x.dtype).at[:plen].set(x)
-        return y.reshape(1, n_pad)
-
-    datas_p = [pad(d) for d in datas]
-    valids_p = [pad(v) for v in valids if v is not None]
-    valid_pos = {}
-    j = 0
-    for i, v in enumerate(valids):
-        if v is not None:
-            valid_pos[i] = j
-            j += 1
-    keybufs_p = [pad(k) for k in keybufs]
-    tabs_p = []
-    for t in tables:
-        t_pad = max(_ceil_to(t.shape[0], 128), 128)
-        tabs_p.append(jnp.zeros(t_pad, dtype=t.dtype).at[:t.shape[0]]
-                      .set(t).reshape(1, t_pad))
-    emit_hash = bool(keybufs)
-    nd, nv, nk, nt = (len(datas_p), len(valids_p), len(keybufs_p),
-                      len(tabs_p))
-
-    def kernel(*refs):
-        ins = refs[:nd + nv + nk + nt]
-        outs = refs[nd + nv + nk + nt:]
-        d_tiles = [ins[i][:] for i in range(nd)]
-        v_tiles = [None if i not in valid_pos
-                   else ins[nd + valid_pos[i]][:] for i in range(nd)]
-        k_tiles = [ins[nd + nv + i][:] for i in range(nk)]
-        t_full = [ins[nd + nv + nk + i][:].reshape(-1) for i in range(nt)]
-        outs[0][:] = _eval_entries(spec, d_tiles, v_tiles, t_full)
-        if emit_hash:
-            outs[1][:] = _fold_hash(k_tiles)
-
-    grid = (n_pad // _TR_SCAN,)
-    tile = lambda i: (i - i, i)          # noqa: E731 — i32 grid index
-    whole = lambda i: (i - i, i - i)     # noqa: E731
-    in_specs = [pl.BlockSpec((1, _TR_SCAN), tile)
-                for _ in range(nd + nv + nk)]
-    in_specs += [pl.BlockSpec((1, int(t.shape[1])), whole) for t in tabs_p]
-    out_specs = [pl.BlockSpec((1, _TR_SCAN), tile)]
-    out_shape = [jax.ShapeDtypeStruct((1, n_pad), jnp.bool_)]
-    if emit_hash:
-        out_specs.append(pl.BlockSpec((1, _TR_SCAN), tile))
-        out_shape.append(jax.ShapeDtypeStruct((1, n_pad), jnp.uint32))
-    got = pl.pallas_call(
-        kernel, grid=grid, in_specs=in_specs, out_specs=out_specs,
-        out_shape=out_shape, interpret=interpret,
-    )(*datas_p, *valids_p, *keybufs_p, *tabs_p)
-    mask = got[0][0, :plen] & (jnp.arange(plen) < n_dev)
-    h = got[1][0, :plen] if emit_hash else None
-    note_launch(spec.stages())
-    return mask, h
-
-
-def scan_kernels_active() -> bool:
-    """True when pipeline builds should extract a scan spec and route
-    the per-chunk hot path through :func:`fused_chunk_scan`. Callers
-    gate on this (and :func:`probe_kernel_active` builds on it).
-
-    Interpret mode only: the fused scan and the fused probe are OFF on
-    the chip by decision, not by a caught exception. Asked at a 1 Mi-row
-    shape (x64 on, jax 0.9.0 / libtpu 0.0.34), the v5e compiler refuses
-    both as written:
-
-    * ``fused_chunk_scan``, int64 lane: ``UNIMPLEMENTED: While rewriting
-      computation to not contain X64 element types ...
-      custom_call_target="tpu_custom_call",
-      operand_layout_constraints={s64[1,1048576]}``;
-    * int32 lane + validity + ``key_slots``: ``NotImplementedError:
-      64-bit types are not supported`` (the ``_fold_hash``/``n_dev`` lanes);
-    * int16 dict codes on the float lane: ``NotImplementedError: Only 2D
-      gather is supported``;
-    * ``fused_probe``, int64 keys over a uint64 hash table:
-      ``NotImplementedError: 64-bit types are not supported``.
-
-    tests/test_tpu_compile.py pins the four refusals; the day one of them
-    compiles, that test fails and this gate is where the kernel comes
-    back (ROADMAP Queue 1 item 6 / Queue 3 item 4: 32-bit lanes).
-    ``NDS_TPU_PALLAS=interpret`` keeps both reachable for the parity
-    tests and the diff harnesses."""
-    return not _pallas_broken and _pallas_mode() == "interpret"
-
-
-def scan_spec_ready(spec: ScanSpec, chunk_flat, plen: int) -> bool:
-    """Smoke-run one fused scan over zeroed buffers of the real chunk
-    shapes at pipeline-BUILD time (eager, one tile's work, result
-    discarded — no host read), so a spec the interpreter cannot run
-    degrades to the XLA chain BEFORE any compiled pipeline bakes the
-    kernel in, never mid-drive."""
-    if not scan_kernels_active():
-        return False
-    try:
-        dummy = tuple(
-            None if x is None else jnp.zeros((plen,), dtype=x.dtype)
-            for x in chunk_flat)
-        fused_chunk_scan(dummy, jnp.asarray(plen, dtype=jnp.int64), spec,
-                         interpret=True)
-        return True
-    except Exception as e:
-        _kernel_failed("fused chunk-scan", e)
-        return False
-
-
-# ---------------------------------------------------------------------------
-# fused bound-bucket join probe
-# ---------------------------------------------------------------------------
-#
-# The stream-bounds join's probe phase hashes the chunk side's key
-# columns and binary-searches the hash-sorted dimension side — under XLA
-# that is one HBM pass per key column plus one per searchsorted. The
-# fused probe replicates ops._key_hash_impl BITWISE on the resident tile
-# (same _mix64 constants, same null/pad/exclusion sentinels) and runs
-# both searchsorted sides against the dimension hash table held whole in
-# VMEM, emitting the (lo, counts) pair the bound-bucket expansion
-# consumes unchanged — candidate counts are identical to the XLA path's
-# by construction, so overflow accounting cannot move between arms.
-
-# dimension buckets past this stay on XLA: the whole sorted hash table
-# rides VMEM per grid cell (8B/row)
-_PROBE_MAX_R = 1 << 15
-
-
-def _probe_hash_tile(views, valids, excluded, rows, n_valid):
-    """uint64 key hash of one tile — ops._key_hash_impl, restated on
-    resident arrays (int views only; f64 keys stay on XLA). ``rows`` are
-    the tile's global row indices (pad/side sentinels must be per-ROW
-    unique exactly like the XLA hash so nothing collides)."""
-    import numpy as np
-    _C1 = jnp.uint64(0x9E3779B97F4A7C15)
-    _C2 = jnp.uint64(0xBF58476D1CE4E5B9)
-    _C3 = jnp.uint64(0x94D049BB133111EB)
-
-    def mix64(x):
-        x = x.astype(jnp.uint64)
-        x = (x ^ (x >> 30)) * _C2
-        x = (x ^ (x >> 27)) * _C3
-        return x ^ (x >> 31)
-
-    shape = views[0].shape
-    h = jnp.full(shape, jnp.uint64(0x243F6A8885A308D3), dtype=jnp.uint64)
-    any_null = jnp.zeros(shape, dtype=bool)
-    for v, valid in zip(views, valids):
-        w = v.astype(jnp.uint64)
-        if valid is not None:
-            w = jnp.where(valid, w, jnp.uint64(0))
-            marker = jnp.where(valid, jnp.uint64(0),
-                               jnp.uint64(0xA5A5A5A5A5A5A5A5))
-            any_null = any_null | ~valid
-        else:
-            marker = jnp.zeros(shape, dtype=jnp.uint64)
-        h = mix64(h ^ marker)
-        h = mix64(h ^ w * _C1)
-    unmatchable = any_null | (rows >= n_valid)
-    if excluded is not None:
-        unmatchable = unmatchable | excluded
-    # side_salt 0 (probe side): sentinel = 2 + (row << 3); REAL bit 4
-    sentinel = jnp.uint64(2) + (rows.astype(jnp.uint64) << jnp.uint64(3))
-    return jnp.where(unmatchable, sentinel, h | jnp.uint64(4))
-
-
-def probe_reference(views, valids, n_valid, excluded, rh_sorted):
-    """Pure-jnp twin of :func:`fused_probe` (parity oracle)."""
-    n = views[0].shape[0]
-    rows = jnp.arange(n)
-    lh = _probe_hash_tile(views, valids, excluded, rows, n_valid)
-    lo = jnp.searchsorted(rh_sorted, lh, side="left")
-    hi = jnp.searchsorted(rh_sorted, lh, side="right")
-    return hi - lo, lo
-
-
-def probe_kernel_active(views, valids, plen_r: int) -> bool:
-    """Gate for the fused probe: interpret mode only (Mosaic refuses it,
-    see :func:`scan_kernels_active`), int key views only, and the
-    dimension hash table small enough to hold whole in VMEM. Callers
-    fall back to the XLA probe whenever this says no."""
-    if not scan_kernels_active():
-        return False
-    if plen_r > _PROBE_MAX_R:
-        return False
-    return all(v.dtype != jnp.float64 for v in views)
-
-
-@_trace.scoped("kernel.probe")
-def fused_probe(views, valids, n_valid, excluded, rh_sorted,
-                interpret: bool):
-    """(counts, lo) of the bound-bucket probe in ONE VMEM pass per chunk
-    tile: key hash (bitwise ops._key_hash_impl) + both binary-search
-    sides against the resident dimension hash table."""
-    n = views[0].shape[0]
-    n_pad = max(_ceil_to(n, _TR_SCAN), _TR_SCAN)
-    r = rh_sorted.shape[0]
-    r_pad = max(_ceil_to(r, 128), 128)
-    # pads sort above every real hash (max uint64): searchsorted of any
-    # real probe value lands below them
-    rh_p = jnp.full(r_pad, jnp.uint64(0xFFFFFFFFFFFFFFFF),
-                    dtype=jnp.uint64).at[:r].set(rh_sorted).reshape(
-        1, r_pad)
-
-    def pad(x, fill=0):
-        return jnp.full(n_pad, fill, dtype=x.dtype).at[:n].set(x).reshape(
-            1, n_pad)
-
-    views_p = [pad(v) for v in views]
-    valid_list = [v for v in valids if v is not None]
-    valids_p = [pad(v) for v in valid_list]
-    vpos = {}
-    j = 0
-    for i, v in enumerate(valids):
-        if v is not None:
-            vpos[i] = j
-            j += 1
-    excl_p = None if excluded is None else pad(excluded, True)
-    nviews, nvalid = len(views_p), len(valids_p)
-    nv_arr = jnp.asarray(n_valid, dtype=jnp.int64).reshape(1, 1)
-
-    def kernel(*refs):
-        i = pl.program_id(0)
-        k = 0
-        v_tiles = [refs[k + j][:] for j in range(nviews)]
-        k += nviews
-        valid_tiles = [None if j not in vpos
-                       else refs[k + vpos[j]][:] for j in range(nviews)]
-        k += nvalid
-        if excl_p is not None:
-            excl_tile = refs[k][:]
-            k += 1
-        else:
-            excl_tile = None
-        rh_full = refs[k][:].reshape(-1)
-        k += 1
-        nv = refs[k][0, 0]
-        k += 1
-        cnt_ref, lo_ref = refs[k], refs[k + 1]
-        rows = i * _TR_SCAN + jax.lax.broadcasted_iota(
-            jnp.int64, (1, _TR_SCAN), 1)
-        lh = _probe_hash_tile(v_tiles, valid_tiles, excl_tile, rows, nv)
-        lo = jnp.searchsorted(rh_full, lh.reshape(-1), side="left")
-        hi = jnp.searchsorted(rh_full, lh.reshape(-1), side="right")
-        cnt_ref[:] = (hi - lo).reshape(1, _TR_SCAN).astype(jnp.int64)
-        lo_ref[:] = lo.reshape(1, _TR_SCAN).astype(jnp.int64)
-
-    grid = (n_pad // _TR_SCAN,)
-    tile = lambda i: (i - i, i)          # noqa: E731
-    whole = lambda i: (i - i, i - i)     # noqa: E731
-    in_specs = [pl.BlockSpec((1, _TR_SCAN), tile)
-                for _ in range(nviews + nvalid)]
-    if excl_p is not None:
-        in_specs.append(pl.BlockSpec((1, _TR_SCAN), tile))
-    in_specs.append(pl.BlockSpec((1, r_pad), whole))
-    in_specs.append(pl.BlockSpec((1, 1), whole))
-    args = [*views_p, *valids_p]
-    if excl_p is not None:
-        args.append(excl_p)
-    args += [rh_p, nv_arr]
-    counts, lo = pl.pallas_call(
-        kernel, grid=grid, in_specs=in_specs,
-        out_specs=[pl.BlockSpec((1, _TR_SCAN), tile),
-                   pl.BlockSpec((1, _TR_SCAN), tile)],
-        out_shape=[jax.ShapeDtypeStruct((1, n_pad), jnp.int64),
-                   jax.ShapeDtypeStruct((1, n_pad), jnp.int64)],
-        interpret=interpret,
-    )(*args)
-    note_probe()
-    return counts[0, :n], lo[0, :n]
-
-
-_probe_smoke_ok: bool | None = None
-
-
-def try_fused_probe(left_keys, lviews, lvalids, n_valid, excluded,
-                    rh_sorted):
-    """The ops.py seam: (counts, lo) through the fused probe, or None
-    when the gate declines (always, outside interpret mode) or a
-    one-time eager smoke run fails, so an error can never surface
-    mid-pipeline-drive."""
-    global _probe_smoke_ok
-    if not probe_kernel_active(lviews, lvalids, int(rh_sorted.shape[0])):
-        return None
-    if any(lk.kind == "f64" for lk in left_keys):
-        return None
-    if _probe_smoke_ok is None:
-        try:
-            v = jnp.zeros(4, dtype=jnp.int64)
-            rh = jnp.zeros(4, dtype=jnp.uint64)
-            fused_probe((v,), (None,), jnp.asarray(4, dtype=jnp.int64),
-                        None, rh, interpret=True)
-            _probe_smoke_ok = True
-        except Exception as e:
-            _probe_smoke_ok = False
-            _kernel_failed("fused join-probe", e)
-    if not _probe_smoke_ok:
-        return None
-    return fused_probe(lviews, lvalids, n_valid, excluded, rh_sorted,
-                       interpret=True)
-
-
-# ---------------------------------------------------------------------------
-# trace-time kernel accounting + the Pallas-vs-XLA arm surface
-# ---------------------------------------------------------------------------
-
-_kern_tls = threading.local()
-
-
-@contextlib.contextmanager
-def kernel_trace():
-    """Count fused-kernel launches while tracing one compiled program —
-    the same trace-time pattern as parallel.exchange.collective_trace:
-    a kernel traced into a jit program launches once per dispatch, so
-    counting at trace time gives exact per-dispatch evidence at zero
-    runtime cost. ``counts``: {"launches", "stages", "probes"}."""
-    prev = getattr(_kern_tls, "counts", None)
-    _kern_tls.counts = {"launches": 0, "stages": 0, "probes": 0}
-    try:
-        yield _kern_tls.counts
-    finally:
-        _kern_tls.counts = prev
-
-
-def note_launch(stages: int) -> None:
-    c = getattr(_kern_tls, "counts", None)
-    if c is not None:
-        c["launches"] += 1
-        c["stages"] += stages
-
-
-def note_probe() -> None:
-    c = getattr(_kern_tls, "counts", None)
-    if c is not None:
-        c["launches"] += 1
-        c["probes"] += 1
-
-
-def active_arm() -> str:
-    """"pallas" | "xla": the arm the FUSED chunk kernels (scan pass, join
-    probe) take for this process right now — "xla" on a chip, where
-    Mosaic refuses them (:func:`scan_kernels_active`), "pallas" only in
-    interpret mode. Surfaced as the ``kernelArm`` annotation on every
-    ``stream`` span so tools/trace_report.py can attribute fused-kernel
-    coverage (and price fused-vs-XLA) per query. The segment kernels'
-    arm is :func:`_pallas_mode` itself (the Power ledger's ``pallas``
-    meta field)."""
-    return "pallas" if scan_kernels_active() else "xla"

@@ -1576,18 +1576,7 @@ def _probe_candidates(left_keys, right_keys, null_safe=False,
         # chunk-invariant program: no data-dependent sizing sync. The
         # caller sizes its pair bucket from static bounds and registers a
         # device-side overflow flag (checked at the pipeline's single
-        # materializing sync). The probe side may take the fused Pallas
-        # bound-bucket probe (one VMEM pass: bitwise _key_hash_impl +
-        # both searchsorted sides against the resident dimension hash
-        # table) — candidate counts are identical by construction, so
-        # the XLA arm below stays the always-available fallback.
-        if not null_safe:
-            from nds_tpu.engine.kernels import try_fused_probe
-            got = try_fused_probe(left_keys, lviews, lvalids,
-                                  count_arr(n_left), l_excl, rh_sorted)
-            if got is not None:
-                counts, lo = got
-                return counts, lo, order, None
+        # materializing sync).
         lh = _key_hash_impl(lviews, lvalids, 0, null_safe,
                             count_arr(n_left), l_excl)
         lo = jnp.searchsorted(rh_sorted, lh, side="left")

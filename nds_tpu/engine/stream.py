@@ -444,10 +444,28 @@ def _logical_chunk_bytes(chunk_spec, chunk_cap, n_chunks) -> int:
     return per_row * chunk_cap * max(n_chunks, 0)
 
 
-# THE partition/shard routing hash, shared with the fused Pallas scan
-# kernel (engine/kernels.hash_mix) so both arms route rows identically —
-# per-partition evidence must be bit-for-bit between NDS_TPU_PALLAS arms
-_hash_mix = _K.hash_mix
+def _hash_mix(h, data):
+    """Fold one key column into the per-row partition hash (uint32) —
+    THE partition/shard routing hash of the partition pass and the
+    exchange pass. Dictionary codes hash as their int32 codes (the
+    whole-table encoding makes them value-stable across chunks); floats
+    hash their bit pattern. Multiplicative mixing — any chunk-row
+    partitioning keeps the per-partition bound valid, the hash only
+    evens the shares. The 32 mixed bits are split into DISJOINT route
+    windows (low ``log2(P)`` bits pick the partition, the next
+    ``log2(S)`` bits the shard); both env knobs are clamped so the two
+    windows always fit: checked per statement (``hash-bits``) and at the
+    clamp itself by ``analysis/num_audit.kernel_claim_checks``."""
+    if jnp.issubdtype(data.dtype, jnp.floating):
+        data = jax.lax.bitcast_convert_type(
+            data, jnp.int64 if data.dtype.itemsize == 8 else jnp.int32)
+    x = data.astype(jnp.int64)
+    lo = (x & jnp.int64(0xffffffff)).astype(jnp.uint32)
+    hi = ((x >> 32) & jnp.int64(0xffffffff)).astype(jnp.uint32)
+    h = (h ^ lo) * jnp.uint32(2654435761)
+    h = h ^ (h >> 16)
+    h = (h ^ hi) * jnp.uint32(2246822519)
+    return h ^ (h >> 13)
 
 
 class StreamPipeline:
@@ -467,7 +485,7 @@ class StreamPipeline:
                  residuals=(), resid_specs=(), build_slots=(),
                  name_catalog=None, n_shards=1, mesh=None,
                  mesh_axis="shard", exchange=False, cap_ex=0,
-                 scan_spec=None, param_nodes=(), param_tags=()):
+                 param_nodes=(), param_tags=()):
         self.chunk_spec = chunk_spec      # ((aliased name, kind, dict), ...)
         self.chunk_cap = chunk_cap
         self.part_specs = part_specs      # specs of non-streamed parts
@@ -507,10 +525,6 @@ class StreamPipeline:
         # per-shard physical chunk length the compiled program sees
         self.body_plen = chunk_cap if n_shards == 1 else \
             (n_shards * cap_ex if exchange else chunk_cap // n_shards)
-        # fused Pallas chunk-scan pass (DESIGN.md "Fused chunk kernels"):
-        # the chunk-invariant predicate/codec spec extracted at record
-        # time (engine/exprs.lower_scan_spec); None = XLA chain only
-        self.scan_spec = scan_spec
         # parameter binding (DESIGN.md "Parameterized plans"): the build
         # statement's audited-bindable Literal AST nodes, kept alive here
         # so their id()s stay stable for the compiled program's lifetime.
@@ -523,7 +537,6 @@ class StreamPipeline:
         self.param_tags = tuple(param_tags)
         self.jitted = None
         self._pid_jit = None
-        self._scan_jit = None
         self._exch_jit = None
         self._reduce_jit = None
         # explicit-collective accounting per compiled program, captured
@@ -532,12 +545,6 @@ class StreamPipeline:
         self.coll_chunk = None
         self.coll_exchange = None
         self.coll_reduce = None
-        # fused-kernel launch accounting, captured the same trace-time
-        # way (kernels.kernel_trace): launches per scan pre-pass / per
-        # chunk-program dispatch — the evidence exec_audit's static
-        # kernel prediction is checked against
-        self.kern_scan = None
-        self.kern_chunk = None
         # first jitted dispatch traces+compiles the per-chunk program;
         # the trace layer labels that dispatch "stream.compile"
         self.traced_once = False
@@ -669,36 +676,11 @@ class StreamPipeline:
         # (chunk in flight) + (chunk uploading) + ONE accumulator copy
         # per partition (the partition mask routes each dispatch to its
         # own accumulator, donated through)
-        scan_spec = self.scan_spec
-        # the Pallas mode is a pipeline-cache-key member, so freezing it
-        # at compile time is consistent with the program's lifetime
-        interp = _K._pallas_mode() == "interpret"
         if self.n_shards == 1:
             self.jitted = jax.jit(traced, donate_argnums=(4,))
 
             if n_partitions > 1:
                 P = n_partitions
-
-                if scan_spec is not None:
-                    @_obs.scoped("stream.kernel")
-                    def scanpid_fn(chunk_flat, n_dev, hist):
-                        # ONE fused VMEM pass: predicates + partition
-                        # hash; the histogram keeps its pre-filter
-                        # semantics (counts every LIVE row, not just
-                        # predicate survivors — part_input evidence is
-                        # identical between Pallas arms)
-                        mask, h = _K.fused_chunk_scan(chunk_flat, n_dev,
-                                                      scan_spec, interp)
-                        pids = (h & jnp.uint32(P - 1)).astype(jnp.int32)
-                        live = jnp.arange(chunk_cap) < n_dev
-                        counts = jnp.bincount(jnp.where(live, pids, P),
-                                              length=P + 1)[:P]
-                        return (mask, pids,
-                                hist + counts.astype(hist.dtype))
-
-                    self._scan_jit = jax.jit(scanpid_fn,
-                                             donate_argnums=(2,))
-                    return self
 
                 @_obs.scoped("stream.partition")
                 def pid_fn(chunk_flat, n_dev, hist):
@@ -715,14 +697,6 @@ class StreamPipeline:
                 # the device-resident input histogram (donated through) —
                 # no host syncs anywhere in it
                 self._pid_jit = jax.jit(pid_fn, donate_argnums=(2,))
-            elif scan_spec is not None:
-                @_obs.scoped("stream.kernel")
-                def scan_fn(chunk_flat, n_dev):
-                    mask, _h = _K.fused_chunk_scan(chunk_flat, n_dev,
-                                                   scan_spec, interp)
-                    return mask
-
-                self._scan_jit = jax.jit(scan_fn)
             return self
 
         # ---- sharded compile: the SAME traced body under shard_map ----
@@ -763,29 +737,6 @@ class StreamPipeline:
         elif n_partitions > 1:
             P = n_partitions
 
-            if scan_spec is not None:
-                @_obs.scoped("stream.kernel")
-                def scanpid_fn(chunk_flat, n_dev, hist):
-                    s = jax.lax.axis_index(axis).astype(jnp.int64)
-                    n_local = jnp.clip(n_dev - s * shard_plen, 0,
-                                       shard_plen)
-                    mask, h = _K.fused_chunk_scan(chunk_flat, n_local,
-                                                  scan_spec, interp)
-                    pids = (h & jnp.uint32(P - 1)).astype(jnp.int32)
-                    live = jnp.arange(shard_plen) < n_local
-                    counts = jnp.bincount(jnp.where(live, pids, P),
-                                          length=P + 1)[:P]
-                    return (mask, pids,
-                            hist + counts.astype(hist.dtype).reshape(
-                                hist.shape))
-
-                sm_scan = shard_map_compat(scanpid_fn, self.mesh,
-                                           (row, rep, row),
-                                           (row, row, row))
-                self._scan_jit = jax.jit(sm_scan, donate_argnums=(2,))
-                self._reduce_jit = self._make_reduce()
-                return self
-
             @_obs.scoped("stream.partition")
             def pid_fn(chunk_flat, n_dev, hist):
                 s = jax.lax.axis_index(axis).astype(jnp.int64)
@@ -803,18 +754,6 @@ class StreamPipeline:
             sm_pid = shard_map_compat(pid_fn, self.mesh,
                                       (row, rep, row), (row, row))
             self._pid_jit = jax.jit(sm_pid, donate_argnums=(2,))
-        elif scan_spec is not None:
-            @_obs.scoped("stream.kernel")
-            def scan_fn(chunk_flat, n_dev):
-                s = jax.lax.axis_index(axis).astype(jnp.int64)
-                n_local = jnp.clip(n_dev - s * shard_plen, 0, shard_plen)
-                mask, _h = _K.fused_chunk_scan(chunk_flat, n_local,
-                                               scan_spec, interp)
-                return mask
-
-            sm_scan = shard_map_compat(scan_fn, self.mesh, (row, rep),
-                                       row)
-            self._scan_jit = jax.jit(sm_scan)
         self._reduce_jit = self._make_reduce()
         return self
 
@@ -843,33 +782,20 @@ class StreamPipeline:
         #                                          low bits; shard routing
         #                                          the next log2(S) bits
 
-        scan_spec = self.scan_spec
-        interp = _K._pallas_mode() == "interpret"
-
         @_obs.scoped("stream.exchange")
         def exch_body(chunk_flat, n_dev, hist, ovf):
             s = jax.lax.axis_index(axis).astype(jnp.int64)
             n_local = jnp.clip(n_dev - s * shard_plen, 0, shard_plen)
             alive = jnp.arange(shard_plen) < n_local
-            if scan_spec is not None:
-                # fused scan pass INSIDE the exchange: predicates + the
-                # routing hash in one VMEM pass; rows failing a lowered
-                # predicate dead-route (never cross the wire). The
-                # histogram keeps counting every alive row — part_input
-                # evidence stays identical between Pallas arms.
-                mask, h = _K.fused_chunk_scan(chunk_flat, n_local,
-                                              scan_spec, interp)
-            else:
-                mask = alive
-                h = jnp.full((shard_plen,), 2166136261, dtype=jnp.uint32)
-                for ks in key_slots:
-                    h = _hash_mix(h, chunk_flat[ks])
+            h = jnp.full((shard_plen,), 2166136261, dtype=jnp.uint32)
+            for ks in key_slots:
+                h = _hash_mix(h, chunk_flat[ks])
             pids = (h & jnp.uint32(P - 1)).astype(jnp.int32)
             hist = hist + jnp.bincount(jnp.where(alive, pids, P),
                                        length=P + 1)[:P].astype(
                 hist.dtype).reshape(hist.shape)
             dest = jnp.where(
-                mask,
+                alive,
                 ((h >> pshift) & jnp.uint32(S - 1)).astype(jnp.int32),
                 jnp.int32(S))                    # dead rows route past S
             order = jnp.argsort(dest)
@@ -989,31 +915,6 @@ class StreamPipeline:
                      for x in flat)
         return flat, n_dev, h2d
 
-    def _first_kern(self, attr, call):
-        """Capture trace-time fused-kernel launch counts on the first
-        (tracing) dispatch of one compiled program — the same pattern
-        the sharded path uses for collectives: a kernel traced into a
-        jit program launches once per dispatch, so the counts are exact
-        per-dispatch evidence at zero runtime cost."""
-        if getattr(self, attr) is None:
-            with _K.kernel_trace() as kc:
-                out = call()
-            setattr(self, attr, dict(kc))
-            return out
-        return call()
-
-    def _kernel_evidence(self, n_chunks: int, dispatches: int) -> dict:
-        """StreamEvent kernel evidence of one drive: total fused-kernel
-        launches (scan pre-pass per chunk + probes per chunk-program
-        dispatch) and the per-launch fused stage count of the scan
-        spec — the numbers tools/exec_audit_diff.py checks against the
-        static prediction."""
-        ks = (self.kern_scan or {}).get("launches", 0)
-        kc = (self.kern_chunk or {}).get("launches", 0)
-        return {"kernel_launches": ks * n_chunks + kc * dispatches,
-                "kernel_stages": self.scan_spec.stages()
-                if self.scan_spec is not None else 0}
-
     def init_acc(self):
         names, kinds, dicts, valided, dtypes, encs = self.out_template
         if self.n_shards > 1:
@@ -1113,25 +1014,11 @@ class StreamPipeline:
                 # dispatch of a fresh pipeline traces+compiles the
                 # per-chunk program; the span names that cost so the
                 # compile-vs-drive split is visible per chunk.
-                live = None
-                if self._scan_jit is not None:
-                    # the fused Pallas pre-pass: one VMEM-resident launch
-                    # evaluates every lowered predicate; the chunk program
-                    # consumes the survivor mask as a lazy compact. Device-
-                    # only by construction (zero host syncs — the span's
-                    # delta is cross-checked by tools/exec_audit_diff.py)
-                    with _obs.span("stream.kernel", chunk=n_chunks):
-                        live = self._first_kern(
-                            "kern_scan",
-                            lambda f=flat, nd=n_dev: self._scan_jit(f, nd))
                 phase = "stream.drive" if self.traced_once \
                     else "stream.compile"
                 with _obs.span(phase, chunk=n_chunks):
-                    acc = self._first_kern(
-                        "kern_chunk",
-                        lambda a=acc, f=flat, nd=n_dev, lv=live:
-                        self.jitted(f, nd, parts_flat, ops, a,
-                                    resid_flat, live=lv))
+                    acc = self.jitted(flat, n_dev, parts_flat, ops, acc,
+                                      resid_flat)
                 self.traced_once = True
                 n_chunks += 1
                 # stall span: driver time BLOCKED on the ring for the
@@ -1160,8 +1047,7 @@ class StreamPipeline:
                                                        fetch)
         evidence = {"h2d": h2d, "stall_ms": stall_ms,
                     "outer": [(slot, m, n) for (slot, (m, _nd), n)
-                              in zip(self.build_slots, miss, extras_n)],
-                    **self._kernel_evidence(n_chunks, n_chunks)}
+                              in zip(self.build_slots, miss, extras_n)]}
         if overflowed:
             return None, n_chunks, evidence
         return self._slice_acc(datas, valids, total), n_chunks, evidence
@@ -1209,31 +1095,16 @@ class StreamPipeline:
             while cur is not None:
                 flat, n_dev, nb = cur
                 h2d += nb
-                mask = None
-                if self._scan_jit is not None:
-                    # fused pass: predicates + partition ids + histogram
-                    # in ONE VMEM launch (replaces the XLA radix pass)
-                    with _obs.span("stream.kernel", chunk=n_chunks,
-                                   partitions=P):
-                        mask, pids, hist = self._first_kern(
-                            "kern_scan",
-                            lambda f=flat, nd=n_dev, h=hist:
-                            self._scan_jit(f, nd, h))
-                else:
-                    with _obs.span("stream.partition", chunk=n_chunks,
-                                   partitions=P):
-                        pids, hist = self._pid_jit(flat, n_dev, hist)
+                with _obs.span("stream.partition", chunk=n_chunks,
+                               partitions=P):
+                    pids, hist = self._pid_jit(flat, n_dev, hist)
                 for p in range(P):
                     phase = "stream.drive" if self.traced_once \
                         else "stream.compile"
                     with _obs.span(phase, chunk=n_chunks, part=p):
-                        accs[p] = self._first_kern(
-                            "kern_chunk",
-                            lambda a=accs[p], f=flat, nd=n_dev, pv=pids,
-                            pc=pid_consts[p], lv=mask:
-                            self.jitted(f, nd, parts_flat, ops,
-                                        a, resid_flat, pids=pv,
-                                        part_id=pc, live=lv))
+                        accs[p] = self.jitted(
+                            flat, n_dev, parts_flat, ops, accs[p],
+                            resid_flat, pids=pids, part_id=pid_consts[p])
                     self.traced_once = True
                 n_chunks += 1
                 with _obs.span("stream.prefetch", chunk=n_chunks) as sp:
@@ -1268,8 +1139,7 @@ class StreamPipeline:
                     "part_input": tuple(hist_host), "h2d": h2d,
                     "stall_ms": stall_ms,
                     "outer": [(slot, m, n) for (slot, (m, _nd), n)
-                              in zip(self.build_slots, miss, extras_n)],
-                    **self._kernel_evidence(n_chunks, n_chunks * P)}
+                              in zip(self.build_slots, miss, extras_n)]}
         if any(overflowed):
             return None, n_chunks, evidence
         tables = [self._slice_acc(accs[p][0], accs[p][1], totals[p])
@@ -1355,22 +1225,7 @@ def _run_sharded(pipe, chunks, first_chunk, parts_flat, resid_flat=(),
                     flat, live, pids, hist, ex_ovf = first_traced(
                         "coll_exchange",
                         lambda f=flat, nd=n_dev, h=hist, o=ex_ovf:
-                        pipe._first_kern("kern_scan",
-                                         lambda: pipe._exch_jit(f, nd,
-                                                                h, o)))
-            elif pipe._scan_jit is not None and P > 1:
-                with _obs.span("stream.kernel", chunk=n_chunks,
-                               partitions=P, shards=S):
-                    live, pids, hist = pipe._first_kern(
-                        "kern_scan",
-                        lambda f=flat, nd=n_dev, h=hist:
-                        pipe._scan_jit(f, nd, h))
-            elif pipe._scan_jit is not None:
-                with _obs.span("stream.kernel", chunk=n_chunks,
-                               shards=S):
-                    live = pipe._first_kern(
-                        "kern_scan",
-                        lambda f=flat, nd=n_dev: pipe._scan_jit(f, nd))
+                        pipe._exch_jit(f, nd, h, o))
             elif P > 1:
                 with _obs.span("stream.partition", chunk=n_chunks,
                                partitions=P, shards=S):
@@ -1383,9 +1238,7 @@ def _run_sharded(pipe, chunks, first_chunk, parts_flat, resid_flat=(),
                         pid_consts[p] if P > 1 else None, live)
                 with _obs.span(phase, chunk=n_chunks, part=p):
                     accs[p] = first_traced(
-                        "coll_chunk",
-                        lambda a=args: pipe._first_kern(
-                            "kern_chunk", lambda: pipe.jitted(*a)))
+                        "coll_chunk", lambda a=args: pipe.jitted(*a))
                 pipe.traced_once = True
             n_chunks += 1
             with _obs.span("stream.prefetch", chunk=n_chunks) as sp:
@@ -1436,8 +1289,7 @@ def _run_sharded(pipe, chunks, first_chunk, parts_flat, resid_flat=(),
                 "shard_rows": tuple(int(x) for x in counts.sum(axis=1)),
                 "collectives": collectives, "bytes_ici": bytes_ici,
                 "outer": [(slot, m, n) for (slot, (m, n)) in
-                          zip(pipe.build_slots, extras_pairs)],
-                **pipe._kernel_evidence(n_chunks, dispatches)}
+                          zip(pipe.build_slots, extras_pairs)]}
     if P > 1:
         evidence["partitions"] = P
         evidence["part_rows"] = tuple(int(x) for x in counts.sum(axis=0))
@@ -1505,14 +1357,8 @@ def _dicts_equal(a, b) -> bool:
 
 def _param_bind_active() -> bool:
     """Parameter binding is ON by default (``NDS_TPU_PARAM_BIND=0`` is
-    the escape hatch) but always OFF under the fused-kernel arm: the
-    Pallas scan specs bake comparison thresholds into their lowered
-    predicate entries on host, so a bound operand could never reach
-    them — rather than splitting conjuncts between arms, the kernel arm
-    keeps today's bake-everything behaviour (both modes are cache-key
-    members, so the arms never share an entry)."""
-    return os.environ.get("NDS_TPU_PARAM_BIND", "1") != "0" \
-        and not _K.scan_kernels_active()
+    the escape hatch; the mode is a cache-key member)."""
+    return os.environ.get("NDS_TPU_PARAM_BIND", "1") != "0"
 
 
 def _param_slots(planner, parts, keep, where_conjuncts, chunk_spec):
@@ -1614,11 +1460,9 @@ def _cache_key(alias, keep, join_preds, where_conjuncts, sources,
         # (or exchange mode) must never serve another
         stream_shards_env(), os.environ.get("NDS_TPU_STREAM_EXCHANGE"),
         os.environ.get("NDS_TPU_STREAM_MESH_AXIS"),
-        # fused-kernel arm: a pipeline whose conjuncts were split into a
-        # Pallas scan spec must never serve the XLA-only arm (and vice
-        # versa) — the spec itself derives from conjuncts + encodings,
-        # both already key members
-        _K.scan_kernels_active(), _K._pallas_mode(),
+        # the Pallas mode picks which segment implementation traces
+        # into the chunk program
+        _K._pallas_mode(),
         # read-at-use engine knobs reachable from the traced per-chunk
         # program (cache-key completeness, enforced statically by
         # analysis/conc_audit.py): pair-bucket budget and group-pack
@@ -1965,9 +1809,6 @@ def stream_execute(planner, parts, keep, join_preds, where_conjuncts,
                         collectives=evidence.get("collectives", -1),
                         bytes_ici=evidence.get("bytes_ici", -1),
                         shard_rows=evidence.get("shard_rows", ()),
-                        kernel_launches=evidence.get("kernel_launches", 0),
-                        kernel_fused_stages=evidence.get("kernel_stages",
-                                                         0),
                         prefetch_stall_ms=stall_ms)
     _obs.annotate(path="compiled", chunks=ran,
                   prefetchStallMs=stall_ms,
@@ -1977,14 +1818,7 @@ def stream_execute(planner, parts, keep, join_preds, where_conjuncts,
                   bytesIci=evidence.get("bytes_ici", -1),
                   bytesH2d=h2d,
                   bytesLogical=_logical_chunk_bytes(pipe.chunk_spec,
-                                                    pipe.chunk_cap, ran),
-                  # kernel coverage per query: the arm the segment/scan
-                  # kernels take (incl. the permanent-fallback flip) +
-                  # this scan's fused launch/stage evidence —
-                  # tools/trace_report.py prices fused-vs-XLA from these
-                  kernelArm=_K.active_arm(),
-                  kernelLaunches=evidence.get("kernel_launches", 0),
-                  kernelStages=evidence.get("kernel_stages", 0))
+                                                    pipe.chunk_cap, ran))
     return out, None
 
 
@@ -2025,65 +1859,6 @@ def _build_pipeline(planner, parts, keep, alias, join_preds,
                 t, mcond, list(mconjs), msrc)
         sub[i] = t
         pi += 1
-    # fused Pallas chunk-scan pass (DESIGN.md "Fused chunk kernels"):
-    # split the chunk-owned WHERE conjuncts the shared eligibility rule
-    # (analysis/kernel_spec.py) accepts into a chunk-invariant spec; the
-    # record/trace then run WITHOUT them — at drive time the fused
-    # kernel evaluates them in encoded space and the chunk program
-    # consumes the survivor mask as a lazy compact (same shapes, same
-    # replay log). Non-lowerable conjuncts stay in the graph
-    # per-conjunct; outer-join graphs keep the whole XLA chain (their
-    # pre/post conjunct split must not be disturbed).
-    scan_spec = None
-    where_kept = list(where_conjuncts)
-    # bind_slots nonempty means the cache key promised operand-backed
-    # conjuncts (computed under kernels-off); never lower them into a
-    # host-baked Pallas spec even if the kernel arm flipped since
-    if _K.scan_kernels_active() and not bind_slots \
-            and not any(m is not None for m in outer_meta):
-        from nds_tpu.engine.exprs import lower_scan_spec
-        cols_meta = []
-        for pos, cname in enumerate(first.column_names):
-            c = first[cname]
-            cols_meta.append({
-                "name": f"{alias.lower()}.{cname.split('.')[-1].lower()}",
-                "kind": c.kind, "enc": c.enc,
-                "dict_values": c.dict_values,
-                "data_slot": 2 * pos,
-                "valid_slot": 2 * pos + 1 if c.valid is not None else -1})
-        all_cols = set()
-        for p in sub:
-            all_cols |= set(p.column_names)
-        sub_cols = [set(p.column_names) for p in sub]
-
-        def owned(c):
-            # the planner's single-ownership test (_join_parts): only a
-            # conjunct the planner would push down to the streamed slot
-            # may leave the graph
-            if planner._has_subquery(c):
-                return False
-            tabs = planner._expr_tables(c, all_cols)
-            owners = set()
-            for p_i, pc in enumerate(sub_cols):
-                for t in tabs:
-                    if any(cc.startswith(t + ".") for cc in pc):
-                        owners.add(p_i)
-            return owners == {keep}
-
-        try:
-            scan_spec, where_kept = lower_scan_spec(where_conjuncts,
-                                                    cols_meta, owned)
-        except Exception:            # never let lowering break a query
-            scan_spec, where_kept = None, list(where_conjuncts)
-        if scan_spec is not None:
-            flat0 = tuple(x for cname in first.column_names
-                          for x in (first[cname].data,
-                                    first[cname].valid))
-            # smoke-run on this chunk's real shapes (interpret mode; the
-            # fused scan is off on a chip): a spec that cannot run
-            # degrades to the XLA chain at BUILD time, never mid-drive
-            if not _K.scan_spec_ready(scan_spec, flat0, chunk_cap):
-                scan_spec, where_kept = None, list(where_conjuncts)
     # save/restore: a subquery residual planned DURING this record may
     # itself stream through a nested pipeline build on the same planner —
     # its record must not clobber the outer record's touched list
@@ -2095,7 +1870,7 @@ def _build_pipeline(planner, parts, keep, alias, join_preds,
                 with E.stream_bounds():
                     with E.outer_match_collector() as omc:
                         out0 = planner._join_parts(sub, list(join_preds),
-                                                   list(where_kept),
+                                                   list(where_conjuncts),
                                                    list(masked_sources))
     except E.StreamSyncError as exc:
         log.info("streamed scan %s not chunk-invariant: %s", alias, exc)
@@ -2198,14 +1973,6 @@ def _build_pipeline(planner, parts, keep, alias, join_preds,
                 max((chunk_cap // n_shards) // n_shards, 1)
                 * stream_skew_factor())
     acc_cap = E.bucket_len(max(budget, out0.plen))
-    if scan_spec is not None and n_parts > 1 and key_slots:
-        # the fused pass also computes the partition/shard routing hash
-        # (one more fused stage); key slots are the SAME buffers the XLA
-        # radix pass folds, so both arms route rows identically
-        scan_spec = _K.ScanSpec(scan_spec.entries, scan_spec.cols,
-                                tables=scan_spec.tables,
-                                key_slots=tuple(key_slots),
-                                n_conjuncts=scan_spec.n_conjuncts)
     _obs.annotate(accRows=acc_cap, partitions=n_parts, shards=n_shards,
                   provedRows=proved if proved is not None else "unproven",
                   residuals=len(residuals), outerBuilds=len(build_slots))
@@ -2220,11 +1987,10 @@ def _build_pipeline(planner, parts, keep, alias, join_preds,
         resid_specs=tuple(spec for (spec, _flat) in resid_infos),
         build_slots=build_slots, name_catalog=name_cat,
         n_shards=n_shards, mesh=mesh, mesh_axis=axis_name or "shard",
-        exchange=exchange, cap_ex=cap_ex, scan_spec=scan_spec,
-        # bound slots reference where_conjuncts Literal nodes; with the
-        # kernel arm off (binding's precondition) where_kept IS
-        # where_conjuncts, so the traced replay sees those same nodes
+        exchange=exchange, cap_ex=cap_ex,
+        # bound slots reference where_conjuncts Literal nodes: the
+        # traced replay sees those same nodes
         param_nodes=tuple(nd for (_ci, _p, _t, nd) in bind_slots),
         param_tags=tuple(t for (_ci, _p, t, _nd) in bind_slots))
-    return (pipe.compile(join_preds, where_kept, masked_sources),
+    return (pipe.compile(join_preds, where_conjuncts, masked_sources),
             resid_infos)

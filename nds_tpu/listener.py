@@ -140,16 +140,6 @@ class StreamEvent:
     shard_rows: tuple = ()     # per-shard survivor counts (shard order,
     #                            summed over partitions) — checked against
     #                            mem_audit's per-shard bound
-    kernel_launches: int = -1  # fused Pallas kernel launches the drive
-    #                            issued (scan pre-pass per chunk + join
-    #                            probes per dispatch, trace-time counted
-    #                            like collectives) — checked against
-    #                            exec_audit's static kernel prediction
-    #                            by tools/exec_audit_diff.py; -1 =
-    #                            unknown (eager path / old events)
-    kernel_fused_stages: int = -1  # fused stages per scan-pass launch
-    #                            (lowered conjuncts + the routing-hash
-    #                            stage); 0 = no fused scan pass ran
     prefetch_stall_ms: float = -1.0  # driver milliseconds BLOCKED on the
     #                            bounded prefetch ring (engine/prefetch)
     #                            across the whole drive; with the ring
@@ -168,8 +158,7 @@ def record_stream_event(where: str, chunks: int, syncs: int, path: str,
                         partitions: int = 1, part_rows=(),
                         bytes_h2d: int = -1, shards: int = 1,
                         collectives: int = -1, bytes_ici: int = -1,
-                        shard_rows=(), kernel_launches: int = -1,
-                        kernel_fused_stages: int = -1,
+                        shard_rows=(),
                         prefetch_stall_ms: float = -1.0) -> None:
     """Engine-side hook (engine/stream.py, sql/planner.py): record how a
     streamed scan executed. Thread-scoped like the sync counters, so
@@ -181,8 +170,7 @@ def record_stream_event(where: str, chunks: int, syncs: int, path: str,
     lst.append(StreamEvent(where, chunks, syncs, path, reason, rows,
                            partitions, tuple(part_rows), bytes_h2d,
                            shards, collectives, bytes_ici,
-                           tuple(shard_rows), kernel_launches,
-                           kernel_fused_stages, prefetch_stall_ms))
+                           tuple(shard_rows), prefetch_stall_ms))
 
 
 def drain_stream_events() -> list:
@@ -211,9 +199,6 @@ def stream_event_json(e: StreamEvent) -> dict:
         **({"shards": e.shards, "shardRows": list(e.shard_rows),
             "collectives": e.collectives, "bytesIci": e.bytes_ici}
            if e.shards > 1 else {}),
-        **({"kernelLaunches": e.kernel_launches,
-            "kernelStages": e.kernel_fused_stages}
-           if e.kernel_launches > 0 else {}),
         **({"prefetchStallMs": round(e.prefetch_stall_ms, 3)}
            if e.prefetch_stall_ms >= 0 else {}),
         **({"reason": e.reason} if e.reason else {}),

@@ -2,7 +2,7 @@
 """Campaign orchestration: a declarative arm matrix of bench runs.
 
 ROADMAP item 1 names one measurement campaign that prices every landed
-mechanism at once — fused kernels on/off, prefetch on/off, warm/cold
+mechanism at once — Pallas kernels on/off, prefetch on/off, warm/cold
 chunk store, 1/2/4/8 shards, encoded upload on/off. Until now that was
 an evening of manual env juggling; this module is the arm model and the
 unattended driver behind ``tools/campaign.py``:
@@ -63,7 +63,7 @@ CAMPAIGN_VERSION = 1
 # default's value" are distinguishable (they are different experiments:
 # defaults can move between commits).
 FINGERPRINT_KNOBS = (
-    "NDS_TPU_PALLAS",            # fused Pallas chunk kernels: auto/off
+    "NDS_TPU_PALLAS",            # Pallas segment kernels: auto/off
     "NDS_TPU_PREFETCH_DEPTH",    # bounded prefetch ring: 0 = inline
     "NDS_TPU_CHUNK_STORE",       # persistent chunk store dir ("" = cold)
     "NDS_TPU_STREAM_SHARDS",     # mesh shard count: 1/2/4/8

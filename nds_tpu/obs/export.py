@@ -66,7 +66,7 @@ def write_chrome_trace(path: str, records, query: str = "",
 
 # the spans that dispatch a streamed scan's chunk program: the first of
 # them (chunk 0) ends a statement's lead-in
-DISPATCH_SPANS = ("stream.kernel", "stream.compile", "stream.drive")
+DISPATCH_SPANS = ("stream.compile", "stream.drive")
 
 
 def _lead_in_ns(spans) -> int:

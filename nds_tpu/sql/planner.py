@@ -1431,11 +1431,8 @@ class Planner:
                                     E.sync_count() - syncs0, "eager", reason,
                                     bytes_h2d=h2d,
                                     prefetch_stall_ms=stall_ms)
-                from nds_tpu.engine.kernels import active_arm
                 _obs.annotate(path="eager", chunks=n_chunks, reason=reason,
-                              bytesH2d=h2d, prefetchStallMs=stall_ms,
-                              kernelArm=active_arm(),
-                              kernelLaunches=0, kernelStages=0)
+                              bytesH2d=h2d, prefetchStallMs=stall_ms)
             return result
 
     def _append_outer_extras(self, result, builds, bitmaps):

@@ -1488,29 +1488,6 @@ def test_perf_audit_differential_harness():
     assert any("EXACTNESS LOST" in ln for ln in drift_lines)
 
 
-def test_perf_audit_kernel_arm_differential():
-    """Fused-kernel arm: the upload equality holds unchanged (the
-    kernels collapse HBM re-reads, not h2d) and measured launches land
-    inside the nonzero static band; zeroed bands must fail."""
-    import numpy as np
-    mod = _load_perf_diff("perf_audit_diff_t2")
-    ab = mod._load_ab_module()
-    queries = ab._STREAM_AB_QUERIES
-    idxs = list(ab._STREAM_AB_KERNEL)
-    with ab._forced_stream_partitions():
-        with ab._forced_pallas("interpret"):
-            session = ab._chunked_star_session(np.random.default_rng(42))
-            bounds, chunk_rows = mod._session_params(session)
-            reports = mod.predict(queries, bounds, chunk_rows,
-                                  mod._wire_cols(session))
-            evidence = mod._run_sweep(ab, session, idxs)
-    assert any(c.kernel_max > 0 for i in idxs for c in reports[i].scans)
-    ok, lines = mod.compare_kernels(reports, evidence)
-    assert ok, "\n".join(lines)
-    drift_ok, _lines = mod.compare_kernels(reports, evidence, inject=True)
-    assert not drift_ok, "kernel drift fixture failed to fail"
-
-
 def test_perf_audit_sharded_ici_differential():
     """Sharded arm: measured ``StreamEvent.bytes_ici`` must EQUAL the
     static exchange+reduce aval arithmetic (every subset template is
@@ -1780,7 +1757,7 @@ def test_lint_cli_update_baseline_refuses_foreign_corpus(tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# fused Pallas chunk kernels: lint rule + static prediction + lockstep
+# Pallas kernels: lint rule
 # ---------------------------------------------------------------------------
 
 
@@ -1927,55 +1904,13 @@ def test_jax_lint_prefetch_rule_baseline_untouched():
                     if f.rule == "host-sync-in-prefetch-worker"], (rel, fs)
 
 
-def test_kernel_spec_eligibility_rule():
-    """The shared eligibility rule (analysis/kernel_spec.py) on its
-    canonical shapes — the ONE rule the runtime lowering and the static
-    kernel prediction both consume."""
-    from nds_tpu.analysis.kernel_spec import (count_eligible,
-                                              eligible_conjunct)
-    from nds_tpu.sql.parser import parse
-
-    def conjs(sql):
-        q = parse(f"select 1 from t where {sql}")
-        w = q.body.where
-        out = []
-
-        def split(e):
-            import nds_tpu.sql.ast as A
-            if isinstance(e, A.BinaryOp) and e.op == "and":
-                split(e.left)
-                split(e.right)
-            else:
-                out.append(e)
-        split(w)
-        return out
-
-    classes = {"a": "num", "d": "date", "s": "str", "b": "bool"}
-
-    def class_of(ref):
-        return classes.get(ref.name.lower())
-
-    cs = conjs("a > 5 and 5 < a and a = 2.5 and s = 'x' and s > 'x' "
-               "and a in (1, 2, 3) and a between 1 and 9 "
-               "and s is not null and b = 1 and a > s")
-    want = [True, True, True, True, False,
-            True, True, True, False, False]
-    got = [eligible_conjunct(c, class_of) for c in cs]
-    assert got == want, list(zip(got, want, cs))
-    assert count_eligible(cs, class_of) == sum(want)
-    # the IN-list cap is part of the rule (kernel code size bound)
-    big = conjs(f"a in ({', '.join(str(i) for i in range(17))})")
-    assert not eligible_conjunct(big[0], class_of)
-
-
-def test_kernel_spec_threshold_math():
-    """Exact rational -> stored-space threshold mapping (the encoded-
-    space evaluation): boundaries, non-integral equalities, FOR rebase
-    and sorted-dict bisect."""
+def test_num_audit_threshold_math():
+    """Exact rational -> integer threshold mapping of an ordered compare
+    (boundaries, non-integral equalities) and the date parse the rebase
+    proofs read literals through."""
     from fractions import Fraction
 
-    from nds_tpu.analysis.kernel_spec import (dict_map, shift_for,
-                                              value_cmp)
+    from nds_tpu.analysis.num_audit import parse_days, value_cmp
     F = Fraction
     assert value_cmp("<", F(11, 2)) == ("ile", 5)    # v < 5.5 -> v <= 5
     assert value_cmp("<=", F(11, 2)) == ("ile", 5)
@@ -1985,102 +1920,23 @@ def test_kernel_spec_threshold_math():
     assert value_cmp("=", F(11, 2)) == ("false",)
     assert value_cmp("<>", F(11, 2)) == ("true",)
     assert value_cmp("=", F(7)) == ("ieq", 7)
-    assert shift_for(("ile", 100), 40) == ("ile", 60)
-    assert shift_for(("irange", 10, 20), 5) == ("irange", 5, 15)
-    vals = [10, 20, 30]
-    assert dict_map(("ieq", 20), vals) == ("ieq", 1)
-    assert dict_map(("ieq", 25), vals) == ("false",)
-    assert dict_map(("ile", 25), vals) == ("ile", 1)
-    assert dict_map(("ige", 25), vals) == ("ige", 2)
-    assert dict_map(("irange", 15, 30), vals) == ("irange", 1, 2)
-
-
-def test_exec_audit_kernel_prediction():
-    """The static kernel budget: exact scan/stage predictions from the
-    shared eligibility rule under an explicit NDS_TPU_PALLAS mode, and
-    all-zero under auto/off (the auditor cannot see the backend)."""
-    from nds_tpu.analysis.exec_audit import ExecAuditor
-    sql = ("select ss_item_sk from store_sales "
-           "where ss_quantity > 5 and ss_item_sk in (1, 2)")
-    old = os.environ.get("NDS_TPU_PALLAS")
-    try:
-        os.environ["NDS_TPU_PALLAS"] = "interpret"
-        rep = ExecAuditor(streamed={"store_sales"}).audit_sql(sql)
-        (scan,) = [s for s in rep.scans if s.compiled]
-        assert scan.kernel_scan_chunk == 1
-        assert scan.kernel_stages == 2          # two eligible conjuncts
-        os.environ["NDS_TPU_PALLAS"] = "off"
-        rep2 = ExecAuditor(streamed={"store_sales"}).audit_sql(sql)
-        (scan2,) = [s for s in rep2.scans if s.compiled]
-        assert (scan2.kernel_scan_chunk, scan2.kernel_stages,
-                scan2.kernel_probe_chunk) == (0, 0, 0)
-    finally:
-        if old is None:
-            os.environ.pop("NDS_TPU_PALLAS", None)
-        else:
-            os.environ["NDS_TPU_PALLAS"] = old
-
-
-def test_exec_audit_kernel_differential():
-    """The fused-kernel half of the lockstep contract: drained
-    StreamEvent kernel evidence (NDS_TPU_PALLAS=interpret sweep) must
-    match the static kernel predictions — stage counts exactly, launch
-    totals inside the scan-floor/probe-ceiling window, stream.kernel
-    spans sync-free — and the zeroed-prediction drift fixture must
-    fail."""
-    import importlib.util
-    path = os.path.join(REPO, "tools", "exec_audit_diff.py")
-    spec = importlib.util.spec_from_file_location("exec_audit_diff3", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    kern_ev = mod.collect_kernel_evidence()
-    ab = mod._load_ab_module()
-    with ab._forced_stream_partitions():
-        with ab._forced_pallas("interpret"):
-            reports = mod.predict(ab._STREAM_AB_QUERIES)
-    ok, lines = mod.compare_kernels(reports, kern_ev)
-    assert ok, "\n".join(lines)
-    drift_ok, drift_lines = mod.compare_kernels(reports, kern_ev,
-                                                inject_drift=True)
-    assert not drift_ok, "kernel drift fixture failed to fail"
-    assert any("kernel model drift" in ln or "static window" in ln
-               for ln in drift_lines)
-
-
-def test_mem_audit_kernel_differential():
-    """Kernel-arm soundness: the fused scan/probe kernels reuse the SAME
-    proof-sized accumulators, so every survivor/partition bound holds on
-    the Pallas arm, the subset really engages the kernels, and zeroed
-    bounds must fail."""
-    import importlib.util
-    path = os.path.join(REPO, "tools", "mem_audit_diff.py")
-    spec = importlib.util.spec_from_file_location("mem_audit_diff3", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    kern_ev, bounds, idxs = mod.collect_kernel_evidence()
-    assert kern_ev and idxs
-    ab = mod._load_ab_module()
-    reports = mod.predict(ab._STREAM_AB_QUERIES, bounds)
-    subset = [reports[i] for i in idxs]
-    ok, lines = mod.compare_kernels(subset, kern_ev)
-    assert ok, "\n".join(lines)
-    drift_ok, drift_lines = mod.compare_kernels(subset, kern_ev,
-                                                inject_drift=True)
-    assert not drift_ok, "kernel-arm drift fixture failed to fail"
-    assert any("UNSOUND" in ln for ln in drift_lines)
+    with pytest.raises(ValueError):
+        value_cmp("like", F(1))
+    assert parse_days("1970-01-01") == 0
+    assert parse_days("2000-03-01") == 11017
+    assert parse_days("1969-12-31") == -1
+    assert parse_days("not a date") is None
 
 
 def test_lint_changed_covers_kernels():
-    """tools/lint.py --changed: an edit to engine/kernels.py must rerun
-    the corpus passes (the kernel prediction lives in exec_audit and the
-    shared rule in analysis/kernel_spec.py — all under _CORPUS_ROOTS)."""
+    """tools/lint.py --changed: an edit to engine/kernels.py (and the
+    other explicitly named roots) must rerun the corpus passes."""
     import importlib.util
     path = os.path.join(REPO, "tools", "lint.py")
     spec = importlib.util.spec_from_file_location("lint_tool_k", path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     for p in ("nds_tpu/engine/kernels.py",
-              "nds_tpu/analysis/kernel_spec.py",
               # async ingest data plane: the prefetch ring (admission
               # pricing + worker lint contract) and the persistent
               # chunk store (the streamed wire format) rerun the
@@ -2507,7 +2363,7 @@ def _load_num_diff(name="num_audit_diff_t"):
 
 def test_num_audit_differential_harness():
     """The boundary-value lockstep: every arm of the sweep (base,
-    fused-kernel, sharded, encoded-off) returns bit-identical rows to
+    sharded, encoded-off) returns bit-identical rows to
     the plain-width eager reference over the adversarial tables (FOR
     spans at the int16 edge over 10^9 / negative bases, full dict code
     space, decimal(7,2) extremes, a hot-hash key), and the static

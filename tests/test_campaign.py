@@ -550,7 +550,7 @@ class TestCrossArm:
         lines = campaign_tool.report_lines(arms, d, "base")
         text = "\n".join(lines)
         assert "| base |" in text and "primary = base" in text
-        assert "fused-kernel delta" in text and "x1.60" in text
+        assert "pallas-kernel delta" in text and "x1.60" in text
         assert "prefetch overlap delta" in text
         assert "# shard scaling: shards-2" in text
         assert "static-roofline %" in text       # column present

@@ -183,236 +183,3 @@ def test_agg_sum_decimal_rides_exact_kernel(interpret_mode):
         os.environ["NDS_TPU_PALLAS"] = "interpret"
     np.testing.assert_allclose(np.asarray(via_avg.data),
                                np.asarray(via_avg_xla.data), rtol=1e-12)
-
-
-# ---------------------------------------------------------------------------
-# fused chunk-scan pass + bound-bucket join probe (one VMEM pass each)
-# ---------------------------------------------------------------------------
-
-
-def _scan_spec(entries, cols, **kw):
-    return kernels.ScanSpec(entries, cols, **kw)
-
-
-def _run_both(chunk_flat, n, spec):
-    """(kernel mask/hash, reference mask/hash) — the parity pair every
-    edge test compares."""
-    nd = jnp.asarray(n, dtype=jnp.int64)
-    m_k, h_k = kernels.fused_chunk_scan(chunk_flat, nd, spec,
-                                        interpret=True)
-    m_r, h_r = kernels.scan_reference(chunk_flat, nd, spec)
-    np.testing.assert_array_equal(np.asarray(m_k), np.asarray(m_r))
-    if h_r is None:
-        assert h_k is None
-    else:
-        np.testing.assert_array_equal(np.asarray(h_k), np.asarray(h_r))
-    return np.asarray(m_k), h_k
-
-
-def test_fused_scan_all_survivors(interpret_mode):
-    """A predicate every live row passes: the mask is exactly the
-    liveness prefix (pads excluded)."""
-    n, cap = 700, 1024
-    d = jnp.asarray(np.arange(cap), dtype=jnp.int64)
-    spec = _scan_spec([("ige", 0, 0)], [(0, -1, "id", 0, -1, 1.0)])
-    m, _ = _run_both((d, None), n, spec)
-    np.testing.assert_array_equal(m, np.arange(cap) < n)
-
-
-def test_fused_scan_zero_survivors(interpret_mode):
-    """A constant-false conjunct (e.g. an equality against a literal
-    absent from the dictionary) kills every row."""
-    cap = 512
-    d = jnp.asarray(np.arange(cap), dtype=jnp.int64)
-    spec = _scan_spec([("false", 0)], [(0, -1, "id", 0, -1, 1.0)])
-    m, _ = _run_both((d, None), cap, spec)
-    assert not m.any()
-
-
-def test_fused_scan_tile_boundary_rows(interpret_mode):
-    """Rows straddling the 512-row kernel tile boundary (and a logical
-    count that is NOT a tile multiple) must evaluate exactly: survivor
-    at index 511/512/513, pad cut at a mid-tile n."""
-    cap = 2048
-    n = 1030                       # mid-tile logical count
-    vals = np.zeros(cap, dtype=np.int64)
-    vals[[510, 511, 512, 513, 1029, 1030]] = 7   # 1030 is already a pad
-    d = jnp.asarray(vals)
-    spec = _scan_spec([("ieq", 0, 7)], [(0, -1, "id", 0, -1, 1.0)])
-    m, _ = _run_both((d, None), n, spec)
-    assert list(np.nonzero(m)[0]) == [510, 511, 512, 513, 1029]
-
-
-def test_fused_scan_dict_code_out_of_range_guard(interpret_mode):
-    """Sorted-dict thresholds at/past the value-table edge select
-    nothing (codes are clipped into range at encode time, so a mapped
-    threshold of len(values) or -1 is the guard)."""
-    from nds_tpu.analysis.kernel_spec import dict_map
-    values = [10, 20, 30]
-    # literal above every value: "<= 99" keeps all codes, ">= 99" none
-    assert dict_map(("ile", 99), values) == ("ile", 2)
-    assert dict_map(("ige", 99), values) == ("ige", 3)   # > max: nothing
-    assert dict_map(("ieq", 99), values) == ("false",)
-    assert dict_map(("ine", 99), values) == ("true",)
-    assert dict_map(("ile", 5), values) == ("ile", -1)   # < min: nothing
-    codes = jnp.asarray(np.array([0, 1, 2, 2, 0], dtype=np.int16))
-    spec = _scan_spec([("ige", 0, 3)], [(0, -1, "dict", 0, 0, 1.0)],
-                      tables=[np.asarray(values, dtype=np.int64)])
-    m, _ = _run_both((codes, None), 5, spec)
-    assert not m.any()
-    spec2 = _scan_spec([("ile", 0, -1)], [(0, -1, "dict", 0, 0, 1.0)],
-                       tables=[np.asarray(values, dtype=np.int64)])
-    m2, _ = _run_both((codes, None), 5, spec2)
-    assert not m2.any()
-
-
-def test_fused_scan_validity_and_hash(interpret_mode):
-    """Null rows never survive a comparison conjunct, and the emitted
-    hash is bitwise the XLA partition pass's _hash_mix fold."""
-    rng = np.random.default_rng(7)
-    cap = 1024
-    d = jnp.asarray(rng.integers(0, 100, cap), dtype=jnp.int64)
-    v = jnp.asarray(rng.random(cap) > 0.3)
-    spec = _scan_spec([("ige", 0, 0)], [(0, 1, "id", 0, -1, 1.0)],
-                      key_slots=(0,))
-    m, h = _run_both((d, v), cap, spec)
-    np.testing.assert_array_equal(m, np.asarray(v))
-    ref_h = kernels._fold_hash([d])
-    np.testing.assert_array_equal(np.asarray(h), np.asarray(ref_h))
-
-
-def test_fused_probe_matches_xla_probe(interpret_mode):
-    """(counts, lo) parity of the fused bound-bucket probe vs the XLA
-    searchsorted path, including null keys, pad rows and an exclusion
-    mask — bitwise, since the kernel restates _key_hash_impl."""
-    from nds_tpu.engine import ops as E
-    rng = np.random.default_rng(13)
-    n_l, n_r = 600, 200
-    lk = jnp.asarray(rng.integers(0, 80, n_l), dtype=jnp.int64)
-    lv = jnp.asarray(rng.random(n_l) > 0.1)
-    excl = jnp.asarray(rng.random(n_l) > 0.8)
-    rk = jnp.asarray(rng.integers(0, 90, n_r), dtype=jnp.int64)
-    rh = E._key_hash_impl((rk,), (None,), 1, False, E.count_arr(n_r),
-                          None)
-    rh_sorted = jnp.take(rh, jnp.argsort(rh))
-    lh = E._key_hash_impl((lk,), (lv,), 0, False, E.count_arr(580), excl)
-    lo_x = jnp.searchsorted(rh_sorted, lh, side="left")
-    hi_x = jnp.searchsorted(rh_sorted, lh, side="right")
-    c_k, lo_k = kernels.fused_probe((lk,), (lv,),
-                                    jnp.asarray(580, dtype=jnp.int64),
-                                    excl, rh_sorted, interpret=True)
-    np.testing.assert_array_equal(np.asarray(lo_k), np.asarray(lo_x))
-    np.testing.assert_array_equal(np.asarray(c_k),
-                                  np.asarray(hi_x - lo_x))
-
-
-def test_fused_probe_gate(interpret_mode):
-    """The probe gate declines f64 key views and oversized dimension
-    buckets (they stay on the XLA path)."""
-    iv = jnp.zeros(8, dtype=jnp.int64)
-    fv = jnp.zeros(8, dtype=jnp.float64)
-    assert kernels.probe_kernel_active((iv,), (None,), 1024)
-    assert not kernels.probe_kernel_active((fv,), (None,), 1024)
-    assert not kernels.probe_kernel_active(
-        (iv,), (None,), kernels._PROBE_MAX_R + 1)
-
-
-def test_scan_spec_stages_and_trace_counts(interpret_mode):
-    """stages() = lowered conjuncts + the hash stage, and kernel_trace
-    captures exactly one launch with that stage count per pass — the
-    evidence contract exec_audit's static prediction is checked
-    against."""
-    d = jnp.asarray(np.arange(512), dtype=jnp.int64)
-    spec = _scan_spec([("ige", 0, 1), ("ile", 0, 400)],
-                      [(0, -1, "id", 0, -1, 1.0)], key_slots=(0,))
-    assert spec.stages() == 3
-    with kernels.kernel_trace() as kc:
-        kernels.fused_chunk_scan((d,), jnp.asarray(512, dtype=jnp.int64),
-                                 spec, interpret=True)
-    assert kc == {"launches": 1, "stages": 3, "probes": 0}
-
-
-def test_fused_scan_lowering_parity_rich_predicates():
-    """End-to-end parity of the spec LOWERING on the predicate shapes
-    the toy A/B star session never exercises: string equality against
-    the whole-table dictionary, BETWEEN, IN-lists (incl. NOT IN),
-    IS [NOT] NULL on a nullable column, and a float literal against an
-    int column — each template bit-for-bit between
-    NDS_TPU_PALLAS=interpret and off, with the fused pass engaged."""
-    import importlib.util
-    import os
-    REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    spec = importlib.util.spec_from_file_location(
-        "sc_fixtures", os.path.join(REPO, "tests", "test_synccount.py"))
-    sc = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(sc)
-
-    import pyarrow as pa
-
-    from nds_tpu.engine.session import Session
-    from nds_tpu.engine.table import ChunkedTable
-    from nds_tpu.listener import drain_stream_events
-
-    def make_session(rng):
-        n = 8_000
-        cats = np.asarray(["alpha", "beta", "gamma", "delta"],
-                          dtype=object)
-        qty = rng.integers(0, 50, n).astype(float)
-        qty[rng.random(n) < 0.15] = np.nan     # nullable column
-        s = Session()
-        s.create_temp_view("lineitem", ChunkedTable(pa.table({
-            "l_key": pa.array(rng.integers(1, 500, n), pa.int64()),
-            "l_cat": pa.array(cats[rng.integers(0, 4, n)]),
-            "l_qty": pa.array(qty),
-            "l_price": pa.array(rng.integers(1, 10_000, n), pa.int64()),
-        }), chunk_rows=1024), base=True)
-        return s
-
-    queries = [
-        ("select count(*) c, sum(l_price) s from lineitem "
-         "where l_cat = 'beta'", True),
-        ("select count(*) c from lineitem where l_cat <> 'omega'", True),
-        ("select count(*) c, sum(l_price) s from lineitem "
-         "where l_price between 100 and 5000", True),
-        ("select count(*) c from lineitem "
-         "where l_key in (1, 2, 3, 499)", True),
-        ("select count(*) c from lineitem "
-         "where l_key not in (7, 9) and l_price > 50", True),
-        ("select count(*) c from lineitem where l_qty is null", True),
-        ("select count(*) c, sum(l_price) s from lineitem "
-         "where l_qty is not null and l_price > 2500.5", True),
-        # NOT IN whose literals are all ABSENT (string dictionary /
-        # fractional at the column's scale): membership is all-false, so
-        # the negation must keep every non-null row — the inversion the
-        # review caught
-        ("select count(*) c from lineitem "
-         "where l_cat not in ('omega', 'zeta')", True),
-        ("select count(*) c from lineitem "
-         "where l_key not in (2.5, 3.5)", True),
-        # mixed-lane BETWEEN (float low bound, int high bound) and the
-        # negated int-lane range
-        ("select count(*) c, sum(l_price) s from lineitem "
-         "where l_price between 100.5 and 5000", True),
-        ("select count(*) c from lineitem "
-         "where l_price not between 100 and 5000", True),
-    ]
-    got = {}
-    for arm in ("interpret", "off"):
-        with sc._forced_stream_partitions():
-            with sc._forced_pallas(arm):
-                s = make_session(np.random.default_rng(11))
-                drain_stream_events()
-                rows = []
-                for q, want_kernel in queries:
-                    rows.append(s.sql(q).collect())
-                    events = drain_stream_events()
-                    assert events and all(e.path == "compiled"
-                                          for e in events), (arm, q)
-                    if arm == "interpret" and want_kernel:
-                        assert any(e.kernel_launches > 0
-                                   for e in events), \
-                            f"fused pass did not engage on: {q}"
-                got[arm] = rows
-    for (q, _), a, b in zip(queries, got["interpret"], got["off"]):
-        assert a == b, f"fused-kernel/XLA divergence on: {q}"
-        assert a, q

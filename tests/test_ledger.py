@@ -592,8 +592,7 @@ def test_stream_and_fault_event_fields_all_ledgered(bench_compare,
         "partitions": "partitions", "part_rows": "partRows",
         "bytes_h2d": "bytesH2d", "shards": "shards",
         "collectives": "collectives", "bytes_ici": "bytesIci",
-        "shard_rows": "shardRows", "kernel_launches": "kernelLaunches",
-        "kernel_fused_stages": "kernelStages",
+        "shard_rows": "shardRows",
         "prefetch_stall_ms": "prefetchStallMs",
     }
     fields = {f.name for f in dataclasses.fields(StreamEvent)}
@@ -604,8 +603,7 @@ def test_stream_and_fault_event_fields_all_ledgered(bench_compare,
                      path="compiled", reason="note", rows=50,
                      partitions=2, part_rows=(30, 20), bytes_h2d=100,
                      shards=2, collectives=7, bytes_ici=64,
-                     shard_rows=(28, 22), kernel_launches=3,
-                     kernel_fused_stages=2, prefetch_stall_ms=1.25)
+                     shard_rows=(28, 22), prefetch_stall_ms=1.25)
     j = stream_event_json(ev)
     assert set(j) == set(STREAM_FIELD_TO_KEY.values())
     assert j["table"] == "store_sales" and j["bytesH2d"] == 100

@@ -450,59 +450,6 @@ def test_power_run_writes_ledger(tmp_path, monkeypatch):
     assert "plan" in rec["tracePhases"]["phases"]
 
 
-def test_trace_report_kernel_arm_delta(tmp_path):
-    """trace_report prices the fused-kernel coverage and the
-    fused-vs-XLA per-template delta when one trace dir holds both
-    NDS_TPU_PALLAS arms of a template, and the stream.kernel pre-pass
-    gets its own phase column."""
-    import json
-    spec = importlib.util.spec_from_file_location(
-        "trace_report_k", os.path.join(REPO, "tools", "trace_report.py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-
-    def trace(name, arm, stream_ms, kern_ms, launches):
-        events = [
-            {"ph": "X", "name": "stream", "ts": 0,
-             "dur": stream_ms * 1000,
-             "args": {"path": "compiled", "kernelArm": arm,
-                      "kernelLaunches": launches, "kernelStages": 2,
-                      "bytesH2d": 1000, "bytesLogical": 2000}},
-            {"ph": "X", "name": "stream.kernel", "ts": 10,
-             "dur": kern_ms * 1000, "args": {"chunk": 0}},
-            {"ph": "X", "name": "stream.drive", "ts": 10 + kern_ms * 1000,
-             "dur": 500, "args": {"chunk": 0}},
-        ]
-        # the report prices phases from the file's rollup (selfMs from
-        # the spans' parent ids), not from interval containment
-        phases = {"stream": {"ms": stream_ms, "count": 1,
-                             "selfMs": stream_ms - kern_ms - 0.5,
-                             "rootMs": stream_ms},
-                  "stream.drive": {"ms": 0.5, "count": 1, "selfMs": 0.5}}
-        if kern_ms:
-            phases["stream.kernel"] = {"ms": kern_ms, "count": 1,
-                                       "selfMs": kern_ms}
-        doc = {"traceEvents": events,
-               "nds": {"query": "query9", "rollup": {"phases": phases}}}
-        (tmp_path / name).write_text(json.dumps(doc))
-
-    # the xla file sorts FIRST so the pallas row (with the
-    # stream.kernel phase) survives the per-query overwrite; the
-    # arm-delta accumulator sees both files either way
-    trace("q9_a_xla.trace.json", "xla", 50.0, 0.0, 0)
-    trace("q9_b_pallas.trace.json", "pallas", 40.0, 2.0, 10)
-    agg = mod.collect_from_traces(str(tmp_path))
-    lines = mod.render(agg, str(tmp_path))
-    out = "\n".join(lines)
-    assert "stream.kernel" in out
-    assert "fused-kernel coverage: 1/1" in out
-    assert "fused-kernel vs XLA per-template" in out
-    delta = [ln for ln in lines if "query9:" in ln]
-    assert delta and "fused 40.0 ms (10 launches) vs xla 50.0 ms" \
-        in delta[0]
-    assert "+10.0 ms (+20.0%)" in delta[0]
-
-
 def test_trace_report_ledger_parity_on_byte_columns(tmp_path):
     """The byte/roofline/pf-stall/static-cost columns must render
     IDENTICALLY from a trace dir and from the equivalent campaign

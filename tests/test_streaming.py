@@ -4,6 +4,8 @@ chunk-bound fact table must match the fully device-resident results —
 SURVEY.md §5.7's structural requirement (tables larger than HBM stream
 through the operators)."""
 
+import functools
+
 import numpy as np
 import pyarrow as pa
 import pytest
@@ -691,3 +693,260 @@ def test_outer_build_not_deferred_under_parent_join():
     # the parent inner join drops unmatched returns rows: no row may
     # carry a NULL sales side (extras leaking through would)
     assert all(r[2] is not None for r in b)
+
+
+# ---------------------------------------------------------------------------
+# chunk filtering and partition routing held to numpy: the predicate
+# shapes, chunk edges and routing hash no engine arm is the reference for
+# ---------------------------------------------------------------------------
+
+
+@functools.lru_cache(maxsize=None)
+def _synccount():
+    """tests/test_synccount.py, loaded once by path as the diff tools
+    do: the home of the shared forced-partition context."""
+    import importlib.util
+    import os
+    path = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                        "test_synccount.py")
+    spec = importlib.util.spec_from_file_location("sc_fixtures", path)
+    sc = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(sc)
+    return sc
+
+
+def _lineitem():
+    """8,000 rows in 1,024-row chunks: a FOR-coded int key, a string
+    dictionary, a float column with real NULLs, a FOR-coded price; and a
+    fan-out side (two rows a key, no PK) that makes a join partition."""
+    rng = np.random.default_rng(11)
+    n = 8_000
+    cats = np.asarray(["alpha", "beta", "gamma", "delta"], dtype=object)
+    qty = rng.integers(0, 50, n).astype(float)
+    null = rng.random(n) < 0.15
+    cols = {
+        "l_key": rng.integers(1, 500, n),
+        "l_cat": cats[rng.integers(0, 4, n)],
+        "l_qty": np.where(null, np.nan, qty),
+        "l_price": rng.integers(1, 10_000, n),
+    }
+    table = pa.table({
+        "l_key": pa.array(cols["l_key"], pa.int64()),
+        "l_cat": pa.array(cols["l_cat"]),
+        "l_qty": pa.array(qty, mask=null),
+        "l_price": pa.array(cols["l_price"], pa.int64()),
+    })
+    fan = pa.table({
+        "r_key": pa.array(np.repeat(np.arange(1, 500), 2), pa.int64()),
+        "r_amt": pa.array(rng.integers(1, 100, 998), pa.int64()),
+    })
+    return cols, table, fan
+
+
+# (WHERE text, numpy mask over the columns, whether sum(l_price) is asked)
+_PREDICATE_SHAPES = {
+    "str-eq": ("l_cat = 'beta'",
+               lambda c: c["l_cat"] == "beta", True),
+    "str-ne-absent": ("l_cat <> 'omega'",
+                      lambda c: c["l_cat"] != "omega", False),
+    "between": ("l_price between 100 and 5000",
+                lambda c: (c["l_price"] >= 100) & (c["l_price"] <= 5000),
+                True),
+    "in": ("l_key in (1, 2, 3, 499)",
+           lambda c: np.isin(c["l_key"], [1, 2, 3, 499]), False),
+    "not-in-and": ("l_key not in (7, 9) and l_price > 50",
+                   lambda c: ~np.isin(c["l_key"], [7, 9])
+                   & (c["l_price"] > 50), False),
+    "is-null": ("l_qty is null", lambda c: np.isnan(c["l_qty"]), False),
+    "not-null-float-lit": ("l_qty is not null and l_price > 2500.5",
+                           lambda c: ~np.isnan(c["l_qty"])
+                           & (c["l_price"] > 2500.5), True),
+    # NOT IN whose literals are all ABSENT (string dictionary /
+    # fractional at the column's scale): membership is all-false, so
+    # the negation must keep every non-null row
+    "not-in-absent-str": ("l_cat not in ('omega', 'zeta')",
+                          lambda c: ~np.isin(c["l_cat"],
+                                             ["omega", "zeta"]), False),
+    "not-in-fractional": ("l_key not in (2.5, 3.5)",
+                          lambda c: ~np.isin(c["l_key"], [2.5, 3.5]),
+                          False),
+    # mixed-lane BETWEEN (float low bound, int high bound) and the
+    # negated int-lane range
+    "between-mixed": ("l_price between 100.5 and 5000",
+                      lambda c: (c["l_price"] >= 100.5)
+                      & (c["l_price"] <= 5000), True),
+    "not-between": ("l_price not between 100 and 5000",
+                    lambda c: ~((c["l_price"] >= 100)
+                                & (c["l_price"] <= 5000)), False),
+}
+
+
+@pytest.mark.parametrize("drive", ["plain", "partitioned"])
+@pytest.mark.parametrize("shape", list(_PREDICATE_SHAPES))
+def test_streamed_predicate_shapes_match_numpy(shape, drive):
+    """Each predicate shape through the COMPILED chunk pipeline, counted
+    and summed against numpy over the same arrays: under the plain drive
+    loop as a bare scan, and under the forced partition count joined to
+    a fan-out side (two rows a key), where the chunk filter composes
+    with the partition pass's masks over two dispatches a chunk."""
+    import contextlib
+
+    from nds_tpu.listener import drain_stream_events
+    where, mask_of, with_sum = _PREDICATE_SHAPES[shape]
+    cols, table, fan = _lineitem()
+    live = mask_of(cols)
+    assert 0 < live.sum(), shape
+    aggs = "count(*) c" + (", sum(l_price) s" if with_sum else "")
+    if drive == "plain":
+        sql = f"select {aggs} from lineitem where {where}"
+        ctx, mult, parts = contextlib.nullcontext(), 1, 1
+    else:
+        sql = (f"select {aggs} from lineitem, fan "
+               f"where l_key = r_key and {where}")
+        ctx, mult, parts = _synccount()._forced_stream_partitions(), 2, 2
+    want = (int(live.sum()) * mult,)
+    if with_sum:
+        want += (int(cols["l_price"][live].sum()) * mult,)
+    with ctx:
+        s = Session()
+        s.create_temp_view("lineitem", ChunkedTable(table, chunk_rows=1024),
+                           base=True)
+        s.create_temp_view("fan", fan, base=True)
+        drain_stream_events()
+        got = s.sql(sql).collect()
+        events = drain_stream_events()
+    assert [e.path for e in events] == ["compiled"], events
+    assert events[0].chunks == 8 and events[0].partitions == parts
+    assert got == [want], (sql, got, want)
+
+
+def _edge_table(n, chunk_rows, null_tail=False):
+    rng = np.random.default_rng(n)
+    key = rng.integers(1, 100, n)
+    val = rng.integers(1, 1000, n)
+    null = np.zeros(n, dtype=bool)
+    if null_tail:
+        null[-3:] = True                 # the last live rows are NULL keys
+    t = pa.table({"e_key": pa.array(key, pa.int64(), mask=null),
+                  "e_val": pa.array(val, pa.int64())})
+    return key, val, null, ChunkedTable(t, chunk_rows=chunk_rows)
+
+
+# case -> (rows, WHERE text, numpy mask over (key, null), NULL keys in
+# the last live rows)
+_CHUNK_EDGES = {
+    "all-survive": (3000, "e_key >= 1", lambda k, nl: k >= 1, False),
+    "none-survive": (3000, "e_key > 100", lambda k, nl: k > 100, False),
+    "exact-multiple": (3072, "e_key < 50", lambda k, nl: k < 50, False),
+    "one-fewer": (3071, "e_key < 50", lambda k, nl: k < 50, False),
+    "one-more": (3073, "e_key < 50", lambda k, nl: k < 50, False),
+    "null-tail": (3000, "e_key >= 1", lambda k, nl: (k >= 1) & ~nl, True),
+    "null-tail-is-null": (3000, "e_key is null", lambda k, nl: nl, True),
+}
+
+
+@pytest.mark.parametrize("case", list(_CHUNK_EDGES))
+def test_streamed_filter_chunk_edges(case):
+    """The compiled chunk filter at its edges: every row survives; none
+    does; a table of exactly k chunks, one row fewer and one more (the
+    padded last chunk's pad rows never count); NULL keys in the last
+    live rows of the padded last chunk. Count and sum against numpy."""
+    from nds_tpu.listener import drain_stream_events
+    n, where, mask_of, null_tail = _CHUNK_EDGES[case]
+    key, val, null, chunked = _edge_table(n, 1024, null_tail)
+    live = mask_of(key, null)
+    s = Session()
+    s.create_temp_view("edges", chunked, base=True)
+    drain_stream_events()
+    got = s.sql(f"select count(*) c, sum(e_val) s from edges "
+                f"where {where}").collect()
+    events = drain_stream_events()
+    assert [e.path for e in events] == ["compiled"], events
+    assert events[0].chunks == -(-n // 1024)
+    want_sum = int(val[live].sum()) if live.any() else None
+    assert got == [(int(live.sum()), want_sum)], (case, got)
+
+
+def _np_hash_mix(h, data):
+    """numpy re-computation of the routing hash's fold (FNV-style
+    multiplicative mix over the low and high 32 bits of each key)."""
+    data = np.asarray(data)
+    if np.issubdtype(data.dtype, np.floating):
+        data = data.view(np.int64 if data.dtype.itemsize == 8 else np.int32)
+    x = data.astype(np.int64)
+    lo = (x & 0xffffffff).astype(np.uint32)
+    hi = ((x >> 32) & 0xffffffff).astype(np.uint32)
+    h = (h ^ lo) * np.uint32(2654435761)
+    h = h ^ (h >> np.uint32(16))
+    h = (h ^ hi) * np.uint32(2246822519)
+    return h ^ (h >> np.uint32(13))
+
+
+# fold of int64 [0, 1, -1] from the FNV offset basis, as the tree routed
+# rows before the hash had a test of its own
+_GOLDEN_HASH_MIX = [342040849, 3227572040, 2575806610]
+
+
+def test_hash_mix_routing_is_pinned(monkeypatch):
+    """The streamed partition/shard routing hash, pinned by itself: the
+    fold over fixed int32 / int64 / uint32 / float vectors equals a
+    numpy re-computation (and three golden values), and the partition
+    pass of a P = 4 pipeline hands out exactly ``hash & 3`` per row with
+    a histogram equal to ``np.bincount`` of the live rows' ids."""
+    import jax.numpy as jnp
+
+    from nds_tpu.engine import stream as S
+    seed = np.full(6, 2166136261, dtype=np.uint32)
+    vectors = [
+        np.array([0, 1, -1, 7, -2 ** 31, 2 ** 31 - 1], dtype=np.int32),
+        np.array([0, 1, -1, 2 ** 40 + 3, -2 ** 63, 2 ** 63 - 1],
+                 dtype=np.int64),
+        np.array([0, 1, 2 ** 31, 2 ** 32 - 1, 12345, 99], dtype=np.uint32),
+        np.array([0.0, -0.0, 1.5, -2.25, 1e300, np.inf], dtype=np.float64),
+        np.array([0.0, -0.0, 1.5, -2.25, 3e38, np.inf], dtype=np.float32),
+    ]
+    h_dev, h_np = jnp.asarray(seed), seed
+    for v in vectors:
+        h_dev = S._hash_mix(h_dev, jnp.asarray(v))
+        h_np = _np_hash_mix(h_np, v)
+        assert h_dev.dtype == jnp.uint32
+        np.testing.assert_array_equal(np.asarray(h_dev), h_np)
+    one = S._hash_mix(jnp.asarray(seed[:3]),
+                      jnp.asarray(np.array([0, 1, -1], dtype=np.int64)))
+    assert np.asarray(one).tolist() == _GOLDEN_HASH_MIX
+
+    # the partition pass of a real P = 4 pipeline
+    from nds_tpu.listener import drain_stream_events
+    sales, returns = _return_tables(n=2000)
+    monkeypatch.setenv("NDS_TPU_STREAM_PARTITIONS", "4")
+    S.reset_pipeline_cache()
+    s = Session()
+    s.create_temp_view("sales", ChunkedTable(sales, chunk_rows=800),
+                       base=True)
+    s.create_temp_view("returns", returns, base=True)
+    drain_stream_events()
+    first = s.sql(_PART_SQL).collect()
+    assert [e.partitions for e in drain_stream_events()] == [4]
+    (pipe,) = [p for p in S._PIPELINE_CACHE.values()
+               if p.n_partitions == 4]
+    (slot,) = pipe.key_slots
+    seen = []
+    real = pipe._pid_jit
+
+    def spy(flat, n_dev, hist):
+        pids, hist = real(flat, n_dev, hist)
+        seen.append((np.asarray(flat[slot]), int(n_dev), np.asarray(pids),
+                     np.asarray(hist)))
+        return pids, hist
+    monkeypatch.setattr(pipe, "_pid_jit", spy)
+    assert s.sql(_PART_SQL).collect() == first          # the cached pipeline
+    assert len(seen) == 3                # one partition pass per chunk
+    total = np.zeros(4, dtype=np.int64)
+    for keys, n_live, pids, hist in seen:
+        want = (_np_hash_mix(np.full(len(keys), 2166136261,
+                                     dtype=np.uint32), keys)
+                & np.uint32(3)).astype(np.int32)
+        np.testing.assert_array_equal(pids, want)
+        total += np.bincount(want[:n_live], minlength=4)
+        np.testing.assert_array_equal(hist, total)
+    assert total.sum() == 2000 and (total > 0).all()

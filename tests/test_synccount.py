@@ -720,118 +720,6 @@ def _forced_stream_shards(n=_STREAM_AB_SHARD_COUNT):
 
 
 @contextlib.contextmanager
-def _forced_pallas(mode="interpret"):
-    """Pin NDS_TPU_PALLAS — and STRICT stream failures — for one
-    fused-kernel A/B arm: the ONE save/set/restore shared by
-    test_fused_kernel_arm_matches_xla and both differential harnesses'
-    kernel sweeps, so the forced kernel arm can never drift between the
-    fixtures and their checkers. ``interpret`` drives the real Pallas
-    kernels through the interpreter on CPU (tier-1); ``off`` is the
-    XLA-chain reference arm."""
-    import os
-    old = {k: os.environ.get(k) for k in ("NDS_TPU_PALLAS",
-                                          "NDS_TPU_STREAM_STRICT")}
-    os.environ["NDS_TPU_PALLAS"] = mode
-    os.environ["NDS_TPU_STREAM_STRICT"] = "1"
-    try:
-        yield mode
-    finally:
-        for k, v in old.items():
-            if v is None:
-                os.environ.pop(k, None)
-            else:
-                os.environ[k] = v
-
-
-# _STREAM_AB_QUERIES indexes whose chunk-local predicates the shared
-# eligibility rule (analysis/kernel_spec.py) lowers into the fused
-# Pallas scan pass: the fused-kernel A/B arm must report kernel
-# launches > 0 (and the exact fused stage count) on these. ab2 is the
-# encoded-predicate template (ss_ext_sales_price FOR-encodes to int16
-# on the toy table, so its thresholds evaluate on raw codes), ab8 the
-# partitioned fan-out template (the fused pass also emits the
-# partition ids the accumulators/exchange consume).
-_STREAM_AB_KERNEL = (1, 2, 7)
-
-
-def test_fused_kernel_arm_matches_xla():
-    """A/B correctness of the fused Pallas chunk-scan/probe kernels
-    (NDS_TPU_PALLAS=interpret vs off): the WHOLE template sweep must be
-    bit-for-bit identical between the two compiled arms under strict
-    mode and forced partitions — including the encoded-predicate,
-    partitioned and (below) sharded templates. The kernel arm must
-    actually engage on the eligible templates (launches > 0, fused
-    stage counts matching the lowered conjuncts), charge ZERO extra
-    host syncs, and the XLA arm must report no kernel launches."""
-    from nds_tpu.listener import drain_stream_events
-    rows_k, rows_x = [], []
-    with _forced_stream_partitions() as n_parts:
-        with _forced_pallas("interpret"):
-            s = _chunked_star_session(np.random.default_rng(42))
-            drain_stream_events()
-            for i, (q, must_stream) in enumerate(_STREAM_AB_QUERIES):
-                before = _syncs()
-                rows_k.append(s.sql(q).collect())
-                used = _syncs() - before
-                events = drain_stream_events()
-                if must_stream:
-                    assert events and all(e.path == "compiled"
-                                          for e in events), \
-                        f"fused-kernel arm fell back on: {q}"
-                    assert used <= 6, \
-                        f"fused-kernel arm used {used} syncs: {q}"
-                if i in _STREAM_AB_KERNEL:
-                    (e,) = events
-                    assert e.kernel_launches >= e.chunks, (q, e)
-                    assert e.kernel_fused_stages > 0, (q, e)
-                if i in _STREAM_AB_PARTITIONED:
-                    (e,) = events
-                    assert e.partitions == n_parts
-                    assert sum(e.part_rows) == e.rows
-        with _forced_pallas("off"):
-            s2 = _chunked_star_session(np.random.default_rng(42))
-            drain_stream_events()
-            for q, _must in _STREAM_AB_QUERIES:
-                rows_x.append(s2.sql(q).collect())
-            for e in drain_stream_events():
-                assert e.kernel_launches <= 0, \
-                    f"XLA arm reported kernel launches: {e}"
-    for (q, _), a, b in zip(_STREAM_AB_QUERIES, rows_k, rows_x):
-        assert a == b, f"fused-kernel/XLA divergence on: {q}"
-        assert a, f"A/B template unexpectedly empty: {q}"
-
-
-def test_fused_kernel_arm_sharded_matches_xla():
-    """The fused-kernel arm under a forced 2-shard mesh: the partitioned
-    fan-out template runs shard_map'd with the kernel emitting the
-    partition/shard routing ids the exchange consumes — bit-for-bit vs
-    the XLA arm on the same mesh."""
-    import jax
-    if len(jax.local_devices()) < _STREAM_AB_SHARD_COUNT:
-        pytest.skip("needs a multi-device (virtual) mesh")
-    from nds_tpu.listener import drain_stream_events
-    q, _must = _STREAM_AB_QUERIES[7]
-    got = {}
-    for arm in ("interpret", "off"):
-        with _forced_stream_partitions():
-            with _forced_stream_shards() as n_shards:
-                with _forced_pallas(arm):
-                    s = _chunked_star_session(np.random.default_rng(42))
-                    drain_stream_events()
-                    got[arm] = s.sql(q).collect()
-                    (e,) = drain_stream_events()
-                    assert e.path == "compiled" and e.shards == n_shards
-                    if arm == "interpret":
-                        assert e.kernel_launches >= e.chunks, e
-                        assert e.kernel_fused_stages > 0, e
-                    else:
-                        assert e.kernel_launches <= 0, e
-    assert got["interpret"] == got["off"], \
-        f"sharded fused-kernel/XLA divergence on: {q}"
-    assert got["interpret"]
-
-
-@contextlib.contextmanager
 def _forced_stream_partitions(n=_STREAM_AB_PARTITION_COUNT):
     """Pin NDS_TPU_STREAM_PARTITIONS — and STRICT stream failures — for
     one A/B sweep: the ONE save/set/restore shared by

@@ -3,16 +3,11 @@
 
 The TPU compiler is installed where the tests run; it compiles for a chip
 that is DESCRIBED, not attached (no chip time, nothing runs). These cases
-keep its answers for the five kernels of ``nds_tpu/engine/kernels.py`` at
-the shapes ``chip_smoke.py`` really produces at scale factor 1, so every
-later PR is held to them:
-
-* the three segment kernels compile, with a ``tpu_custom_call`` in the
-  program — on a chip a refusal fails the query (no XLA fallback there);
-* the fused chunk scan and the fused probe are REFUSED as written. The
-  four refusals are pinned with ``pytest.raises``: the day someone makes
-  one of them compile, its test fails loudly, becomes a positive case,
-  and ``kernels.scan_kernels_active()`` lets the kernel back on the chip.
+keep its answers for the three segment kernels of
+``nds_tpu/engine/kernels.py`` at the shapes ``chip_smoke.py`` really
+produces at scale factor 1, so every later PR is held to them: they
+compile, with a ``tpu_custom_call`` in the program — on a chip a refusal
+fails the query (no XLA fallback there) — and are refused over a mesh.
 
 The topology is described inside a module-scoped fixture — never at
 import, never in a ``skipif`` or a ``parametrize`` argument: only one
@@ -34,7 +29,7 @@ from nds_tpu.engine import kernels as K
 from nds_tpu.engine import ops as E
 
 # the streamed phase's chunk capacity at SF1 (NDS_TPU_STREAM_CHUNK_ROWS
-# in chip_smoke.py) and the 1 Mi-row shape the refusals were first seen at
+# in chip_smoke.py) and a 1 Mi-row resident bucket
 CHUNK = 131072
 MI = 1 << 20
 
@@ -147,54 +142,6 @@ def test_narrowed_probe_compiles_for_v5e(one_chip, fn, shapes, static):
     lowered.compile()
 
 
-def test_fused_scan_int64_lane_is_refused(one_chip):
-    """REFUSED: ``UNIMPLEMENTED: While rewriting computation to not
-    contain X64 element types ... custom_call_target="tpu_custom_call",
-    operand_layout_constraints={s64[1,1048576]}`` — XLA:TPU emulates
-    int64 by rewriting it away and cannot rewrite through a Mosaic call
-    whose operand is s64."""
-    spec = K.ScanSpec([("ige", 0, 0)], [(0, -1, "id", 0, -1, 1.0)])
-    with pytest.raises(jax.errors.JaxRuntimeError,
-                       match="X64 element types"):
-        _compile(one_chip,
-                 lambda d, n: K.fused_chunk_scan((d, None), n, spec, False),
-                 ((MI,), jnp.int64), ((), jnp.int64))
-
-
-def test_fused_scan_hash_lane_is_refused(one_chip):
-    """REFUSED: ``NotImplementedError: 64-bit types are not supported``
-    — even over an int32 data lane, ``_fold_hash`` casts keys to int64
-    and the ``n_dev`` row bound is int64."""
-    spec = K.ScanSpec([("ige", 0, 0)], [(0, 1, "id", 0, -1, 1.0)],
-                      key_slots=(0,))
-    with pytest.raises(NotImplementedError, match="64-bit types"):
-        _compile(one_chip,
-                 lambda d, v, n: K.fused_chunk_scan((d, v), n, spec, False),
-                 ((MI,), jnp.int32), ((MI,), jnp.bool_), ((), jnp.int64))
-
-
-def test_fused_scan_dict_float_lane_is_refused(one_chip):
-    """REFUSED: ``NotImplementedError: Only 2D gather is supported`` —
-    the float lane decodes sorted-dict codes with a 1-D ``jnp.take``."""
-    spec = K.ScanSpec([("fge", 0, 1.5)], [(0, -1, "dict", 0, 0, 100.0)],
-                      tables=[np.arange(100, dtype=np.int64)])
-    with pytest.raises(NotImplementedError, match="Only 2D gather"):
-        _compile(one_chip,
-                 lambda d, n: K.fused_chunk_scan((d, None), n, spec, False),
-                 ((MI,), jnp.int16), ((), jnp.int64))
-
-
-def test_fused_probe_is_refused(one_chip):
-    """REFUSED: ``NotImplementedError: 64-bit types are not supported``
-    — int64 key views hashed to uint64 against a uint64 table."""
-    with pytest.raises(NotImplementedError, match="64-bit types"):
-        _compile(one_chip,
-                 lambda k, n, rh: K.fused_probe((k,), (None,), n, None, rh,
-                                                False),
-                 ((MI,), jnp.int64), ((), jnp.int64),
-                 ((K._PROBE_MAX_R,), jnp.uint64))
-
-
 def test_segment_kernel_over_a_mesh_is_refused(topo):
     """REFUSED: ``NotImplementedError: Mosaic kernels cannot be
     automatically partitioned. Please wrap the call in a shard_map`` —
@@ -239,24 +186,6 @@ def test_inputs_spanning_devices_take_the_xla_segment_ops(monkeypatch):
     mins, maxs = K.segment_minmax_fused(vals.astype(jnp.float64), gids, 4)
     assert mins.tolist() == [0.0, 1.0, 2.0, 3.0]
     assert maxs.tolist() == [4.0, 5.0, 6.0, 7.0]
-
-
-def test_fused_kernels_stay_off_the_chip(monkeypatch):
-    """The gate that follows from the refusals above: in mode ``tpu`` the
-    fused scan and probe are off by decision (``active_arm`` says xla),
-    while the segment kernels stay on; interpret mode keeps the fused
-    kernels reachable for the parity tests."""
-    monkeypatch.setattr(K, "_pallas_broken", False)
-    monkeypatch.setattr(K, "_pallas_mode", lambda: "tpu")
-    keys = (jnp.zeros(8, dtype=jnp.int64),)
-    assert K.pallas_active(16)
-    assert not K.scan_kernels_active()
-    assert not K.probe_kernel_active(keys, (None,), 1024)
-    assert K.active_arm() == "xla"
-    monkeypatch.setattr(K, "_pallas_mode", lambda: "interpret")
-    assert K.scan_kernels_active()
-    assert K.probe_kernel_active(keys, (None,), 1024)
-    assert K.active_arm() == "pallas"
 
 
 def test_segment_kernel_failure_on_chip_fails_the_query(monkeypatch):

@@ -4,7 +4,7 @@ one command.
 
 A campaign is a declarative arm matrix (built-in preset or JSON file;
 see ``nds_tpu/obs/campaign.py`` for the model): each arm is an env
-overlay over bench.py — fused Pallas kernels on/off, prefetch depth,
+overlay over bench.py — Pallas segment kernels on/off, prefetch depth,
 warm/cold chunk store, 1/2/4/8 stream shards, encoded upload on/off —
 run in order into per-arm ledger + trace artifacts under one campaign
 directory with a schema-versioned manifest. Kill-proof and rerunnable:
@@ -19,7 +19,7 @@ The cross-arm report reuses the existing evidence math end to end —
 ``tools/trace_report.py`` for phase/roofline rendering — and keys every
 row on the arm name RECORDED in the ledger (bench.py's campaign stamp),
 not the file path. Named delta lines answer the deferred questions
-directly: fused-kernel delta (base vs pallas-off), prefetch stall
+directly: pallas-kernel delta (base vs pallas-off), prefetch stall
 hidden vs exposed (base vs prefetch-off), warm-vs-cold store, per-shard
 ICI GB/s vs the ICI roofline, and static-roofline % / unexplained ms
 from the perf_audit cost model.
@@ -195,7 +195,7 @@ def report_lines(arms, campaign_dir, primary):
     # named mechanism deltas: each line prices ONE landed mechanism as
     # primary-vs-ablation geomean ratio (>1 = the ablated arm is slower,
     # i.e. the mechanism wins)
-    named = (("fused-kernel delta", primary, "pallas-off",
+    named = (("pallas-kernel delta", primary, "pallas-off",
               "pallas kernels ablated"),
              ("prefetch overlap delta", primary, "prefetch-off",
               "prefetch ring ablated (stall exposed)"),

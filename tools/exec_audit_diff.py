@@ -31,16 +31,6 @@ disagrees with what actually ran:
   is device-only by construction), and the sync/budget checks above hold
   unchanged — the partition pass is sync-free, so no bound moves.
 
-* **kernel path** — a fused-kernel sweep re-drives the whole set under
-  ``NDS_TPU_PALLAS=interpret`` (the shared ``_forced_pallas`` context):
-  every single-pipeline statement's ``StreamEvent.kernel_fused_stages``
-  must EQUAL the static stage prediction (both sides consume the ONE
-  eligibility rule in ``analysis/kernel_spec.py``), ``kernel_launches``
-  must sit inside the scan-floor/probe-ceiling window, ``stream.kernel``
-  spans must charge ZERO host syncs (kernel launches join the sync
-  model at zero), and the ``_STREAM_AB_KERNEL`` templates must actually
-  engage; ``--inject-drift`` zeroes the kernel predictions too.
-
 * **collective budget** — a SECOND mini-sweep drives the sharded subset
   (``_STREAM_AB_SHARDED``: star join, psum'd grouped aggregate, fan-out
   partitioned join) through the shard_map'd pipeline under a forced
@@ -163,137 +153,6 @@ def predict(queries):
     auditor = ExecAuditor(streamed={"store_sales"})
     return [auditor.audit_sql(sql, query=f"ab{i + 1}")
             for i, (sql, _must) in enumerate(queries)]
-
-
-def collect_kernel_evidence():
-    """Drive the whole A/B sweep through the fused-Pallas arm
-    (``NDS_TPU_PALLAS=interpret`` via the shared ``_forced_pallas``
-    context, forced partitions, strict) and collect the kernel evidence
-    each StreamEvent carries — launches, fused stage counts, and the
-    ``stream.kernel`` span sync deltas the sync model prices at zero."""
-    import numpy as np
-
-    from nds_tpu.listener import drain_stream_events
-    from nds_tpu.obs import trace as obs_trace
-
-    mod = _load_ab_module()
-    queries = mod._STREAM_AB_QUERIES
-    kernel_set = set(getattr(mod, "_STREAM_AB_KERNEL", ()))
-    evidence = []
-    with mod._forced_stream_partitions():
-        with mod._forced_pallas("interpret"):
-            session = mod._chunked_star_session(np.random.default_rng(42))
-            drain_stream_events()
-            obs_trace.drain_spans()
-            for i, (sql, _must) in enumerate(queries):
-                runs = []
-                for sight in ("cold", "warm"):
-                    rows = session.sql(sql).collect()
-                    events = drain_stream_events()
-                    records = obs_trace.drain_spans()
-                    kspans = [r for r in records
-                              if getattr(r, "name", "")
-                              == "stream.kernel"]
-                    runs.append({
-                        "sight": sight,
-                        "paths": [e.path for e in events],
-                        "chunks": [e.chunks for e in events],
-                        "partitions": [e.partitions for e in events],
-                        "kernel_launches": [e.kernel_launches
-                                            for e in events],
-                        "kernel_stages": [e.kernel_fused_stages
-                                          for e in events],
-                        "kern_span_count": len(kspans),
-                        "kern_span_syncs": sum(s.syncs for s in kspans),
-                        "rows": len(rows),
-                    })
-                evidence.append({"idx": i, "sql": sql,
-                                 "cold": runs[0], "warm": runs[1],
-                                 "must_kernel": i in kernel_set})
-    return evidence
-
-
-def compare_kernels(reports, evidence, inject_drift=False):
-    """Check the static kernel-path predictions (exec_audit's
-    ``kernel_scan_chunk``/``kernel_stages``/``kernel_probe_chunk``)
-    against the Pallas-arm runtime evidence:
-
-    * every compiled single-pipeline statement's
-      ``kernel_fused_stages`` must EQUAL the static stage prediction
-      (the shared eligibility rule made both from the same conjuncts);
-    * ``kernel_launches`` must sit inside
-      ``[scan x chunks, (scan + probe x P) x chunks]`` — the exact scan
-      floor plus the probe upper bound;
-    * a predicted scan pass must drain ``stream.kernel`` spans, and
-      those spans must charge ZERO host syncs (kernel launches join the
-      sync-effect model at zero);
-    * the ``_STREAM_AB_KERNEL`` templates must actually engage.
-
-    ``inject_drift`` zeroes every static prediction first — the stage
-    equality (and the engagement floor) must then fail."""
-    ok = True
-    lines = []
-    for ev in evidence:
-        rep = reports[ev["idx"]]
-        scans = [s for s in rep.scans if s.compiled]
-        head = f"[{rep.query}] kernel arm"
-        problems = []
-        # multi-pipeline statements (subquery chains) interleave events
-        # from several scans; the exact checks need the 1:1 case
-        single = len(scans) == 1
-        k_scan = scans[0].kernel_scan_chunk if single else 0
-        k_stages = scans[0].kernel_stages if single else 0
-        k_probe = scans[0].kernel_probe_chunk if single else 0
-        if inject_drift:
-            k_scan = k_stages = k_probe = 0
-        for sight in ("cold", "warm"):
-            r = ev[sight]
-            if r["kern_span_syncs"]:
-                problems.append(
-                    f"{sight} stream.kernel spans charged "
-                    f"{r['kern_span_syncs']} host syncs; the fused pass "
-                    "must be device-only (0)")
-            if not single or len(r["paths"]) != 1 \
-                    or r["paths"] != ["compiled"]:
-                continue
-            got_l = r["kernel_launches"][0]
-            got_s = r["kernel_stages"][0]
-            chunks = r["chunks"][0]
-            P = max(r["partitions"][0], 1)
-            if got_s != k_stages:
-                problems.append(
-                    f"{sight} ran {got_s} fused stages per launch, the "
-                    f"model predicts {k_stages} (kernel model drift)")
-            lo_b = k_scan * chunks
-            hi_b = (k_scan + k_probe * P) * chunks
-            if not (lo_b <= got_l <= hi_b):
-                problems.append(
-                    f"{sight} issued {got_l} kernel launches outside the "
-                    f"static window [{lo_b}, {hi_b}] "
-                    f"(scan {k_scan}/chunk, probe <= {k_probe}/dispatch)")
-            if k_scan and not r["kern_span_count"]:
-                problems.append(
-                    f"{sight} predicted a fused scan pass but drained "
-                    "no stream.kernel spans")
-        if ev["must_kernel"] and not inject_drift:
-            for sight in ("cold", "warm"):
-                if all(n <= 0 for n in ev[sight]["kernel_launches"]):
-                    problems.append(
-                        f"{sight} fused-subset template reported no "
-                        "kernel launches (the Pallas routing fell back)")
-        if not ev["warm"]["rows"]:
-            problems.append("kernel-arm A/B template returned no rows")
-        if problems:
-            ok = False
-            lines.append(f"MISMATCH {head}")
-            lines.extend(f"    {p}" for p in problems)
-        elif ev["must_kernel"]:
-            lines.append(
-                f"ok {head} :: warm launches "
-                f"{ev['warm']['kernel_launches']} stages "
-                f"{ev['warm']['kernel_stages']} (static scan={k_scan} "
-                f"stages={k_stages} probe<={k_probe})")
-    return ok, lines
 
 
 def collect_sharded_evidence():
@@ -550,18 +409,6 @@ def run_diff(inject_drift=False):
     reports = predict(queries)
     evidence = collect_runtime_evidence()
     ok, lines = compare(reports, evidence, inject_drift=inject_drift)
-    # fused-kernel sweep: predictions must run under the SAME forced
-    # envs as the evidence (the kernel budget reads NDS_TPU_PALLAS and
-    # the forced partition count)
-    mod = _load_ab_module()
-    kern_ev = collect_kernel_evidence()
-    with mod._forced_stream_partitions():
-        with mod._forced_pallas("interpret"):
-            kern_reports = predict(queries)
-    ok_k, lines_k = compare_kernels(kern_reports, kern_ev,
-                                    inject_drift=inject_drift)
-    ok = ok and ok_k
-    lines.extend(lines_k)
     shard_ev, n_shards = collect_sharded_evidence()
     if shard_ev:
         # sharded predictions run under the forced mesh env, so the

@@ -119,12 +119,11 @@ def git_changed_files():
 # evidence ledger — the runtime evidence layer the differential
 # harnesses check the audits against; ledger/export edits rerun the
 # corpus passes so span-in-jit and friends stay enforced on them.
-# nds_tpu/engine/kernels.py holds the fused Pallas chunk-scan/probe
-# kernels whose launch/stage counts exec_audit predicts statically
-# (the shared eligibility rule lives in analysis/kernel_spec.py) —
-# kernel edits rerun the corpus passes. Named explicitly even though
-# the nds_tpu/engine prefix already covers it: the kernel-edit contract
-# is load-bearing for the lockstep gate, not an accident of prefixing.
+# nds_tpu/engine/kernels.py holds the Pallas segment kernels whose
+# numeric claims num_audit checks — kernel edits rerun the corpus
+# passes. Named explicitly even though the nds_tpu/engine prefix already
+# covers it: the contract is load-bearing for the lockstep gate, not an
+# accident of prefixing.
 # nds_tpu/engine/prefetch.py (same explicit-naming rationale) holds the
 # bounded prefetch ring whose live set mem_audit prices into admission
 # and whose worker contract the host-sync-in-prefetch-worker rule

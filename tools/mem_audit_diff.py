@@ -41,14 +41,6 @@ a record/trace failure that is not a legitimate routing exception
 re-raises and fails the harness outright, so an engine bug can never
 pose as an eager fallback while the bounds quietly stop being checked.
 
-A fused-KERNEL mini-sweep re-drives the ``_STREAM_AB_KERNEL`` subset
-under ``NDS_TPU_PALLAS=interpret`` (the shared ``_forced_pallas``
-context): the fused Pallas scan/probe kernels reuse the SAME
-proof-sized donated accumulators, so every survivor/partition bound
-must hold unchanged on the Pallas arm — and each template must report
-kernel-launch evidence, else the sweep silently stopped testing the
-kernels.
-
 A SECOND mini-sweep drives the sharded subset (``_STREAM_AB_SHARDED``)
 through the shard_map'd pipeline under a forced 2-shard mesh (the
 shared ``_forced_stream_shards`` context): the runtime shard count must
@@ -261,75 +253,6 @@ def compare(reports, evidence, inject_drift=False):
     return ok, lines
 
 
-def collect_kernel_evidence():
-    """Drive the fused-kernel subset (``_STREAM_AB_KERNEL``) through the
-    Pallas arm (``NDS_TPU_PALLAS=interpret``, the shared
-    ``_forced_pallas`` context + forced partitions, strict): the fused
-    scan/probe kernels reuse the SAME proof-sized donated accumulators,
-    so every survivor/partition bound must hold unchanged — and each
-    template must actually engage the kernels (launch evidence > 0),
-    else the sweep is vacuous. Returns (evidence, row bounds, indexes)."""
-    import numpy as np
-
-    from nds_tpu.listener import drain_stream_events
-
-    mod = _load_ab_module()
-    queries = mod._STREAM_AB_QUERIES
-    idxs = list(getattr(mod, "_STREAM_AB_KERNEL", ()))
-    partitioned = set(getattr(mod, "_STREAM_AB_PARTITIONED", ()))
-    evidence = []
-    with mod._forced_stream_partitions():
-        with mod._forced_pallas("interpret"):
-            session = mod._chunked_star_session(np.random.default_rng(42))
-            bounds = _session_row_bounds(session)
-            drain_stream_events()
-            for i in idxs:
-                sql, _must = queries[i]
-                runs = []
-                for sight in ("cold", "warm"):
-                    rows = session.sql(sql).collect()
-                    events = drain_stream_events()
-                    runs.append({
-                        "sight": sight,
-                        "out_rows": len(rows),
-                        "paths": [e.path for e in events],
-                        "survivors": [e.rows for e in events
-                                      if e.path == "compiled"
-                                      and e.rows >= 0],
-                        "partitions": [e.partitions for e in events
-                                       if e.path == "compiled"],
-                        "part_rows": [list(e.part_rows) for e in events
-                                      if e.path == "compiled"],
-                        "kernel_launches": [e.kernel_launches
-                                            for e in events],
-                    })
-                evidence.append({"sql": sql, "cold": runs[0],
-                                 "warm": runs[1],
-                                 "must_partition": i in partitioned})
-    return evidence, bounds, idxs
-
-
-def compare_kernels(reports, evidence, inject_drift=False):
-    """Kernel-arm soundness: the standard bound checks (via
-    :func:`compare`) on the Pallas-arm evidence, plus the engagement
-    check — a fused-subset template whose drive reported no kernel
-    launches means the kernel routing silently fell back and the sweep
-    stopped testing anything."""
-    ok, lines = compare(reports, evidence, inject_drift=inject_drift)
-    for rep, ev in zip(reports, evidence):
-        launches = [n for s in ("cold", "warm")
-                    for n in ev[s]["kernel_launches"]]
-        if not inject_drift and (not launches
-                                 or all(n <= 0 for n in launches)):
-            ok = False
-            lines.append(f"MISMATCH [{rep.query}] kernel arm: no fused "
-                         "kernel launches reported (the Pallas routing "
-                         "fell back — sweep is vacuous)")
-    lines.append(f"# kernel arm: {len(evidence)} templates re-checked "
-                 "under NDS_TPU_PALLAS=interpret")
-    return ok, lines
-
-
 def collect_sharded_evidence():
     """Drive the sharded subset through the shard_map'd pipeline (forced
     shard count + partitions) and return (evidence, row bounds, forced
@@ -421,20 +344,11 @@ def compare_sharded(reports, shard_ev, n_shards, inject_drift=False):
 
 def run_diff(inject_drift=False):
     """Full harness: execute, predict from real counts, compare — the
-    single-device sweep, the fused-kernel (Pallas-arm) sweep, plus the
-    sharded per-shard-bound sweep."""
+    single-device sweep plus the sharded per-shard-bound sweep."""
     queries, _ = _load_ab_templates()
     evidence, bounds = collect_runtime_evidence()
     reports = predict(queries, bounds)
     ok, lines = compare(reports, evidence, inject_drift=inject_drift)
-    kern_ev, k_bounds, k_idx = collect_kernel_evidence()
-    if kern_ev:
-        k_reports = predict(queries, k_bounds)
-        ok_k, lines_k = compare_kernels([k_reports[i] for i in k_idx],
-                                        kern_ev,
-                                        inject_drift=inject_drift)
-        ok = ok and ok_k
-        lines.extend(lines_k)
     shard_ev, sh_bounds, n_shards = collect_sharded_evidence()
     if shard_ev:
         mod = _load_ab_module()

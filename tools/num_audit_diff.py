@@ -16,9 +16,9 @@ comment with extra steps.  This harness is the check:
   plus an off-catalog extremes table (int32-edge FOR span, max-scale
   decimal(16,10) at MAX_DEC_SCALE);
 
-* drive a fixed query set over those tables through FOUR arms — base
-  (compiled streaming), kernel (NDS_TPU_PALLAS=interpret), sharded
-  (NDS_TPU_STREAM_SHARDS=2), and encoded-off (NDS_TPU_ENCODED=0) — and
+* drive a fixed query set over those tables through THREE arms — base
+  (compiled streaming), sharded (NDS_TPU_STREAM_SHARDS=2), and
+  encoded-off (NDS_TPU_ENCODED=0) — and
   demand bit-for-bit equality of every arm against the plain-width
   eager reference (resident tables, encoding disabled).  The first two
   queries aim literals OUTSIDE the encoded domain in both wrap
@@ -196,7 +196,6 @@ _AB_QUERIES = (
 
 _ARMS = (
     ("base", {}),
-    ("kernel", {"NDS_TPU_PALLAS": "interpret"}),
     ("sharded", {"NDS_TPU_STREAM_SHARDS": "2"}),
     ("encoded-off", {"NDS_TPU_ENCODED": "0"}),
 )

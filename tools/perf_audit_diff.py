@@ -2,9 +2,8 @@
 """Differential validation of the static cost auditor (exactness).
 
 The perf auditor (``nds_tpu/analysis/perf_audit.py``) prices every
-statement's data movement — h2d upload bytes, ICI wire bytes, fused-
-kernel launches — from the same planner decomposition the exec/mem
-audits walk. Unlike the bound-shaped audits, its headline predictions
+statement's data movement — h2d upload bytes, ICI wire bytes — from
+the same planner decomposition the exec/mem audits walk. Unlike the bound-shaped audits, its headline predictions
 claim EQUALITY: the compiled chunk pipeline pads every chunk to one
 capacity and always ships a validity byte per column, so
 ``bytes_h2d = chunks x chunk_cap x sum(width + 1)`` is a closed form,
@@ -31,13 +30,8 @@ checked, mirroring ``tools/mem_audit_diff.py``:
   not the buffers: re-upload must be byte-identical), or when a
   predicted compiled scan produced no byte evidence at all.
 
-Three mini-sweeps extend the check to the other arms:
+Two mini-sweeps extend the check to the other arms:
 
-* **kernel** (``_STREAM_AB_KERNEL`` under ``NDS_TPU_PALLAS=interpret``):
-  h2d equality must hold unchanged (the fused kernels collapse HBM
-  re-reads, not the upload), and measured ``kernel_launches`` must land
-  inside the static ``[kernel_min, kernel_max]`` band — nonzero, else
-  the arm went vacuous;
 * **sharded** (``_STREAM_AB_SHARDED`` on a forced 2-shard mesh):
   measured ``StreamEvent.bytes_ici`` must EQUAL the model's
   exchange+reduce byte arithmetic for ici-exact scans and dominate it
@@ -46,8 +40,8 @@ Three mini-sweeps extend the check to the other arms:
   plain widths — the arm that catches a width table hard-coded to the
   encoded path.
 
-``--inject-drift`` zeroes every predicted byte total and kernel band
-before comparing: a fixture that MUST fail in the h2d, ICI and kernel
+``--inject-drift`` zeroes every predicted byte total
+before comparing: a fixture that MUST fail in the h2d and ICI
 directions (``tests/test_analysis.py`` asserts both directions). Run
 after any change to ``engine/table.py`` chunk shapes,
 ``io/columnar.py`` codec selection, ``parallel/exchange.py`` collective
@@ -131,7 +125,7 @@ def _wire_cols(session):
 def predict(queries, bounds, chunk_rows, wire):
     """PerfReports under the CALLER's env (run inside the same forced
     contexts as the evidence sweep, so the model's partition/shard/
-    kernel/codec choices and the runtime's agree by construction)."""
+    codec choices and the runtime's agree by construction)."""
     from nds_tpu.analysis.mem_audit import MemModel
     from nds_tpu.analysis.perf_audit import PerfAuditor
     model = MemModel(row_bounds=bounds, chunk_rows=chunk_rows)
@@ -142,7 +136,7 @@ def predict(queries, bounds, chunk_rows, wire):
 
 
 def _run_sweep(mod, session, indices):
-    """Cold+warm evidence per template: the byte/kernel fields of every
+    """Cold+warm evidence per template: the byte fields of every
     compiled StreamEvent."""
     from nds_tpu.listener import drain_stream_events
     queries = mod._STREAM_AB_QUERIES
@@ -158,8 +152,6 @@ def _run_sweep(mod, session, indices):
             runs[sight] = {
                 "h2d": [e.bytes_h2d for e in comp if e.bytes_h2d >= 0],
                 "ici": [e.bytes_ici for e in comp if e.bytes_ici >= 0],
-                "kernels": [e.kernel_launches for e in comp
-                            if e.kernel_launches >= 0],
                 "chunks": [e.chunks for e in comp],
                 "n_compiled": len(comp),
             }
@@ -225,40 +217,6 @@ def compare(reports, evidence, inject=False):
     return ok, lines
 
 
-def compare_kernels(reports, evidence, inject=False):
-    """Kernel-arm: h2d equality unchanged + measured launches inside the
-    static band, nonzero (else the Pallas routing fell back and the arm
-    is vacuous)."""
-    ok, lines = compare(reports, evidence, inject=inject)
-    for ev in evidence:
-        rep = reports[ev["idx"]]
-        bands = sorted(((c.kernel_min, c.kernel_max)
-                        for c in rep.scans if c.compiled), reverse=True)
-        if inject:
-            bands = [(0, 0) for _ in bands]
-        problems = []
-        engaged = False
-        for sight in ("cold", "warm"):
-            got = sorted(ev[sight]["kernels"], reverse=True)
-            for (kmin, kmax), g in zip(bands, got):
-                if g > 0:
-                    engaged = True
-                if not (kmin <= g <= kmax):
-                    problems.append(
-                        f"{sight} launched {g} fused kernels outside "
-                        f"the static band [{kmin}, {kmax}]")
-        if not inject and not engaged:
-            problems.append("no fused kernel launches reported (the "
-                            "Pallas routing fell back — arm is vacuous)")
-        if problems:
-            ok = False
-            lines.append(f"MISMATCH [{rep.query}] kernel arm")
-            lines.extend(f"    {p}" for p in problems)
-    lines.append(f"# kernel arm: {len(evidence)} templates re-checked "
-                 "under NDS_TPU_PALLAS=interpret")
-    return ok, lines
-
-
 def compare_sharded(reports, evidence, n_shards, inject=False):
     """Sharded-arm: h2d equality unchanged + measured ICI wire bytes ==
     the exchange+reduce arithmetic (equality for ici-exact scans, lower
@@ -302,8 +260,8 @@ def compare_sharded(reports, evidence, n_shards, inject=False):
 
 
 def run_diff(inject_drift=False):
-    """Full harness: base arm (all templates, forced partitions), fused-
-    kernel arm, sharded arm, encoded-off arm."""
+    """Full harness: base arm (all templates, forced partitions),
+    sharded arm, encoded-off arm."""
     import numpy as np
     mod = _load_ab_module()
     queries = mod._STREAM_AB_QUERIES
@@ -317,22 +275,6 @@ def run_diff(inject_drift=False):
                           _wire_cols(session))
         evidence = _run_sweep(mod, session, all_idx)
     ok, lines = compare(reports, evidence, inject=inject_drift)
-
-    # -- fused-kernel arm ---------------------------------------------------
-    k_idx = list(getattr(mod, "_STREAM_AB_KERNEL", ()))
-    if k_idx:
-        with mod._forced_stream_partitions():
-            with mod._forced_pallas("interpret"):
-                session = mod._chunked_star_session(
-                    np.random.default_rng(42))
-                bounds, chunk_rows = _session_params(session)
-                k_reports = predict(queries, bounds, chunk_rows,
-                                    _wire_cols(session))
-                k_ev = _run_sweep(mod, session, k_idx)
-        ok_k, lines_k = compare_kernels(k_reports, k_ev,
-                                        inject=inject_drift)
-        ok = ok and ok_k
-        lines.extend(lines_k)
 
     # -- sharded arm --------------------------------------------------------
     import jax
@@ -375,11 +317,11 @@ def run_diff(inject_drift=False):
 
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(
-        description="differential validation: static perf-audit byte/"
-        "kernel predictions vs runtime StreamEvent evidence (exactness)")
+        description="differential validation: static perf-audit byte "
+        "predictions vs runtime StreamEvent evidence (exactness)")
     ap.add_argument("--inject-drift", action="store_true",
-                    help="zero every predicted byte total and kernel "
-                    "band before comparing: the harness must FAIL "
+                    help="zero every predicted byte total before "
+                    "comparing: the harness must FAIL "
                     "(model-drift self-test)")
     args = ap.parse_args(argv)
     ok, lines = run_diff(inject_drift=args.inject_drift)
@@ -393,7 +335,7 @@ def main(argv=None) -> int:
         print("# drift fixture correctly rejected (harness is live)")
         return 0
     if ok:
-        print("# perf-audit differential: every measured byte/kernel "
+        print("# perf-audit differential: every measured byte "
               "count matches its static prediction")
         return 0
     print("# perf-audit differential FAILED: update the static cost "

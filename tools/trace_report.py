@@ -88,17 +88,12 @@ ROOFLINE_ICI_GBS = float(os.environ.get("NDS_TPU_ROOFLINE_ICI_GBS", "186"))
 # stream.exchange is the sharded pipeline's per-chunk hash-exchange pass
 # (parallel/exchange.py all-to-alls) — the collective-time column; the
 # one cross-shard reduce rides stream.materialize.
-# stream.kernel is the fused Pallas chunk-scan pre-pass (decode +
-# predicates + routing hash in ONE VMEM-resident launch — it REPLACES
-# stream.partition when the fused arm engages), priced as its own column
-# so the kernels are priced by the same report the campaign reads.
 # "ops" is every engine-primitive span (op.join, op.sort, ...) folded
 # into one column; "stream" is the umbrella's own self time (cache
 # lookup, part flattening: what its stream.* children do not cover).
 PHASES = ("statement", "parse", "plan", "ops",
           "replay.record", "replay.compile", "replay.drive",
-          "stream", "stream.record", "stream.compile", "stream.kernel",
-          "stream.partition",
+          "stream", "stream.record", "stream.compile", "stream.partition",
           "stream.exchange", "stream.prefetch", "stream.drive",
           "stream.eager", "stream.overflow-rerun", "stream.materialize",
           "materialize", "collect")
@@ -161,12 +156,6 @@ def _new_agg():
         # eager fallback would roughly cost compiled — per-chunk drive
         # time of comparable pipelines plus one materialize)
         "drive_ms": 0.0, "drive_n": 0, "mat_ms": 0.0, "mat_n": 0,
-        # per-template stream wall by kernel arm (the stream span's
-        # kernelArm/kernelLaunches annotations): when a trace dir holds
-        # BOTH arms of a template, the report prices fused-vs-XLA
-        "kernel_arms": defaultdict(
-            lambda: defaultdict(lambda: {"ms": 0.0, "launches": 0,
-                                         "scans": 0})),
     }
 
 
@@ -214,12 +203,6 @@ def collect_from_traces(trace_dir):
                 # span annotation) — the async-ingest overlap evidence
                 row["pf_stall"] += max(args.get("prefetchStallMs", 0)
                                        or 0, 0)
-                arm = args.get("kernelArm")
-                if arm:
-                    ka = agg["kernel_arms"][query][arm]
-                    ka["ms"] += e["dur"] / 1e3
-                    ka["launches"] += args.get("kernelLaunches", 0) or 0
-                    ka["scans"] += 1
                 # encoded-columnar accounting rides the stream span
                 # (engine/stream.py annotates bytesH2d/bytesLogical;
                 # the eager loop annotates bytesH2d only; sharded runs
@@ -462,29 +445,6 @@ def render(agg, source, top=10):
         ratio = f"{comp / drive:.2f}" if drive else "inf"
         lines.append(f"# streamed pipeline compile/drive ratio: {ratio} "
                      f"({comp:.1f} ms compile / {drive:.1f} ms drive)")
-    ka = agg.get("kernel_arms") or {}
-    engaged = [q for q, d in ka.items()
-               if any(a.get("launches", 0) > 0 for a in d.values())]
-    if ka:
-        lines.append(f"# fused-kernel coverage: {len(engaged)}/{len(ka)} "
-                     "streamed templates engaged the Pallas scan/probe "
-                     "pass")
-    both = {q: d for q, d in ka.items()
-            if "pallas" in d and "xla" in d}
-    if both:
-        # fused-vs-XLA per-template delta: only meaningful when one
-        # trace dir holds the SAME template under both NDS_TPU_PALLAS
-        # arms (e.g. an A/B pair of power runs)
-        lines.append("# fused-kernel vs XLA per-template stream wall "
-                     "(both arms in this dir)")
-        for q in sorted(both):
-            pa, xa = both[q]["pallas"], both[q]["xla"]
-            delta = xa["ms"] - pa["ms"]
-            pct = (delta / xa["ms"] * 100.0) if xa["ms"] else 0.0
-            lines.append(
-                f"  {q}: fused {pa['ms']:.1f} ms "
-                f"({pa['launches']} launches) vs xla {xa['ms']:.1f} ms "
-                f"-> {delta:+.1f} ms ({pct:+.1f}%)")
     lines.append("")
     lines.append(f"# top host-sync sites (of {sum(sites.values())} "
                  "attributed syncs)")
