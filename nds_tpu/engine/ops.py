@@ -681,6 +681,15 @@ def lazy_shrink_rows() -> int:
     return int(os.environ.get("NDS_TPU_LAZY_SHRINK_ROWS", str(1 << 20)))
 
 
+def count_first(bucket: int) -> bool:
+    """Whether work at ``bucket`` rows waits for a count: past
+    ``lazy_shrink_rows()`` and outside a stream-bounds region (a chunk
+    program reads nothing). The one test of the count-first compaction,
+    the narrowed join probe and the deferred dimension columns: each
+    leaves fact-width work to run at the survivors' bucket."""
+    return bucket > lazy_shrink_rows() and not stream_bounds_on()
+
+
 @_trace.traced("compact")
 def compact_table(table: DeviceTable, mask: jnp.ndarray,
                   shrink: bool = False) -> DeviceTable:
@@ -700,7 +709,7 @@ def compact_table(table: DeviceTable, mask: jnp.ndarray,
     m = mask & live_mask(table.plen, table.nrows)
     cap = min(bucket_len(count_bound(table.nrows)), bucket_len(table.plen))
     bound = min(count_bound(table.nrows), cap)
-    if shrink or (cap > lazy_shrink_rows() and not stream_bounds_on()):
+    if shrink or count_first(cap):
         # adaptive: past this bucket size the gather here and the
         # downstream sorts/segment ops a fat bucket drags through cost more
         # than one (batched) round trip, so resolve now — the transfer
@@ -740,22 +749,87 @@ def _gather_cols_impl(idx, datas, valids):
     return outs, vouts
 
 
+@jax.jit
+@_trace.scoped("gather")
+def _compose_impl(index, match, idx):
+    """A deferred group's row index and match mask at the rows ``idx``
+    keeps: ``take(take(src, index), idx) == take(src, take(index, idx))``
+    element for element, pad slots included."""
+    return (jnp.take(index, idx, axis=0, mode="clip"),
+            None if match is None else jnp.take(match, idx, axis=0,
+                                                mode="clip"))
+
+
+@jax.jit
+@_trace.scoped("gather")
+def _null_extend_impl(valids, match):
+    return tuple(match if v is None else v & match for v in valids)
+
+
+def _gather_rows(table: DeviceTable, idx: jnp.ndarray, tally: list,
+                 composed: bool = False) -> dict:
+    """The columns of ``table`` at rows ``idx``, by name. ``tally`` counts
+    the arrays gathered at ``idx``'s width (data, validity, and a deferred
+    group's composed index and match mask) and, second, those of them that
+    are columns reached through a composed index."""
+    from dataclasses import replace as _replace
+    gathered, groups = table.split()
+    names = list(gathered)
+    cols = list(gathered.values())
+    datas = tuple(c.data for c in cols)
+    valids = tuple(c.valid for c in cols)
+    arrays = len(datas) + sum(v is not None for v in valids)
+    tally[0] += arrays
+    tally[1] += arrays * composed
+    out = {}
+    if cols or not groups:
+        datas, valids = _gather_cols_impl(idx, datas, valids)
+        out = {n: _replace(c, data=d, valid=v)
+               for n, c, d, v in zip(names, cols, datas, valids)}
+    for group, src in groups:
+        # a deferred group: the source's rows through the composed index
+        # (the source may hold deferred groups of its own: a snowflake)
+        sub_idx, match = _compose_impl(group.index, group.match, idx)
+        tally[0] += 1 + (match is not None)
+        sub = _gather_rows(group.source.select(list(src.values())), sub_idx,
+                           tally, True)
+        if match is not None:                  # a LEFT join's misses: NULL
+            ext = _null_extend_impl(tuple(c.valid for c in sub.values()),
+                                    match)
+            sub = {s: _replace(c, valid=v)
+                   for (s, c), v in zip(sub.items(), ext)}
+        out.update({n: sub[s] for n, s in src.items()})
+    return {n: out[n] for n in table.column_names}
+
+
 @_trace.traced("gather")
 def gather_table_rows(table: DeviceTable, idx: jnp.ndarray,
                       nrows: int) -> DeviceTable:
-    """Fused whole-table row gather (clip mode); logical length ``nrows``."""
-    from dataclasses import replace as _replace
-    names = table.column_names
-    cols = [table.columns[n] for n in names]
-    datas = tuple(c.data for c in cols)
-    valids = tuple(c.valid for c in cols)
+    """Fused whole-table row gather (clip mode); logical length ``nrows``.
+    A deferred column group of ``table`` is gathered from its source through
+    the composed index, so the result holds every column."""
+    tally = [0, 0]
+    cols = _gather_rows(table, idx, tally)
     # what the gather moves, from host-known shapes: index width x arrays
-    _trace.annotate(cells=int(idx.shape[0])
-                    * (len(datas) + sum(v is not None for v in valids)))
-    datas, valids = _gather_cols_impl(idx, datas, valids)
-    out = {n: _replace(c, data=d, valid=v)
-           for n, c, d, v in zip(names, cols, datas, valids)}
-    return DeviceTable(out, nrows, plen=int(idx.shape[0]))
+    _trace.annotate(cells=int(idx.shape[0]) * tally[0])
+    if tally[1]:
+        # columns' arrays that skipped the gather at their join's width
+        _trace.annotate(deferredArrays=tally[1])
+    return DeviceTable(cols, nrows, plen=int(idx.shape[0]))
+
+
+def gather_deferred(group, names, nrows) -> dict:
+    """Columns ``names`` of a deferred group at the group's own width: the
+    gather its PK-gather join would have made at once, misses of a LEFT
+    join null-extended."""
+    got = gather_table_rows(group.source.select(names), group.index,
+                            nrows).columns
+    if group.match is None:
+        return got
+    # column by column, as the LEFT join always did: chunk programs trace
+    # these operations and must stay byte-identical
+    return {n: Column(c.kind, c.data, c.valid_mask() & group.match,
+                      c.dict_values, c.enc) for n, c in got.items()}
 
 
 def take_padded(table: DeviceTable, idx: jnp.ndarray, nrows: int) -> DeviceTable:
@@ -768,7 +842,7 @@ def take_padded(table: DeviceTable, idx: jnp.ndarray, nrows: int) -> DeviceTable
         cols = {n: _null_column_like(c, cap)
                 for n, c in table.columns.items()}
         return DeviceTable(cols, 0, plen=cap)
-    if not table.columns:
+    if not table.column_names:
         return DeviceTable({}, nrows, plen=cap)
     return gather_table_rows(table, idx, nrows)
 
@@ -1585,7 +1659,7 @@ def _probe_candidates(left_keys, right_keys, null_safe=False,
     lh = _key_hash_impl(lviews, lvalids, 0, null_safe, count_arr(n_left),
                         l_excl)
     idx = None
-    if plen_l > lazy_shrink_rows():
+    if count_first(plen_l):
         # past the bucket where compact_table reads its count first, so
         # does the probe: the two searches cost 20 dependent gathers each
         # at the width they run at, and after the pk chain's deferred

@@ -9,50 +9,126 @@ every operator ignores (see :mod:`nds_tpu.engine.ops` — bucketed shapes).
 
 from __future__ import annotations
 
+from dataclasses import dataclass
+
 from nds_tpu.engine.column import Column
 
 
+@dataclass(eq=False)
+class DeferredGroup:
+    """The columns a PK-gather join brings to a table, not gathered yet:
+    row ``index[i]`` of ``source`` belongs to the table's row ``i`` (clip
+    mode, as every row gather), NULL where ``match[i]`` is false (a LEFT
+    join's null-extension; None where the misses are the planner's
+    deferred mask). ``ops.gather_table_rows`` gathers the source through
+    ``take(index, idx)``, so the columns first exist at the width of
+    whatever compaction or join consumes the table."""
+
+    source: "DeviceTable"
+    index: object                      # int array at the table's width
+    match: object = None               # bool array at the table's width
+
+
+@dataclass(eq=False)
+class _Deferred:
+    """A table's entry for column ``name`` of ``group.source``."""
+
+    group: DeferredGroup
+    name: str
+
+
 class DeviceTable:
-    def __init__(self, columns: dict[str, Column], nrows: int | None = None,
+    def __init__(self, columns: dict, nrows: int | None = None,
                  plen: int | None = None):
-        self.columns = dict(columns)
+        # name -> Column, or _Deferred until something reads the column
+        self._cols = dict(columns)
         if nrows is None:
-            nrows = len(next(iter(columns.values()))) if columns else 0
+            nrows = self._first_len(0)
         self.nrows = nrows
         # physical length; only meaningful to pass for column-less tables
         # (aggregation contexts carry capacity without materialized columns)
-        if plen is None:
-            plen = len(next(iter(columns.values()))) if columns else nrows
-        self._plen = plen
+        self._plen = self._first_len(nrows) if plen is None else plen
+
+    def _first_len(self, default: int) -> int:
+        for e in self._cols.values():
+            return int(e.group.index.shape[0]) if isinstance(e, _Deferred) \
+                else len(e)
+        return default
 
     @property
     def plen(self) -> int:
-        if self.columns:
-            return len(next(iter(self.columns.values())))
-        return self._plen
+        return self._first_len(self._plen)
+
+    @property
+    def columns(self) -> dict[str, Column]:
+        """Every column, gathered (see :meth:`materialize`). Read one
+        column with ``table[name]``, and names or kinds with
+        ``column_names`` / ``kind``, to leave the rest deferred."""
+        return self.materialize()._cols
+
+    def materialize(self) -> "DeviceTable":
+        """Gather every deferred column at the table's own width, one
+        fused gather a group (what the join that deferred them would have
+        gathered at once), and keep them; returns the table."""
+        from nds_tpu.engine.ops import gather_deferred
+        for group, src in self.split()[1]:
+            got = gather_deferred(group, list(src.values()), self.nrows)
+            for n, s in src.items():
+                self._cols[n] = got[s]
+        return self
 
     @property
     def column_names(self):
-        return list(self.columns.keys())
+        return list(self._cols.keys())
 
     def __getitem__(self, name: str) -> Column:
-        return self.columns[name]
+        if isinstance(self._cols[name], _Deferred):
+            # a deferred column read alone: that one is gathered and kept
+            self._cols[name] = self.select([name]).materialize()._cols[name]
+        return self._cols[name]
 
     def __contains__(self, name: str) -> bool:
-        return name in self.columns
+        return name in self._cols
+
+    def kind(self, name: str) -> str:
+        """A column's device kind, from the source's metadata where the
+        column is deferred (no gather)."""
+        e = self._cols[name]
+        return e.group.source.kind(e.name) if isinstance(e, _Deferred) \
+            else e.kind
+
+    def split(self):
+        """``(gathered, groups)``: the columns that exist, by name, and per
+        deferred group ``(group, {name: source column})``."""
+        gathered, groups = {}, {}
+        for n, e in self._cols.items():
+            if isinstance(e, _Deferred):
+                groups.setdefault(id(e.group), (e.group, {}))[1][n] = e.name
+            else:
+                gathered[n] = e
+        return gathered, list(groups.values())
+
+    def with_deferred(self, source: "DeviceTable", index,
+                      match=None) -> "DeviceTable":
+        """This table with every column of ``source`` joined on as one
+        deferred group (``index`` and ``match`` at this table's width)."""
+        group = DeferredGroup(source, index, match)
+        cols = dict(self._cols)
+        cols.update({n: _Deferred(group, n) for n in source.column_names})
+        return DeviceTable(cols, self.nrows, self.plen)
 
     def select(self, names) -> "DeviceTable":
-        return DeviceTable({n: self.columns[n] for n in names}, self.nrows,
+        return DeviceTable({n: self._cols[n] for n in names}, self.nrows,
                            self.plen)
 
     def with_column(self, name: str, col: Column) -> "DeviceTable":
-        cols = dict(self.columns)
+        cols = dict(self._cols)
         cols[name] = col
         return DeviceTable(cols, self.nrows, self.plen)
 
     def rename(self, mapping: dict[str, str]) -> "DeviceTable":
         return DeviceTable(
-            {mapping.get(n, n): c for n, c in self.columns.items()},
+            {mapping.get(n, n): c for n, c in self._cols.items()},
             self.nrows, self.plen)
 
     def take(self, indices, nrows: int | None = None) -> "DeviceTable":
@@ -62,7 +138,7 @@ class DeviceTable:
         to preserve the logical count."""
         from nds_tpu.engine.ops import gather_table_rows
         n = int(indices.shape[0]) if nrows is None else nrows
-        if not self.columns:
+        if not self._cols:
             return DeviceTable({}, n, plen=int(indices.shape[0]))
         return gather_table_rows(self, indices, n)
 
@@ -76,7 +152,7 @@ class DeviceTable:
         return from_arrow(table, canonical_types)
 
     def __repr__(self):
-        cols = ", ".join(f"{n}:{c.kind}" for n, c in self.columns.items())
+        cols = ", ".join(f"{n}:{self.kind(n)}" for n in self._cols)
         return f"DeviceTable[{self.nrows}/{self.plen} rows]({cols})"
 
 

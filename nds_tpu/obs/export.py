@@ -102,7 +102,9 @@ def rollup(records, top_sites: int = 5) -> dict:
     arrays and the indices or mask out) carries their sum, as does
     ``probeRows`` of ``op.join``: the bucket each join's two binary
     searches ran at (under the probe side's bucket: narrowed to its
-    candidates)."""
+    candidates), and ``deferredArrays`` of ``op.gather``: the columns'
+    arrays read through a composed index, never gathered at the width of
+    the PK-gather join that brought them."""
     phases: dict = {}
     sites: Counter = Counter()
     site_tag: dict = {}
@@ -133,7 +135,7 @@ def rollup(records, top_sites: int = 5) -> dict:
                 p["compileMs"] + max(r.compile_ns - comp, 0) / 1e6, 3)
             if r.parent is None:
                 p["rootMs"] = round(p["rootMs"] + r.dur_ns / 1e6, 3)
-            for k in ("cells", "probeRows"):
+            for k in ("cells", "probeRows", "deferredArrays"):
                 if k in r.attrs:
                     p[k] = p.get(k, 0) + r.attrs[k]
             if r.name == "stream" and r.attrs.get("path") == "eager":

@@ -196,7 +196,7 @@ def outer_extras_table(build: DeviceTable, idx, n_extras,
     cap = int(idx.shape[0])
     for n in template.column_names:
         t = template[n]
-        if n in build.columns:
+        if n in build:
             cols[n] = build[n].take(idx)
         else:
             data = jnp.zeros((cap,) + t.data.shape[1:], dtype=t.data.dtype)
@@ -873,14 +873,7 @@ class Planner:
                         [left[n] for n in l_on], [right[n] for n in r_on],
                         left.nrows, right.nrows)
                     if got is not None:
-                        r_idx, matched = got
-                        cols = dict(left.columns)
-                        rg = E.gather_table_rows(right, r_idx, left.nrows)
-                        for n, c in rg.columns.items():
-                            cols[n] = Column(c.kind, c.data,
-                                             c.valid_mask() & matched,
-                                             c.dict_values, c.enc)
-                        return DeviceTable(cols, left.nrows, plen=left.plen)
+                        return self._pk_joined(left, right, *got)
             return E.join_tables(left, right, l_on, r_on, kind)
         # join with residual and/or expression keys: match pairs on the key
         # columns, filter by the residual conjuncts, then rebuild outer rows
@@ -927,6 +920,21 @@ class Planner:
                 out_parts.append(DeviceTable(cols, n_rx))
         return E.concat_tables(out_parts) if len(out_parts) > 1 else out_parts[0]
 
+    @staticmethod
+    def _pk_joined(fact: DeviceTable, dim: DeviceTable, r_idx,
+                   match=None) -> DeviceTable:
+        """``fact`` with the columns of ``dim`` at rows ``r_idx`` (a PK
+        gather's row index, at the fact's width; ``match``: a LEFT join's
+        hits, its misses NULL). Where a compaction of the fact would read
+        its count first (``E.count_first``), the dimension's columns stay
+        a deferred group: the compaction or join that follows gathers them
+        through the composed index at the survivors' bucket, and a column
+        read before that (a snowflake key, a residual) is gathered alone.
+        Under that bucket, and in every chunk program, they are gathered
+        here, at once: the same group, materialised."""
+        out = fact.with_deferred(dim, r_idx, match)
+        return out if E.count_first(fact.plen) else out.materialize()
+
     def _pk_gather_plan(self, tables, sources, a, b, es):
         """Eligibility of the (a, b) edge batch for a PK gather join.
 
@@ -958,12 +966,13 @@ class Planner:
                 continue
             ok = True
             for fk, dk in zip(fks, dks):
-                fkc, dkc = tables[fact_slot][fk], tables[dim_slot][dk]
-                if fkc.kind == "f64" or dkc.kind == "f64":
+                fkk = tables[fact_slot].kind(fk)
+                dkk = tables[dim_slot].kind(dk)
+                if fkk == "f64" or dkk == "f64":
                     ok = False                 # surrogate keys only
-                if (fkc.kind == "str") != (dkc.kind == "str"):
+                if (fkk == "str") != (dkk == "str"):
                     ok = False
-                if len(es) > 1 and (fkc.kind == "str" or dkc.kind == "str"):
+                if len(es) > 1 and (fkk == "str" or dkk == "str"):
                     ok = False                 # composite pack is int-only
             if ok:
                 return fact_slot, dim_slot, fks, dks
@@ -1042,11 +1051,9 @@ class Planner:
         n = self._synth_keys
         self._synth_keys += 1
         ln, rn = f"__jk{n}_l", f"__jk{n}_r"
-        parts[lo_] = DeviceTable({**parts[lo_].columns, ln: lcol},
-                                 parts[lo_].nrows, plen=parts[lo_].plen)
+        parts[lo_] = parts[lo_].with_column(ln, lcol)
         part_cols[lo_].add(ln)
-        parts[ro_] = DeviceTable({**parts[ro_].columns, rn: rcol},
-                                 parts[ro_].nrows, plen=parts[ro_].plen)
+        parts[ro_] = parts[ro_].with_column(rn, rcol)
         part_cols[ro_].add(rn)
         return (lo_, ro_, ln, rn)
 
@@ -1168,7 +1175,7 @@ class Planner:
         names = [n for n in table.column_names if n.split(".")[-1] in refs]
         if not names:
             return None
-        cols = [table.columns[n] for n in names]
+        cols = [table[n] for n in names]
         plen = table.plen
         from nds_tpu.engine.column import enc_key, encs_equal
         key = (tuple(expr_key(c) for c in exprs), plen,
@@ -1681,10 +1688,7 @@ class Planner:
                     f_excl=masks[fact_slot], d_excl=masks[dim_slot])
             if got is not None:
                 r_idx, matched = got
-                cols = dict(fact_t.columns)
-                cols.update(E.gather_table_rows(
-                    dim_t, r_idx, fact_t.nrows).columns)
-                tables[a] = DeviceTable(cols, fact_t.nrows, plen=fact_t.plen)
+                tables[a] = self._pk_joined(fact_t, dim_t, r_idx)
                 masks[a] = ~matched          # accumulates misses + old masks
                 masks[b] = None
                 sources[a] = sources[fact_slot]   # fact physical survives

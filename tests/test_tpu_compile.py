@@ -142,6 +142,41 @@ def test_narrowed_probe_compiles_for_v5e(one_chip, fn, shapes, static):
     lowered.compile()
 
 
+# the deferred dimension columns at the resident cells' shapes: a PK
+# gather's row index at store_sales' 4 Mi bucket composed with the
+# survivors' index (query10: 256 Ki; query93's LEFT arm with its match
+# mask: 8 Ki), and the null-extension of three gathered validity masks
+COMPOSE_CASES = [
+    ("compose", E._compose_impl,
+     [((1 << 22,), jnp.int64), None, ((1 << 18,), jnp.int64)]),
+    ("compose_left", E._compose_impl,
+     [((1 << 22,), jnp.int64), ((1 << 22,), jnp.bool_),
+      ((1 << 13,), jnp.int64)]),
+    ("null_extend", E._null_extend_impl,
+     [(((1 << 13,), jnp.bool_), None, ((1 << 13,), jnp.bool_)),
+      ((1 << 13,), jnp.bool_)]),
+]
+
+
+@pytest.mark.parametrize("fn,shapes", [c[1:] for c in COMPOSE_CASES],
+                         ids=[c[0] for c in COMPOSE_CASES])
+def test_composed_gather_compiles_for_v5e(one_chip, fn, shapes):
+    """The two jitted bodies a deferred column group adds to a row gather
+    compile for a v5e at SF1's buckets, under the gather's scope name."""
+    def struct(x):
+        """``(shape, dtype)`` -> a described array; None stays; a tuple of
+        them (validity masks) maps through."""
+        if x is None:
+            return None
+        if isinstance(x[0][0], int):
+            return jax.ShapeDtypeStruct(x[0], x[1], sharding=one_chip)
+        return tuple(struct(y) for y in x)
+
+    lowered = fn.lower(*[struct(x) for x in shapes])
+    assert "nds.gather" in lowered.as_text(debug_info=True)
+    lowered.compile()
+
+
 def test_segment_kernel_over_a_mesh_is_refused(topo):
     """REFUSED: ``NotImplementedError: Mosaic kernels cannot be
     automatically partitioned. Please wrap the call in a shard_map`` —
