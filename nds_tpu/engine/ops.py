@@ -2404,9 +2404,14 @@ def concat_tables(tables) -> DeviceTable:
     # bucket: a non-bucket plen (e.g. 16+32=48) would leak into the XLA
     # shape universe and defeat executable reuse downstream
     if total == int(live.shape[0]) and total == bucket_len(total):
-        return raw                                    # no pads anywhere
-    idx = compact_indices(live, total)
-    return take_padded(raw, idx, total)
+        res = raw                                     # no pads anywhere
+    else:
+        res = take_padded(raw, compact_indices(live, total), total)
+    # what the append wrote, from host-known shapes: every column's arrays
+    # (data and validity) at the output's bucket
+    _trace.annotate(cells=res.plen * sum(
+        1 + (v is not None) for v in valids))
+    return res
 
 
 # ---------------------------------------------------------------------------

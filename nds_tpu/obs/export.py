@@ -104,7 +104,9 @@ def rollup(records, top_sites: int = 5) -> dict:
     searches ran at (under the probe side's bucket: narrowed to its
     candidates), and ``deferredArrays`` of ``op.gather``: the columns'
     arrays read through a composed index, never gathered at the width of
-    the PK-gather join that brought them."""
+    the PK-gather join that brought them. ``op.setop`` states the key
+    arrays its DISTINCT reads, ``op.concat`` the arrays it appends at the
+    output's bucket, ``op.window`` rows sorted x arrays scanned."""
     phases: dict = {}
     sites: Counter = Counter()
     site_tag: dict = {}

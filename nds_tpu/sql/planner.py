@@ -436,15 +436,24 @@ class Planner:
             if body.op == "union_all":
                 return E.concat_tables([left, right])
             if body.op == "union":
-                return self._distinct(E.concat_tables([left, right]))
-            # intersect / except: null-safe membership of distinct left rows
-            ldist = self._distinct(left)
-            lkeys = [ldist[n] for n in ldist.column_names]
-            rkeys = [right[n] for n in ldist.column_names]
-            mask = E.semi_join_mask(lkeys, rkeys, negate=(body.op == "except"),
-                                    null_safe=True, n_left=ldist.nrows,
-                                    n_right=right.nrows)
-            return E.compact_table(ldist, mask)
+                left = E.concat_tables([left, right])
+            # the span states the key arrays its DISTINCT reads, at their
+            # bucket; the membership's keys and mask are the op.join /
+            # op.semi_join spans' it opens, counted once
+            with _obs.op("setop", fn=body.op,
+                         cells=E._key_cells([left[n] for n in
+                                             left.column_names])):
+                ldist = self._distinct(left)
+                if body.op == "union":
+                    return ldist
+                # intersect / except: null-safe membership of distinct
+                # left rows
+                lkeys = [ldist[n] for n in ldist.column_names]
+                rkeys = [right[n] for n in ldist.column_names]
+                mask = E.semi_join_mask(
+                    lkeys, rkeys, negate=(body.op == "except"),
+                    null_safe=True, n_left=ldist.nrows, n_right=right.nrows)
+                return E.compact_table(ldist, mask)
         raise ExecError(f"unsupported set expression {type(body).__name__}")
 
     def _distinct(self, t: DeviceTable) -> DeviceTable:
@@ -2195,6 +2204,7 @@ class Planner:
         functions of one (partition, order) spec."""
         skey = (tuple(expr_key(p) for p in w.spec.partition_by),
                 tuple((expr_key(e), d, nl) for e, d, nl in w.spec.order_by))
+        scanned = []       # the columns this function reads at wc.n rows
         if skey not in contexts:
             pcols = [self.eval_expr(p, ctx) for p in w.spec.partition_by]
             ocols = [self.eval_expr(e, ctx) for e, _, _ in w.spec.order_by]
@@ -2202,6 +2212,7 @@ class Planner:
             nl = [n for _, _, n in w.spec.order_by]
             contexts[skey] = WindowContext(pcols, ocols, desc, nl,
                                            n_valid=ctx.table.nrows)
+            scanned += pcols + ocols          # the spec's one sort
         wc = contexts[skey]
         fname = w.func.name
         if fname == "row_number":
@@ -2223,8 +2234,12 @@ class Planner:
                                      rows_frame=frame.startswith("rows"))
             else:
                 col = wc.partition_agg(arg, fname)
+            scanned.append(arg)
         else:
             raise ExecError(f"unsupported window function {fname}")
+        # rows sorted x arrays scanned (the keys where this function made
+        # the spec's sort, its argument, the result scattered back)
+        _obs.annotate(cells=E._key_cells(scanned + [col]))
         ctx.window_values[expr_key(w)] = col
 
     # ----------------------------------------------------------- expressions

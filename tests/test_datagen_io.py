@@ -14,9 +14,19 @@ from nds_tpu.schema import get_maintenance_schemas, get_schemas
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 NDSGEN = os.path.join(REPO, "native", "ndsgen", "ndsgen")
 
-pytestmark = pytest.mark.skipif(
-    not os.path.exists(NDSGEN), reason="native generator not built"
-)
+
+@pytest.fixture(scope="module", autouse=True)
+def built_generator():
+    """The generator these tests run, built as ``benchmark/datagen.py``
+    builds it (the Makefile links to a name of its own and renames, so two
+    makes at once are safe). A checkout that cannot build it fails these
+    tests: skipped, they would count in a used checkout and not in a clean
+    one."""
+    made = subprocess.run(["make", "-C", os.path.dirname(NDSGEN)],
+                          capture_output=True, text=True)
+    assert made.returncode == 0 and os.path.exists(NDSGEN), (
+        f"make -C native/ndsgen: exit {made.returncode}\n"
+        f"{made.stdout[-2000:]}{made.stderr[-2000:]}")
 
 
 def gen(tmp, *extra):

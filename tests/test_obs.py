@@ -838,16 +838,9 @@ def _cells_session():
     return s
 
 
-@pytest.mark.parametrize("case", sorted(_CELLS_QUERIES))
-def test_join_and_semi_join_spans_state_cells_without_a_sync(case):
-    """The attribute is built from host-known shapes alone: the rollup sums
-    it, the statement's rows and its count of host reads are the same with
-    tracing off, and the number is what the shapes give."""
-    s = _cells_session()
-    ta, tb = s.catalog["ta"], s.catalog["tb"]
-    q = _CELLS_QUERIES[case]
-    phase = case.split("-")[0]
-
+def _traced_and_untraced(s, q):
+    """(rows, span records) of ``q`` with tracing on, after checking that
+    tracing off gives the same rows with the same count of host reads."""
     def run():
         E.resolve_counts()                # start from a drained thread
         obs_trace.drain_spans()
@@ -863,6 +856,18 @@ def test_join_and_semi_join_spans_state_cells_without_a_sync(case):
         obs_trace.set_enabled(True)
     assert rows_on == rows_off and rows_on
     assert syncs_on == syncs_off and not nothing
+    return rows_on, records
+
+
+@pytest.mark.parametrize("case", sorted(_CELLS_QUERIES))
+def test_join_and_semi_join_spans_state_cells_without_a_sync(case):
+    """The attribute is built from host-known shapes alone: the rollup sums
+    it, the statement's rows and its count of host reads are the same with
+    tracing off, and the number is what the shapes give."""
+    s = _cells_session()
+    ta, tb = s.catalog["ta"], s.catalog["tb"]
+    phase = case.split("-")[0]
+    _rows, records = _traced_and_untraced(s, _CELLS_QUERIES[case])
     phases = obs_export.rollup(records)["phases"]
     stated = [r.attrs["cells"] for r in records
               if isinstance(r, obs_trace.SpanRecord) and r.name == phase
@@ -903,3 +908,108 @@ def test_the_join_probes_searches_run_under_their_scope():
         assert np.array_equal(
             E._probe_search_impl(build, probe, side=side),
             np.searchsorted(np.asarray(build), np.asarray(probe), side))
+
+
+# ---------------------------------------------------------------------------
+# op.setop[fn], and the cells of op.setop / op.concat / op.window (PR 33)
+# ---------------------------------------------------------------------------
+
+_SETOP_QUERIES = {
+    # each operand keeps duplicates and a NULL key: the DISTINCT and the
+    # null-safe membership both have work to do
+    "union": "select count(*) from (select k, j from ta union "
+             "select k, j from tb) u",
+    "intersect": "select count(*) from (select k, j from ta intersect "
+                 "select k, j from tb) u",
+    "except": "select count(*) from (select k, j from ta except "
+              "select k, j from tb) u",
+    "concat": "select count(*), sum(v) from (select k, v from ta union all "
+              "select k, w from tb) u",
+    "window": "select k, v, rank() over (partition by j order by v desc) r, "
+              "sum(v) over (partition by j) t from ta order by k, v, r",
+}
+
+
+def _nullable_cells_session():
+    s = _cells_session()
+    for name in ("ta", "tb"):
+        t = s.catalog[name].to_arrow()
+        k = t.column("k").to_pylist()
+        k[::7] = [None] * len(k[::7])
+        s.create_temp_view(name, t.set_column(0, "k", pa.array(k, pa.int64())))
+    return s
+
+
+@pytest.mark.parametrize("case", sorted(_SETOP_QUERIES))
+def test_setop_concat_and_window_spans_state_cells_without_a_sync(case):
+    """``op.setop`` carries ``fn`` and the key arrays its DISTINCT reads;
+    ``op.concat`` the arrays it appends at the output's bucket;
+    ``op.window`` rows sorted x arrays scanned. All from host-known shapes:
+    the rows and the count of host reads are the same with tracing off, and
+    the rollup sums each."""
+    s = _nullable_cells_session()
+    ta, tb = s.catalog["ta"], s.catalog["tb"]
+    rows, records = _traced_and_untraced(s, _SETOP_QUERIES[case])
+    phases = obs_export.rollup(records)["phases"]
+    spans = [r for r in records if isinstance(r, obs_trace.SpanRecord)]
+    setops = [r for r in spans if r.name == "op.setop"]
+    arrays = 2 + 1                        # k with its validity, j
+    if case in ("union", "intersect", "except"):
+        assert [r.attrs["fn"] for r in setops] == [case]
+        (span,) = setops
+        assert phases["op.setop"]["cells"] == span.attrs["cells"]
+        assert phases["op.setop"]["ms"] > 0
+    if case == "union":
+        # the DISTINCT reads the appended table: both operands' rows at the
+        # bucket the append came out at; the append states its own
+        out_bucket = E.bucket_len(ta.nrows + tb.nrows)
+        assert span.attrs["cells"] == arrays * out_bucket
+        assert phases["op.concat"]["cells"] == arrays * out_bucket
+        assert "op.semi_join" not in phases
+    elif case in ("intersect", "except"):
+        # the left operand's key arrays at its bucket; the membership's
+        # keys and mask are stated by the op.join / op.semi_join it opens
+        assert span.attrs["cells"] == arrays * ta.plen
+        inner = [r for r in spans if r.name in ("op.semi_join", "op.join")
+                 and r.parent is not None]
+        assert {r.name for r in inner} == {"op.semi_join", "op.join"}
+        assert phases["op.semi_join"]["cells"] > 0
+        assert phases["op.join"]["cells"] > 0
+        assert "op.concat" not in phases
+    elif case == "concat":
+        assert not setops
+        (span,) = [r for r in spans if r.name == "op.concat"]
+        # k (nullable in both operands) and v / w, at the output's bucket
+        assert span.attrs["cells"] == 3 * E.bucket_len(ta.nrows + tb.nrows)
+        assert phases["op.concat"]["cells"] == span.attrs["cells"]
+        assert rows[0][0] == ta.nrows + tb.nrows
+    else:
+        wins = [r for r in spans if r.name == "op.window"]
+        assert [r.attrs["fn"] for r in wins] == ["rank", "sum"]
+        # rank: the spec's sort reads j and v, the result goes back; sum
+        # (another spec: no order) sorts by j, reads v, writes the sums
+        # with their validity
+        assert wins[0].attrs["cells"] == 3 * ta.plen
+        assert wins[1].attrs["cells"] == 4 * ta.plen
+        assert phases["op.window"]["cells"] == 7 * ta.plen
+        assert len(rows) == ta.nrows
+
+
+def test_a_full_outer_join_states_the_unmatched_rows_of_both_sides():
+    """``op.join``'s ``cells`` = both sides' key arrays at their buckets +
+    the two pair-index arrays + the unmatched-row indices of the LEFT and
+    of the RIGHT side (a full outer join keeps both)."""
+    s = _cells_session()
+    ta, tb = s.catalog["ta"], s.catalog["tb"]
+    obs_trace.drain_spans()
+    l_idx, r_idx, _n, l_extra, n_lx, r_extra, n_rx = E.join_indices(
+        [ta["k"], ta["j"]], [tb["k"], tb["j"]], "full",
+        n_left=ta.nrows, n_right=tb.nrows)
+    (span,) = [r for r in obs_trace.drain_spans()
+               if isinstance(r, obs_trace.SpanRecord) and r.name == "op.join"]
+    assert n_lx > 0 and n_rx > 0
+    assert span.attrs["cells"] == (
+        2 * ta.plen + 2 * tb.plen + 2 * int(l_idx.shape[0])
+        + int(l_extra.shape[0]) + int(r_extra.shape[0]))
+    assert int(l_extra.shape[0]) == E.bucket_len(n_lx)
+    assert int(r_extra.shape[0]) == E.bucket_len(n_rx)
