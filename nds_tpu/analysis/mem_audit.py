@@ -97,7 +97,7 @@ from __future__ import annotations
 
 import math
 import os
-from dataclasses import dataclass
+from dataclasses import dataclass, replace
 
 import numpy as np
 
@@ -594,7 +594,9 @@ def statement_needed_names(stmt, catalog_cols: dict | None = None) \
     derived table (CTE or FROM-subquery) needs nothing new (its inner
     projection is explicit and walked); a star over a catalog table adds
     that table's full column set; only a star over an unresolvable name
-    disables pruning. ``catalog_cols`` maps table -> column names
+    disables pruning. The select list of an EXISTS / NOT EXISTS subquery
+    is unobservable, so a star directly under one names nothing (its
+    explicit items still do). ``catalog_cols`` maps table -> column names
     (default: the TPC-DS schema)."""
     if catalog_cols is None:
         catalog_cols = {t: [f.name for f in fields]
@@ -650,7 +652,12 @@ def statement_needed_names(stmt, catalog_cols: dict | None = None) \
             return
         if isinstance(e, (A.ScalarSubquery, A.InSubquery, A.Exists,
                           A.QuantifiedCompare)):
-            walk_query(e.query, ctes)
+            q = e.query
+            if isinstance(e, A.Exists) and isinstance(q.body, A.Select):
+                q = replace(q, body=replace(q.body, items=[
+                    it for it in q.body.items
+                    if not isinstance(it.expr, A.Star)]))
+            walk_query(q, ctes)
             if isinstance(e, (A.InSubquery, A.QuantifiedCompare)):
                 walk_expr(e.expr, ctes, rels)
             return

@@ -106,7 +106,9 @@ def rollup(records, top_sites: int = 5) -> dict:
     arrays read through a composed index, never gathered at the width of
     the PK-gather join that brought them. ``op.setop`` states the key
     arrays its DISTINCT reads, ``op.concat`` the arrays it appends at the
-    output's bucket, ``op.window`` rows sorted x arrays scanned."""
+    output's bucket, ``op.window`` rows sorted x arrays scanned. ``plan``
+    states ``scanColumns``: the columns its catalog scans kept after the
+    projection pushdown, summed over the statement's scans."""
     phases: dict = {}
     sites: Counter = Counter()
     site_tag: dict = {}
@@ -137,7 +139,8 @@ def rollup(records, top_sites: int = 5) -> dict:
                 p["compileMs"] + max(r.compile_ns - comp, 0) / 1e6, 3)
             if r.parent is None:
                 p["rootMs"] = round(p["rootMs"] + r.dur_ns / 1e6, 3)
-            for k in ("cells", "probeRows", "deferredArrays"):
+            for k in ("cells", "probeRows", "deferredArrays",
+                      "scanColumns"):
                 if k in r.attrs:
                     p[k] = p.get(k, 0) + r.attrs[k]
             if r.name == "stream" and r.attrs.get("path") == "eager":

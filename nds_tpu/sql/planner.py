@@ -17,7 +17,7 @@ from __future__ import annotations
 import logging
 import os
 import threading
-from dataclasses import dataclass, field
+from dataclasses import dataclass, field, replace
 
 import jax
 import jax.numpy as jnp
@@ -227,6 +227,9 @@ class Planner:
         # (projection pushdown); None = pruning disabled (SELECT * present
         # or not yet computed)
         self._needed_names: set | None = None
+        # columns the statement's catalog scans kept after the pruning,
+        # summed over its scans: the plan span's ``scanColumns``
+        self._scan_columns = 0
         # roofline accounting: catalog tables this statement actually bound,
         # with their resident byte sizes (per-query scanBytes in summaries)
         self.scanned: dict[str, int] = {}
@@ -259,7 +262,10 @@ class Planner:
         its columns through the join). A star over a derived table needs
         nothing (the inner projection is explicit and its refs are walked);
         a star over a catalog table adds that table's full column set; only
-        a star over an unresolvable name disables pruning."""
+        a star over an unresolvable name disables pruning. The select list
+        of an EXISTS / NOT EXISTS subquery is unobservable (``_eval_exists``
+        reads keys, a residual or a row count, never the list), so a star
+        directly under one names nothing; its explicit items still do."""
         names: set = set()
         star = False
         # names that resolve to derived tables (CTEs) anywhere in the
@@ -335,6 +341,11 @@ class Planner:
                 return
             if isinstance(x, A.ColumnRef):
                 names.add(x.name.lower())
+            if isinstance(x, A.Exists) and isinstance(x.query.body, A.Select):
+                body = x.query.body
+                x = replace(x.query, body=replace(body, items=[
+                    it for it in body.items
+                    if not isinstance(it.expr, A.Star)]))
             here = x if isinstance(x, A.Select) else sel
             if hasattr(x, "__dataclass_fields__"):
                 for f in vars(x).values():
@@ -355,6 +366,7 @@ class Planner:
         top_level = self._needed_names is None and not self.cte_stack
         if top_level:
             self._needed_names = self._collect_needed_names(q)
+            self._scan_columns = 0
         scope = {}
         self.cte_stack.append(scope)
         # the statement-level plan/execute span (this engine plans as it
@@ -370,6 +382,7 @@ class Planner:
                     out = self._apply_order_by(out, q.order_by, q.body)
                 if q.limit is not None:
                     out = E.limit_table(out, q.limit)
+                plan_span.set(scanColumns=self._scan_columns)
                 return out
         finally:
             self.cte_stack.pop()
@@ -528,6 +541,8 @@ class Planner:
                             if n.lower() in self._needed_names]
                     if keep and len(keep) < len(raw.column_names):
                         raw = raw.select(keep)
+                if not in_cte:
+                    self._scan_columns += len(raw.column_names)
                 part = _StreamedScan(raw, alias)
                 return [part], [], [name_l if is_base else None]
             t = self._alias_table(raw, alias)
@@ -539,6 +554,8 @@ class Planner:
                         if n.split(".")[-1] in self._needed_names}
                 if keep and len(keep) < len(t.columns):
                     t = t.select([n for n in t.column_names if n in keep])
+            if not in_cte:
+                self._scan_columns += len(t.column_names)
             return [t], [], [name_l if is_base else None]
         if isinstance(from_, A.SubqueryRef):
             t = self.query(from_.query)

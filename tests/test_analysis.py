@@ -2340,7 +2340,7 @@ def test_num_audit_corpus_proves_clean():
     # un-proves one) must fail loudly — update ONLY together with the
     # matching engine/model change (the lockstep rule)
     assert check_counts(reports) == {
-        "agg": (287, 287), "arith": (61, 61), "codec": (406, 406),
+        "agg": (287, 287), "arith": (61, 61), "codec": (237, 237),
         "hash-bits": (150, 150), "rebase": (35, 35), "scale": (24, 24)}
     assert sum(1 for r in reports if r.proven_safe) == 96
 

@@ -95,3 +95,41 @@ def test_engine_matches_sqlite(oracle_setup, qname):
     engine_rows = engine_date_to_text(session.sql(sql).collect(), None)
     ok, why = rows_match(engine_rows, oracle_rows)
     assert ok, f"{qname}: {why}"
+
+
+# the five Power templates that write ``exists (select * from <fact> ...``:
+# (statement, columns its catalog scans keep, host reads at SF0.01). The
+# reads are the parent's (PR 33's tree, same data, same session): pruning
+# the star's columns adds and removes none.
+EXISTS_STAR_QUERIES = [
+    ("query10", 29, 4), ("query16", 21, 3), ("query35", 26, 4),
+    ("query69", 26, 4), ("query94", 21, 3),
+]
+
+
+@pytest.mark.parametrize("qname,scan_columns,reads", EXISTS_STAR_QUERIES,
+                         ids=[q[0] for q in EXISTS_STAR_QUERIES])
+def test_exists_star_statements_match_sqlite_on_pruned_scans(
+        oracle_setup, qname, scan_columns, reads):
+    """A star under EXISTS names no columns (query10 / 35 / 69: the
+    equality arm over three facts x date_dim; query16 / 94: the residual
+    arm over the outer scan's own fact): the rows are SQLite's, the
+    ``plan`` span states the columns the scans kept (query10: 29, of 189
+    before the rule), and the host reads are unchanged."""
+    from nds_tpu.engine import ops as E
+    from nds_tpu.obs import export as obs_export
+    from nds_tpu.obs import trace as obs_trace
+    from tools.oracle_validate import (engine_date_to_text, execute_oracle,
+                                       rows_match)
+    con, session, queries = oracle_setup
+    sql = queries[qname]
+    E.resolve_counts()
+    obs_trace.drain_spans()
+    before = E.sync_count()
+    engine_rows = engine_date_to_text(session.sql(sql).collect(), None)
+    got_reads = E.sync_count() - before
+    phases = obs_export.rollup(obs_trace.drain_spans())["phases"]
+    ok, why = rows_match(engine_rows, execute_oracle(con, sql))
+    assert ok, f"{qname}: {why}"
+    assert phases["plan"]["scanColumns"] == scan_columns
+    assert got_reads == reads

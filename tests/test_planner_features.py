@@ -9,6 +9,7 @@ import os
 import sys
 
 import pyarrow as pa
+import pytest
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
@@ -222,3 +223,166 @@ def test_rollup_hierarchy_matches_generic_path(monkeypatch):
             key=repr)
     assert norm(got_fast) == norm(got_generic)
     assert len(got_fast) > 10
+
+
+# ---------------------------------------------------------------------------
+# projection pushdown: a star under EXISTS names no columns
+# ---------------------------------------------------------------------------
+
+def _schema_columns():
+    from nds_tpu.schema import get_schemas
+    return {t: [f.name.lower() for f in fields]
+            for t, fields in get_schemas(True).items()}
+
+
+def _planner_names(stmt):
+    """The planner's walk over a catalog that holds the TPC-DS column
+    names and no data (the walk reads ``column_names`` alone)."""
+    from types import SimpleNamespace
+    from nds_tpu.sql.planner import Planner
+    catalog = {t: SimpleNamespace(column_names=cols)
+               for t, cols in _schema_columns().items()}
+    return Planner(catalog)._collect_needed_names(stmt)
+
+
+def _mirror_names(stmt):
+    from nds_tpu.analysis.mem_audit import statement_needed_names
+    return statement_needed_names(stmt)
+
+
+_SEMI = ("c.c_customer_sk = ss_customer_sk and ss_sold_date_sk = d_date_sk "
+         "and d_year = 2002 and d_moy between 1 and 4")
+_SEMI_NAMES = {"c_customer_sk", "ss_customer_sk", "ss_sold_date_sk",
+               "d_date_sk", "d_year", "d_moy"}
+
+# (id, statement, bare names it must name, tables named whole)
+_EXISTS_STAR_CASES = [
+    ("exists_star",
+     f"select c_customer_sk from customer c where exists "
+     f"(select * from store_sales, date_dim where {_SEMI})",
+     _SEMI_NAMES, []),
+    ("not_exists_star",
+     f"select c_customer_sk from customer c where not exists "
+     f"(select * from store_sales, date_dim where {_SEMI})",
+     _SEMI_NAMES, []),
+    ("qualified_star",
+     f"select c_customer_sk from customer c where exists "
+     f"(select ss.* from store_sales ss, date_dim where {_SEMI})",
+     _SEMI_NAMES, []),
+    ("explicit_item_is_named",
+     f"select c_customer_sk from customer c where exists "
+     f"(select ss_item_sk + 1, d.* from store_sales, date_dim d "
+     f"where {_SEMI})",
+     _SEMI_NAMES | {"ss_item_sk"}, []),
+    ("from_subquery_star_keeps_its_scope",
+     "select c_customer_sk from customer c where exists "
+     "(select * from (select * from store_sales) x "
+     "where x.ss_customer_sk = c.c_customer_sk)",
+     {"c_customer_sk"}, ["store_sales"]),
+    ("top_level_star_beside_exists",
+     "select * from customer where exists "
+     "(select * from store_sales where ss_customer_sk = c_customer_sk)",
+     {"ss_customer_sk"}, ["customer"]),
+    ("query16_residual",
+     "select count(distinct cs_order_number) from catalog_sales cs1 "
+     "where exists (select * from catalog_sales cs2 "
+     "where cs1.cs_order_number = cs2.cs_order_number "
+     "and cs1.cs_warehouse_sk <> cs2.cs_warehouse_sk)",
+     {"cs_order_number", "cs_warehouse_sk"}, []),
+    ("set_operation_under_exists_keeps_its_stars",
+     "select c_customer_sk from customer where exists "
+     "(select * from store_sales union all select * from store_sales)",
+     {"c_customer_sk"}, ["store_sales"]),
+]
+
+
+@pytest.mark.parametrize("walker", [_planner_names, _mirror_names],
+                         ids=["planner", "mirror"])
+@pytest.mark.parametrize("sql,named,whole",
+                         [c[1:] for c in _EXISTS_STAR_CASES],
+                         ids=[c[0] for c in _EXISTS_STAR_CASES])
+def test_star_under_exists_names_no_columns(walker, sql, named, whole):
+    """The select list of an EXISTS is unobservable: a star directly
+    under it adds nothing to the statement's needed names, in the
+    planner's walk and in the auditors' mirror of it alike. Everything
+    else the subquery names (its predicates, an explicit item, a star in
+    a FROM-subquery's own scope, a set operation's operands) still is."""
+    from nds_tpu.sql.parser import parse
+    cols = _schema_columns()
+    want = set(named).union(*(cols[t] for t in whole))
+    assert walker(parse(sql)) == want
+
+
+def test_needed_names_mirror_agrees_with_planner_on_corpus():
+    """``statement_needed_names`` is a hand-kept mirror of
+    ``Planner._collect_needed_names``: over every one of the 103 generated
+    statements the two keep the SAME columns of every catalog table (and
+    neither disables pruning). The planner's set may hold more: the
+    output aliases of a CTE under a star (``tpcds_cmax``, ``psum``), which
+    prune the CTE's own table and are no catalog column, so no scan the
+    auditors size can see them."""
+    import numpy as np
+    from nds_tpu.analysis.mem_audit import _AUDIT_SEED
+    from nds_tpu.queries import (TEMPLATE_DIR, instantiate_template,
+                                 list_templates, load_template)
+    from nds_tpu.sql.parser import parse
+    catalog_names = set().union(*_schema_columns().values())
+    n = 0
+    for name in list_templates(TEMPLATE_DIR):
+        sql = instantiate_template(load_template(name, TEMPLATE_DIR),
+                                   np.random.default_rng(_AUDIT_SEED))
+        for text in (s for s in sql.split(";") if s.strip()):
+            stmt = parse(text)
+            got, mirror = _planner_names(stmt), _mirror_names(stmt)
+            n += 1
+            assert got is not None and mirror is not None, name
+            assert mirror <= got, (name, sorted(mirror - got))
+            assert not (got - mirror) & catalog_names, \
+                (name, sorted((got - mirror) & catalog_names))
+    assert n == 103
+
+
+# (id, statement, rows, columns the catalog scans kept: sales has 5, dim 3)
+_EXISTS_ARM_CASES = [
+    ("equality_arm",
+     "select s_order from sales where exists "
+     "(select * from dim where d_sk = s_item and d_cat = 'a') "
+     "order by s_order", [(1,), (1,), (2,)], 2 + 2),
+    ("equality_arm_negated",
+     "select s_order from sales where not exists "
+     "(select * from dim where d_sk = s_item and d_cat = 'a') "
+     "order by s_order", [(3,), (4,)], 2 + 2),
+    ("residual_arm",
+     "select distinct s_order from sales s1 where exists "
+     "(select * from sales s2 where s1.s_order = s2.s_order "
+     "and s1.s_wh <> s2.s_wh) order by s_order", [(1,)], 2 + 2),
+    ("uncorrelated_arm",
+     "select count(*) from sales where s_amt > 5 and exists "
+     "(select * from dim where d_cat = 'b')", [(4,)], 1 + 1),
+    ("uncorrelated_arm_empty",
+     "select count(*) from sales where s_amt > 5 and exists "
+     "(select * from dim where d_cat = 'z')", [(0,)], 1 + 1),
+    # a table nothing names stays whole (select() refuses an empty keep)
+    ("uncorrelated_arm_nothing_named",
+     "select count(*) from sales where s_amt > 5 and exists "
+     "(select * from dim)", [(4,)], 1 + 3),
+    ("uncorrelated_arm_explicit_item",
+     "select count(*) from sales where s_amt > 5 and exists "
+     "(select d_sk + 1 from dim where d_cat = 'b')", [(4,)], 1 + 2),
+]
+
+
+@pytest.mark.parametrize("sql,rows,scan_columns",
+                         [c[1:] for c in _EXISTS_ARM_CASES],
+                         ids=[c[0] for c in _EXISTS_ARM_CASES])
+def test_exists_arms_on_pruned_tables(sql, rows, scan_columns):
+    """Each arm of ``_eval_exists`` gives its rows on tables that carry
+    only the columns the statement names, and the ``plan`` span states
+    how many columns the scans kept."""
+    from nds_tpu.obs import export as obs_export
+    from nds_tpu.obs import trace as obs_trace
+    s = _session()
+    obs_trace.drain_spans()
+    assert s.sql(sql).collect() == rows
+    phases = obs_export.rollup(obs_trace.drain_spans())["phases"]
+    assert phases["plan"]["scanColumns"] == scan_columns
