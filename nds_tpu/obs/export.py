@@ -108,7 +108,10 @@ def rollup(records, top_sites: int = 5) -> dict:
     arrays its DISTINCT reads, ``op.concat`` the arrays it appends at the
     output's bucket, ``op.window`` rows sorted x arrays scanned. ``plan``
     states ``scanColumns``: the columns its catalog scans kept after the
-    projection pushdown, summed over the statement's scans."""
+    projection pushdown, summed over the statement's scans. ``op.subquery``
+    states the key arrays its decorrelation reads as ``cells`` and, each
+    0 / 1 a span and so a count a phase, ``planned`` (this evaluator
+    planned the inner query), ``correlated``, ``residual``, ``negated``."""
     phases: dict = {}
     sites: Counter = Counter()
     site_tag: dict = {}
@@ -140,7 +143,8 @@ def rollup(records, top_sites: int = 5) -> dict:
             if r.parent is None:
                 p["rootMs"] = round(p["rootMs"] + r.dur_ns / 1e6, 3)
             for k in ("cells", "probeRows", "deferredArrays",
-                      "scanColumns"):
+                      "scanColumns", "planned", "correlated", "residual",
+                      "negated"):
                 if k in r.attrs:
                     p[k] = p.get(k, 0) + r.attrs[k]
             if r.name == "stream" and r.attrs.get("path") == "eager":

@@ -14,6 +14,7 @@ by unique suffix match, mirroring SQL scoping.
 
 from __future__ import annotations
 
+import contextlib
 import logging
 import os
 import threading
@@ -2162,35 +2163,53 @@ class Planner:
                                         E.count_int(base_ctx.table.nrows))
         raise ExecError(f"unsupported aggregate {name}")
 
+    @staticmethod
+    @contextlib.contextmanager
+    def _distinct_agg_span(fn: str, gids):
+        """``op.agg[fn]`` around a DISTINCT aggregate's regrouping by
+        (group, argument) and its reduction; ``cells`` = the two arrays
+        regrouped (group ids and argument) at the base width. The device
+        scope ``nds.agg.<fn>`` names the operations between the primitives
+        where this code is traced into a replayed or chunk program (run
+        eagerly, each is a program of its own and carries no outer scope)."""
+        with _obs.op("agg", fn=fn, cells=2 * int(gids.shape[0])), \
+                jax.named_scope(_obs.SCOPE_PREFIX + "agg." + fn):
+            yield
+
     def _count_distinct(self, arg: Column, gids, ng, n_base: int):
         # empty-input fallback: gids comes from the zero-length path, but the
         # padded arg still has plen >= 16, so test the base row count
         if n_base == 0 or gids.shape[0] == 0:
             return Column("i64", jnp.zeros(ng, dtype=jnp.int64))
-        gid_col = Column("i64", gids)
-        inner_gids, inner_ng, inner_rep, inner_cap = E.group_ids(
-            [gid_col, arg], n_valid=n_base)
-        # inner_rep pad slots are out of range: route them to the dropped
-        # segment instead of letting a clipped gather pollute a real group
-        outer_at_rep = jnp.take(gids, inner_rep, mode="fill", fill_value=ng)
-        valid_at_rep = jnp.take(arg.valid_mask(), inner_rep, mode="fill",
-                                fill_value=False).astype(jnp.int64)
-        import jax
-        out = jax.ops.segment_sum(valid_at_rep, outer_at_rep, num_segments=ng)
-        return Column("i64", out)
+        with self._distinct_agg_span("count_distinct", gids):
+            gid_col = Column("i64", gids)
+            inner_gids, inner_ng, inner_rep, inner_cap = E.group_ids(
+                [gid_col, arg], n_valid=n_base)
+            # inner_rep pad slots are out of range: route them to the
+            # dropped segment instead of letting a clipped gather pollute
+            # a real group
+            outer_at_rep = jnp.take(gids, inner_rep, mode="fill",
+                                    fill_value=ng)
+            valid_at_rep = jnp.take(arg.valid_mask(), inner_rep, mode="fill",
+                                    fill_value=False).astype(jnp.int64)
+            out = jax.ops.segment_sum(valid_at_rep, outer_at_rep,
+                                      num_segments=ng)
+            return Column("i64", out)
 
     def _sum_avg_distinct(self, name, arg: Column, gids, ng, n_base: int):
         if n_base == 0 or gids.shape[0] == 0:
             return Column("f64" if name == "avg" else arg.kind,
                           jnp.zeros(ng, dtype=jnp.float64 if name == "avg" else jnp.int64))
-        gid_col = Column("i64", gids)
-        inner_gids, inner_ng, inner_rep, inner_cap = E.group_ids(
-            [gid_col, arg], n_valid=n_base)
-        outer_at_rep = jnp.take(gids, inner_rep, mode="fill", fill_value=ng)
-        rep_arg = arg.take(inner_rep)
-        if name == "sum":
-            return E.agg_sum(rep_arg, outer_at_rep, ng)
-        return E.agg_avg(rep_arg, outer_at_rep, ng)
+        with self._distinct_agg_span(name + "_distinct", gids):
+            gid_col = Column("i64", gids)
+            inner_gids, inner_ng, inner_rep, inner_cap = E.group_ids(
+                [gid_col, arg], n_valid=n_base)
+            outer_at_rep = jnp.take(gids, inner_rep, mode="fill",
+                                    fill_value=ng)
+            rep_arg = arg.take(inner_rep)
+            if name == "sum":
+                return E.agg_sum(rep_arg, outer_at_rep, ng)
+            return E.agg_avg(rep_arg, outer_at_rep, ng)
 
     # --------------------------------------------------------------- windows
 
@@ -2670,6 +2689,10 @@ class Planner:
         (StreamSyncError => eager fallback)."""
         key = self._residual_key(payload)
         hit = self._subquery_residuals.get(key)
+        # every caller is an evaluator below, just inside its op.subquery
+        # span: 1 where this one plans the inner query (the plan's time is
+        # then in the span's inclusive ms), 0 where the registry serves it
+        _obs.annotate(planned=int(hit is None))
         if hit is None:
             if E.stream_bounds_on():
                 if E.replay_mode() == "replay":
@@ -2741,9 +2764,31 @@ class Planner:
             [], None, [])
         return corr, stripped, residual
 
+    # Each evaluator runs under one ``op.subquery`` span (``fn`` = exists /
+    # in / scalar / quantified), opened on entry: its inclusive time holds
+    # the inner plan where this evaluator is the first to ask
+    # ``_residual_table`` for it (``planned``), its self time the
+    # decorrelation alone. ``correlated`` / ``residual`` / ``negated`` are
+    # 0 / 1; ``cells`` = the key arrays the evaluator reads at their
+    # buckets (outer keys + inner keys, each once; the ``op.join`` /
+    # ``op.semi_join`` / ``op.gather`` it opens state their own), all from
+    # host-known shapes: no read is added.
+
+    @staticmethod
+    def _state_correlation(found) -> None:
+        """On the open ``op.subquery`` span: what ``_find_correlation``
+        found, and no cells until an arm states the arrays it reads."""
+        _obs.annotate(correlated=int(found is not None),
+                      residual=int(bool(found and found[2])), cells=0)
+
     def _eval_exists(self, e: A.Exists, ctx: EvalCtx) -> Column:
+        with _obs.op("subquery", fn="exists", negated=int(e.negated)):
+            return self._exists_mask(e, ctx)
+
+    def _exists_mask(self, e: A.Exists, ctx: EvalCtx) -> Column:
         n = ctx.table.plen
         found = self._find_correlation(e.query, ctx)
+        self._state_correlation(found)
         if found is None:
             t = self._residual_table(("query", e.query))
             val = E.count_int(t.nrows) > 0
@@ -2769,6 +2814,10 @@ class Planner:
             pair_cols = dict(E.gather_table_rows(
                 inner_t, r_idx, n_pairs).columns)
             outer_g = E.gather_table_rows(ctx.table, l_idx, n_pairs).columns
+            # both sides whole at the pairs' bucket, and the two index arrays
+            _obs.annotate(cells=E._key_cells(lkeys + rkeys) + E._key_cells(
+                [*pair_cols.values(), *outer_g.values()])
+                + 2 * int(l_idx.shape[0]))
             for nm, c in outer_g.items():
                 pair_cols.setdefault(nm, c)
             pairs = DeviceTable(pair_cols, n_pairs)
@@ -2784,17 +2833,25 @@ class Planner:
         rt = self._residual_table(("query", sub))
         lkeys = [self.eval_expr(outer, ctx) for outer, _ in corr]
         rkeys = [rt[c] for c in rt.column_names]
+        _obs.annotate(cells=E._key_cells(lkeys + rkeys))
         mask = E.semi_join_mask(lkeys, rkeys, negate=e.negated,
                                 n_left=ctx.table.nrows, n_right=rt.nrows)
         return Column("bool", mask)
 
-    def _eval_in_subquery(self, e: A.InSubquery, ctx: EvalCtx) -> Column:
+    def _eval_in_subquery(self, e: A.InSubquery, ctx: EvalCtx,
+                          fn: str = "in") -> Column:
+        with _obs.op("subquery", fn=fn, negated=int(e.negated)):
+            return self._in_mask(e, ctx)
+
+    def _in_mask(self, e: A.InSubquery, ctx: EvalCtx) -> Column:
         found = self._find_correlation(e.query, ctx)
+        self._state_correlation(found)
         if found is None:
             rt = self._residual_table(("query", e.query))
             rcol = rt[rt.column_names[0]]
             lcol = self.eval_expr(e.expr, ctx)
             lcol2, rcol2 = self._coerce_pair(lcol, rcol)
+            _obs.annotate(cells=E._key_cells([lcol2, rcol2]))
             mask = E.semi_join_mask([lcol2], [rcol2], negate=e.negated,
                                     n_left=ctx.table.nrows, n_right=rt.nrows)
             if e.negated:
@@ -2820,6 +2877,7 @@ class Planner:
         for lc, rc in zip(lcols, rcols):
             lc2, _ = self._coerce_pair(lc, rc)
             lcols2.append(lc2)
+        _obs.annotate(cells=E._key_cells(lcols2 + rcols))
         mask = E.semi_join_mask(lcols2, rcols, n_left=ctx.table.nrows,
                                 n_right=rt.nrows)
         if not e.negated:
@@ -2840,8 +2898,13 @@ class Planner:
         return Column("bool", keep)
 
     def _eval_scalar_subquery(self, e: A.ScalarSubquery, ctx: EvalCtx) -> Column:
+        with _obs.op("subquery", fn="scalar", negated=0):
+            return self._scalar_column(e, ctx)
+
+    def _scalar_column(self, e: A.ScalarSubquery, ctx: EvalCtx) -> Column:
         n = ctx.table.plen
         found = self._find_correlation(e.query, ctx)
+        self._state_correlation(found)
         if found is None:
             rt = self._residual_table(("query", e.query))
             col = rt[rt.column_names[0]]
@@ -2893,6 +2956,7 @@ class Planner:
         rkeys = [rt[c] for c in rt.column_names[1:1 + len(corr)]]
         lkeys = [self.eval_expr(outer, ctx) for outer, _ in corr]
         lkeys = [self._coerce_pair(lc, rc)[0] for lc, rc in zip(lkeys, rkeys)]
+        _obs.annotate(cells=E._key_cells(lkeys + rkeys))
         l_idx, r_idx, n_pairs, _, _, _, _ = E.join_indices(
             lkeys, rkeys, "inner", n_left=ctx.table.nrows, n_right=rt.nrows)
         # the subquery was grouped by its correlation keys, so each outer row
@@ -2918,14 +2982,22 @@ class Planner:
                       val_col.enc)
 
     def _eval_quantified(self, e: A.QuantifiedCompare, ctx: EvalCtx) -> Column:
-        n = ctx.table.plen
         if e.op == "=" and e.quantifier == "any":
-            return self._eval_in_subquery(A.InSubquery(e.expr, e.query, False), ctx)
+            return self._eval_in_subquery(
+                A.InSubquery(e.expr, e.query, False), ctx, fn="quantified")
         if e.op == "<>" and e.quantifier == "all":
-            return self._eval_in_subquery(A.InSubquery(e.expr, e.query, True), ctx)
+            return self._eval_in_subquery(
+                A.InSubquery(e.expr, e.query, True), ctx, fn="quantified")
+        with _obs.op("subquery", fn="quantified", negated=0, correlated=0,
+                     residual=0):
+            return self._quantified_mask(e, ctx)
+
+    def _quantified_mask(self, e: A.QuantifiedCompare, ctx: EvalCtx) -> Column:
+        n = ctx.table.plen
         rt = self._residual_table(("query", e.query))
         col = rt[rt.column_names[0]]
         lhs = self.eval_expr(e.expr, ctx)
+        _obs.annotate(cells=E._key_cells([lhs, col]))
         if E.count_int(rt.nrows) == 0:
             val = e.quantifier == "all"
             return Column("bool", jnp.full(n, val, dtype=bool))
