@@ -803,13 +803,14 @@ def _gather_rows(table: DeviceTable, idx: jnp.ndarray, tally: list,
 
 
 @_trace.traced("gather")
-def gather_table_rows(table: DeviceTable, idx: jnp.ndarray,
-                      nrows: int) -> DeviceTable:
+def gather_table_rows(table: DeviceTable, idx: jnp.ndarray, nrows: int,
+                      deferred: bool = False) -> DeviceTable:
     """Fused whole-table row gather (clip mode); logical length ``nrows``.
     A deferred column group of ``table`` is gathered from its source through
-    the composed index, so the result holds every column."""
+    the composed index, so the result holds every column. ``deferred``:
+    ``idx`` is itself a pair table's index (:func:`gather_deferred`)."""
     tally = [0, 0]
-    cols = _gather_rows(table, idx, tally)
+    cols = _gather_rows(table, idx, tally, deferred)
     # what the gather moves, from host-known shapes: index width x arrays
     _trace.annotate(cells=int(idx.shape[0]) * tally[0])
     if tally[1]:
@@ -820,10 +821,10 @@ def gather_table_rows(table: DeviceTable, idx: jnp.ndarray,
 
 def gather_deferred(group, names, nrows) -> dict:
     """Columns ``names`` of a deferred group at the group's own width: the
-    gather its PK-gather join would have made at once, misses of a LEFT
-    join null-extended."""
+    gather its join would have made at once, misses of a LEFT join
+    null-extended."""
     got = gather_table_rows(group.source.select(names), group.index,
-                            nrows).columns
+                            nrows, group.pair).columns
     if group.match is None:
         return got
     # column by column, as the LEFT join always did: chunk programs trace
@@ -2145,11 +2146,29 @@ def _chunk_spans(counts_np, budget):
     return spans
 
 
+def pair_table(left: DeviceTable, l_idx, right: DeviceTable, r_idx,
+               nrows) -> DeviceTable:
+    """The pair table of a join, nothing gathered: row ``i`` is row
+    ``l_idx[i]`` of ``left`` beside row ``r_idx[i]`` of ``right``, each
+    side one deferred group (on a name both sides hold, ``right``'s column
+    wins). A residual reads the columns it names through ``table[name]``,
+    each gathered alone at the pairs' bucket; whatever compacts or joins
+    the table next composes the two indices (a side's own deferred groups,
+    a snowflake, stay reachable: :func:`_gather_rows` recurses)."""
+    return DeviceTable({}, nrows, plen=int(l_idx.shape[0])) \
+        .with_deferred(left, l_idx, pair=True) \
+        .with_deferred(right, r_idx, pair=True)
+
+
 def _chunked_inner_join(left, right, left_keys, right_keys, probe,
                         residual_fn) -> DeviceTable:
-    """Inner join materialized span-by-span so peak memory is bounded by
+    """Inner join made span-by-span so peak memory is bounded by
     ``pair_budget()`` pairs, with residual predicates applied per span
-    before anything is kept — the pair expansion never exists whole."""
+    before anything is kept — the pair expansion never exists whole, and
+    no column of it at all: a span's :func:`pair_table` lets the residual
+    gather the columns it names, the survivors are kept as their two index
+    arrays, and the result is one pair table over the spans' indices,
+    concatenated and squeezed as :func:`concat_tables` does any parts."""
     counts, lo, order, total = probe
 
     def fetch():
@@ -2158,7 +2177,8 @@ def _chunked_inner_join(left, right, left_keys, right_keys, probe,
                 np.concatenate([[0], np.cumsum(counts_np)]))
 
     spans, cum = timed_read("chunk_spans", fetch)
-    parts, schema_chunk = [], None
+    parts, n_spans = [], 0
+    cells = _key_cells(left_keys) + _key_cells(right_keys)
     for (s, e) in spans:
         span_total = int(cum[e] - cum[s])
         if span_total == 0:
@@ -2167,21 +2187,29 @@ def _chunked_inner_join(left, right, left_keys, right_keys, probe,
         l_idx, r_idx = _span_pair_indices(counts, lo, order, s, e, cand)
         ok = _verify_pairs(l_idx, r_idx, left_keys, right_keys)
         ok = ok & live_mask(cand, span_total)
-        raw = DeviceTable(
-            {**gather_table_rows(left, l_idx, cand).columns,
-             **gather_table_rows(right, r_idx, cand).columns}, cand)
-        schema_chunk = raw
         if residual_fn is not None:
-            ok = ok & residual_fn(raw)
+            ok = ok & residual_fn(pair_table(left, l_idx, right, r_idx, cand))
         n_live = host_sync(jnp.sum(ok))                # host sync per span
+        n_spans += 1
+        cells += 2 * cand
         if n_live == 0:
             continue
-        keep = compact_indices(ok, n_live)
-        parts.append(take_padded(raw, keep, n_live))
+        pairs = DeviceTable({"l": Column("i64", l_idx),
+                             "r": Column("i64", r_idx)}, cand)
+        parts.append(take_padded(pairs, compact_indices(ok, n_live), n_live))
+        cells += 2 * parts[-1].plen
+    # what the arm touches, from host-known shapes: both sides' key arrays,
+    # and per span the two pair-index arrays at the candidates' bucket and
+    # the two survivors' at theirs
+    _trace.annotate(cells=cells, spans=n_spans)
     if not parts:
-        empty = jnp.zeros(bucket_len(0), dtype=jnp.int64)
-        return take_padded(schema_chunk, empty + schema_chunk.plen, 0)
-    return concat_tables(parts) if len(parts) > 1 else parts[0]
+        cap0 = bucket_len(0)
+        return pair_table(
+            left, jnp.full(cap0, len(left_keys[0]), dtype=jnp.int64),
+            right, jnp.full(cap0, len(right_keys[0]), dtype=jnp.int64), 0)
+    kept = concat_tables(parts) if len(parts) > 1 else parts[0]
+    return pair_table(left, kept["l"].data, right, kept["r"].data,
+                      kept.nrows)
 
 
 def _exchange_inner_join(left, right, left_keys, right_keys, mesh,
@@ -2224,9 +2252,13 @@ def join_tables(left: DeviceTable, right: DeviceTable, left_on, right_on,
     """Materialized equi-join of two tables; column name collisions must be
     resolved by the caller (planner aliases). ``l_excl``/``r_excl`` fold
     deferred filter masks into the join (see :func:`join_indices`).
-    ``residual_fn`` (inner joins) maps a materialized pair table to a keep
-    mask — non-equi residual predicates evaluated inside the join, before
-    (in the chunked path) any pair expansion is materialized whole."""
+    ``residual_fn`` (inner joins) maps a pair table to a keep mask —
+    non-equi residual predicates evaluated inside the join. Past
+    ``pair_budget()`` candidates (the chunked path) that table is
+    :func:`pair_table`'s, two deferred groups, so the residual gathers the
+    columns it reads by name and no pair expansion is ever made whole; the
+    result is such a table too, and a column of it is gathered when the
+    statement first reads it."""
     left_keys = [left[c] for c in left_on]
     right_keys = [right[c] for c in right_on]
     probe = None

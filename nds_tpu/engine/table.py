@@ -16,17 +16,24 @@ from nds_tpu.engine.column import Column
 
 @dataclass(eq=False)
 class DeferredGroup:
-    """The columns a PK-gather join brings to a table, not gathered yet:
-    row ``index[i]`` of ``source`` belongs to the table's row ``i`` (clip
+    """The columns a join brings to a table, not gathered yet: row
+    ``index[i]`` of ``source`` belongs to the table's row ``i`` (clip
     mode, as every row gather), NULL where ``match[i]`` is false (a LEFT
     join's null-extension; None where the misses are the planner's
-    deferred mask). ``ops.gather_table_rows`` gathers the source through
-    ``take(index, idx)``, so the columns first exist at the width of
-    whatever compaction or join consumes the table."""
+    deferred mask). A PK-gather join leaves the dimension's columns as one
+    group on the fact; a hash join's pair table (``ops.pair_table``) is
+    two groups, one a side, and nothing else. ``ops.gather_table_rows``
+    gathers the source through ``take(index, idx)``, so a column first
+    exists at the width of whatever reads it: the residual that names it,
+    or the compaction or join that consumes the table. ``pair`` marks a
+    side of a pair table: no join gathered its columns at any width, so
+    every array read through ``index`` counts as a deferred one
+    (``op.gather``'s ``deferredArrays``)."""
 
     source: "DeviceTable"
     index: object                      # int array at the table's width
     match: object = None               # bool array at the table's width
+    pair: bool = False
 
 
 @dataclass(eq=False)
@@ -108,11 +115,11 @@ class DeviceTable:
                 gathered[n] = e
         return gathered, list(groups.values())
 
-    def with_deferred(self, source: "DeviceTable", index,
-                      match=None) -> "DeviceTable":
+    def with_deferred(self, source: "DeviceTable", index, match=None,
+                      pair: bool = False) -> "DeviceTable":
         """This table with every column of ``source`` joined on as one
         deferred group (``index`` and ``match`` at this table's width)."""
-        group = DeferredGroup(source, index, match)
+        group = DeferredGroup(source, index, match, pair)
         cols = dict(self._cols)
         cols.update({n: _Deferred(group, n) for n in source.column_names})
         return DeviceTable(cols, self.nrows, self.plen)

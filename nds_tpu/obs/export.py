@@ -102,9 +102,11 @@ def rollup(records, top_sites: int = 5) -> dict:
     arrays and the indices or mask out) carries their sum, as does
     ``probeRows`` of ``op.join``: the bucket each join's two binary
     searches ran at (under the probe side's bucket: narrowed to its
-    candidates), and ``deferredArrays`` of ``op.gather``: the columns'
-    arrays read through a composed index, never gathered at the width of
-    the PK-gather join that brought them. ``op.setop`` states the key
+    candidates; on the chunked arm ``op.join`` also states ``spans``, the
+    spans of probe rows whose pairs it made), and ``deferredArrays`` of
+    ``op.gather``: the columns' arrays read through a composed index, never
+    gathered at the width of the PK-gather join that brought them, and
+    every array read through a pair table's index. ``op.setop`` states the key
     arrays its DISTINCT reads, ``op.concat`` the arrays it appends at the
     output's bucket, ``op.window`` rows sorted x arrays scanned. ``plan``
     states ``scanColumns``: the columns its catalog scans kept after the
@@ -142,7 +144,7 @@ def rollup(records, top_sites: int = 5) -> dict:
                 p["compileMs"] + max(r.compile_ns - comp, 0) / 1e6, 3)
             if r.parent is None:
                 p["rootMs"] = round(p["rootMs"] + r.dur_ns / 1e6, 3)
-            for k in ("cells", "probeRows", "deferredArrays",
+            for k in ("cells", "probeRows", "deferredArrays", "spans",
                       "scanColumns", "planned", "correlated", "residual",
                       "negated"):
                 if k in r.attrs:

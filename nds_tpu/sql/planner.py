@@ -2770,9 +2770,11 @@ class Planner:
     # ``_residual_table`` for it (``planned``), its self time the
     # decorrelation alone. ``correlated`` / ``residual`` / ``negated`` are
     # 0 / 1; ``cells`` = the key arrays the evaluator reads at their
-    # buckets (outer keys + inner keys, each once; the ``op.join`` /
-    # ``op.semi_join`` / ``op.gather`` it opens state their own), all from
-    # host-known shapes: no read is added.
+    # buckets (outer keys + inner keys, each once; on the residual arm of
+    # ``_exists_mask`` also the arrays the residual gathered at the pairs'
+    # bucket and the two index arrays; the ``op.join`` / ``op.semi_join`` /
+    # ``op.gather`` it opens state their own), all from host-known shapes:
+    # no read is added.
 
     @staticmethod
     def _state_correlation(found) -> None:
@@ -2811,18 +2813,18 @@ class Planner:
             l_idx, r_idx, n_pairs, _, _, _, _ = E.join_indices(
                 lkeys, rkeys, "inner",
                 n_left=ctx.table.nrows, n_right=inner_t.nrows)
-            pair_cols = dict(E.gather_table_rows(
-                inner_t, r_idx, n_pairs).columns)
-            outer_g = E.gather_table_rows(ctx.table, l_idx, n_pairs).columns
-            # both sides whole at the pairs' bucket, and the two index arrays
-            _obs.annotate(cells=E._key_cells(lkeys + rkeys) + E._key_cells(
-                [*pair_cols.values(), *outer_g.values()])
-                + 2 * int(l_idx.shape[0]))
-            for nm, c in outer_g.items():
-                pair_cols.setdefault(nm, c)
-            pairs = DeviceTable(pair_cols, n_pairs)
+            # the pairs' table, nothing gathered: the inner side's name
+            # wins, so the outer group holds the names it does not
+            pairs = E.pair_table(
+                inner_t, r_idx, ctx.table.select(
+                    [nm for nm in ctx.table.column_names
+                     if nm not in inner_t]), l_idx, n_pairs)
             ok = self._conjunct_mask(pairs, residual)
             ok = ok & E.live_mask(pairs.plen, pairs.nrows)
+            # the keys, the arrays the residual gathered at the pairs'
+            # bucket, and the two index arrays
+            _obs.annotate(cells=E._key_cells(lkeys + rkeys) + E._key_cells(
+                pairs.split()[0].values()) + 2 * int(l_idx.shape[0]))
             safe = jnp.where(ok, l_idx, n)
             matched = jnp.zeros(n, dtype=bool).at[safe].set(True, mode="drop")
             return Column("bool", ~matched if e.negated else matched)

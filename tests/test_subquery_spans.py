@@ -235,13 +235,14 @@ def test_cells_are_the_key_arrays_at_their_buckets():
     assert plain >= 2 * o.plen + 2 * 16
     # the membership's two sides: o.ord with its validity, r.ord without
     assert cells_of("o.ord in (select ord from r)") == [2 * o.plen + r.plen]
-    # residual arm: the keys, BOTH tables as the pushdown left them (ord and
-    # wh of each: the star under EXISTS names nothing) at the pairs' bucket,
-    # data and validity, and the two pair-index arrays
+    # residual arm: the keys, the two columns the residual names (wh of each
+    # side, data and validity: the pairs' table is two deferred groups and
+    # ord is read by the equality alone) at the pairs' bucket, and the two
+    # pair-index arrays
     (residual,) = cells_of(
         "exists (select * from l where l.ord = o.ord and l.wh <> o.wh)")
     pairs = E.bucket_len(16)
-    assert residual == 2 * o.plen + 2 * ln.plen + 8 * pairs + 2 * pairs
+    assert residual == 2 * o.plen + 2 * ln.plen + 4 * pairs + 2 * pairs
     # nothing is read where nothing correlates and the answer is one count
     assert cells_of("exists (select * from r where r.ord > 8)") == [0]
     assert cells_of("o.amt > (select avg(amt) from l)") == [0]
