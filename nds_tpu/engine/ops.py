@@ -106,30 +106,30 @@ def fetch_bytes() -> int:
 # compile-time accounting: XLA compilation is the dominant first-sight cost
 # at scale (SF1 Power: 70% of the official wall was shape-universe compile)
 # and the reports must split it from execution to be optimizable. JAX's
-# monitoring stream reports every backend compile synchronously on the
-# compiling thread, so thread-local accumulation composes with concurrent
-# Throughput streams exactly like the sync counters above.
+# monitoring stream reports every part of a program build (trace, lowering,
+# persistent-cache hit or miss, backend compile) synchronously on the
+# compiling thread: nds_tpu/obs/compiles.py makes one record a build from
+# them, with its pending parts and per-thread sums in _sync_tls, so it
+# composes with concurrent Throughput streams like the sync counters above.
 _compile_meter_on = False
 
 
-def _compile_event(event: str, secs: float, **kw) -> None:
-    if event == "/jax/core/compile/backend_compile_duration":
-        _sync_tls.compile_ns = (getattr(_sync_tls, "compile_ns", 0)
-                                + int(secs * 1e9))
-
-
 def enable_compile_meter() -> None:
-    """Register the global compile-duration listener (idempotent)."""
+    """Register the compile meter's two listeners (idempotent)."""
     global _compile_meter_on
     if _compile_meter_on:
         return
     from jax import monitoring
-    monitoring.register_event_duration_secs_listener(_compile_event)
+    from nds_tpu.obs import compiles
+    on_event, on_duration = compiles.listeners(_sync_tls)
+    monitoring.register_event_listener(on_event)
+    monitoring.register_event_duration_secs_listener(on_duration)
     _compile_meter_on = True
 
 
 def compile_ns() -> int:
-    """Nanoseconds of XLA backend compilation on the calling thread."""
+    """Nanoseconds the calling thread spent in JAX's backend-compile step:
+    XLA compiles AND persistent-cache reads (obs.compiles splits them)."""
     return getattr(_sync_tls, "compile_ns", 0)
 
 

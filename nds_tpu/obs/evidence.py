@@ -17,6 +17,7 @@ and ring drains only.
 
 from __future__ import annotations
 
+from nds_tpu.obs import compiles as _compiles
 from nds_tpu.obs import export as _export
 from nds_tpu.obs import trace as _trace
 
@@ -24,7 +25,7 @@ from nds_tpu.obs import trace as _trace
 class StatementEvidence:
     """Counters read at :func:`begin`; :meth:`end` returns the deltas."""
 
-    __slots__ = ("_syncs", "_wait", "_fetch", "_compile")
+    __slots__ = ("_syncs", "_wait", "_fetch", "_compile", "_builds")
 
     def __init__(self):
         from nds_tpu.engine import ops
@@ -35,10 +36,17 @@ class StatementEvidence:
         self._wait = ops.sync_wait_ns()
         self._fetch = ops.fetch_bytes()
         self._compile = ops.compile_ns()
+        self._builds = _compiles.thread_sums()
 
     def end(self) -> dict:
         """``{"hostSyncs", "syncWaitMs", "fetchBytes", "compileMs"}`` (the
-        counters' deltas, unrounded), ``"streamEvents"`` (the drained
+        counters' deltas, unrounded; ``compileMs`` is JAX's backend step:
+        XLA compiles AND persistent-cache reads), the split of the call's
+        program builds (:mod:`nds_tpu.obs.compiles`) ``"cacheReadMs"``
+        (the reads inside ``compileMs``: ``compileMs - cacheReadMs`` is
+        the compiling alone), ``"traceLowerMs"`` (tracing and lowering:
+        host time beside ``compileMs``, that no cache saves),
+        ``"cacheHits"`` / ``"cacheMisses"``, ``"streamEvents"`` (the drained
         :class:`StreamEvent` objects) with their JSON form
         ``"streamedScans"``, ``"faults"`` (drained ``FaultEvent``
         objects) with ``"faultEvents"``, ``"records"`` (the drained
@@ -52,6 +60,12 @@ class StatementEvidence:
                "syncWaitMs": (ops.sync_wait_ns() - self._wait) / 1e6,
                "fetchBytes": ops.fetch_bytes() - self._fetch,
                "compileMs": (ops.compile_ns() - self._compile) / 1e6}
+        builds, b0 = _compiles.thread_sums(), self._builds
+        out["cacheReadMs"] = builds["readMs"] - b0["readMs"]
+        out["traceLowerMs"] = (builds["traceMs"] + builds["lowerMs"]
+                               - b0["traceMs"] - b0["lowerMs"])
+        out["cacheHits"] = builds["hits"] - b0["hits"]
+        out["cacheMisses"] = builds["misses"] - b0["misses"]
         events = drain_stream_events()
         faults = drain_fault_events()
         records = _trace.drain_spans()

@@ -14,7 +14,7 @@ from __future__ import annotations
 import json
 from collections import Counter
 
-from nds_tpu.obs.trace import STATEMENT, SpanRecord, SyncSite
+from nds_tpu.obs.trace import COMPILE, STATEMENT, SpanRecord, SyncSite
 
 
 def to_chrome(records, query: str = "", pid: int = 0,
@@ -113,10 +113,20 @@ def rollup(records, top_sites: int = 5) -> dict:
     projection pushdown, summed over the statement's scans. ``op.subquery``
     states the key arrays its decorrelation reads as ``cells`` and, each
     0 / 1 a span and so a count a phase, ``planned`` (this evaluator
-    planned the inner query), ``correlated``, ``residual``, ``negated``."""
+    planned the inner query), ``correlated``, ``residual``, ``negated``.
+    ``phases["compile"]`` (one span a program build,
+    :mod:`nds_tpu.obs.compiles`) carries the sums of its spans'
+    ``backendMs`` / ``readMs`` / ``traceMs`` / ``lowerMs`` and counts
+    their ``cache`` outcomes as ``hits`` / ``misses``; a build is its
+    parent's child, so the parent's ``selfMs`` and self ``compileMs`` no
+    longer hold it.
+
+    ``syncSites``: the ``top_sites`` sites by ``syncs``, each with the
+    time its reads waited (``waitMs``) and the longest one (``maxWaitMs``)."""
     phases: dict = {}
     sites: Counter = Counter()
     site_tag: dict = {}
+    site_wait: dict = {}
     fallbacks = []
     spans = [r for r in records if isinstance(r, SpanRecord)]
     # what each span's same-thread direct children cover
@@ -149,6 +159,11 @@ def rollup(records, top_sites: int = 5) -> dict:
                       "negated"):
                 if k in r.attrs:
                     p[k] = p.get(k, 0) + r.attrs[k]
+            if r.name == COMPILE:
+                for k in ("backendMs", "readMs", "traceMs", "lowerMs"):
+                    p[k] = round(p.get(k, 0.0) + r.attrs.get(k, 0.0), 3)
+                for k, outcome in (("hits", "hit"), ("misses", "miss")):
+                    p[k] = p.get(k, 0) + (r.attrs.get("cache") == outcome)
             if r.name == "stream" and r.attrs.get("path") == "eager":
                 fallbacks.append({
                     "table": r.attrs.get("table", "?"),
@@ -157,10 +172,15 @@ def rollup(records, top_sites: int = 5) -> dict:
         elif isinstance(r, SyncSite):
             sites[r.site] += r.syncs
             site_tag.setdefault(r.site, r.tag)
+            wait = site_wait.setdefault(r.site, [0, 0])
+            wait[0] += r.wait_ns
+            wait[1] = max(wait[1], r.wait_ns)
     if "stream" in phases:
         phases["stream"]["leadInMs"] = round(_lead_in_ns(spans) / 1e6, 3)
     out = {"phases": phases,
-           "syncSites": [{"site": s, "tag": site_tag[s], "syncs": n}
+           "syncSites": [{"site": s, "tag": site_tag[s], "syncs": n,
+                          "waitMs": round(site_wait[s][0] / 1e6, 3),
+                          "maxWaitMs": round(site_wait[s][1] / 1e6, 3)}
                          for s, n in sites.most_common(top_sites)]}
     if fallbacks:
         out["fallbacks"] = fallbacks

@@ -409,3 +409,30 @@ def annotate(**attrs) -> None:
     st = getattr(_tls, "stack", None)
     if st:
         st[-1].attrs.update(attrs)
+
+
+# one program build (trace, lowering, backend compile or cache read). Kept
+# at the end of the file: the lines above sit in the call stack of every
+# scoped body, and a Mosaic program is keyed by where those lines are.
+COMPILE = "compile"
+
+
+def note_compile(ts_ns: int, dur_ns: int, compile_ns: int, **attrs) -> None:
+    """Record one program build as a finished ``compile`` span of the
+    calling thread (called by :mod:`nds_tpu.obs.compiles` when JAX closes
+    the build: its time is known only once it is over, like a sync
+    site's): a driver-thread child of the innermost open span, cut to
+    start inside it, so the parent's self time no longer holds the build.
+    ``compile_ns`` is the build's share of ``ops.compile_ns()``."""
+    if not _enabled:
+        return
+    st = getattr(_tls, "stack", None)
+    top = st[-1] if st else None
+    rec = SpanRecord(COMPILE, attrs, top.qid if top else None)
+    if top is not None:
+        rec.parent = top.sid
+        end_ns = ts_ns + dur_ns
+        ts_ns = min(max(ts_ns, top.ts_ns), end_ns)
+        dur_ns = end_ns - ts_ns
+    rec.ts_ns, rec.dur_ns, rec.compile_ns = ts_ns, dur_ns, compile_ns
+    _emit(rec)

@@ -40,7 +40,11 @@ Reads every ``*.trace.json`` a driver wrote (``nds_power.py --trace-dir``
 5. a ranked NEXT-BOTTLENECK summary — host-sync blocking, eager
    fallbacks, compile time, HBM-roofline headroom and ICI-roofline
    headroom, each priced in attributable milliseconds across the run —
-   ROADMAP's "name the next bottleneck from data" as one command.
+   ROADMAP's "name the next bottleneck from data" as one command;
+6. from a ledger, COMPILE BY PROGRAM — the ``compiles`` block of the
+   terminal record (``nds_tpu/obs/compiles.py``): the process's program
+   builds in all and by program name, each with its builds, cache hits
+   and misses, XLA compile ms, cache-read ms, trace ms and lowering ms.
 
 The input may be a ``--trace-dir`` of per-query Chrome traces OR a
 campaign evidence ledger file (``nds_tpu/obs/ledger.py`` — bench.py
@@ -91,7 +95,11 @@ ROOFLINE_ICI_GBS = float(os.environ.get("NDS_TPU_ROOFLINE_ICI_GBS", "186"))
 # "ops" is every engine-primitive span (op.join, op.sort, ...) folded
 # into one column; "stream" is the umbrella's own self time (cache
 # lookup, part flattening: what its stream.* children do not cover).
-PHASES = ("statement", "parse", "plan", "ops",
+# "compile" is every program build (trace, lowering, XLA compile or
+# persistent-cache read: one span each, nds_tpu/obs/compiles.py), taken
+# out of the phase that asked for it: stream.compile / replay.compile
+# keep their own dispatch and re-trace.
+PHASES = ("statement", "parse", "plan", "ops", "compile",
           "replay.record", "replay.compile", "replay.drive",
           "stream", "stream.record", "stream.compile", "stream.partition",
           "stream.exchange", "stream.prefetch", "stream.drive",
@@ -334,7 +342,8 @@ def bottlenecks(agg):
     # per row, the larger of span-phase compile and the driver's compile
     # meter (ledger rows) — the meter covers compiles no span wraps
     compile_ms = sum(max(r["phases"].get("stream.compile", 0.0)
-                         + r["phases"].get("replay.compile", 0.0),
+                         + r["phases"].get("replay.compile", 0.0)
+                         + r["phases"].get("compile", 0.0),
                          r.get("compile_ms", 0.0))
                      for r in per_query)
     if compile_ms > 0:
@@ -437,8 +446,11 @@ def render(agg, source, top=10):
                 tail += " - | - |"
         lines.append(f"| {q} | {r['total_ms']:.1f} | {cells} | "
                      f"{r['syncs']} |" + tail)
+    # the first dispatch of each chunk program and, since builds are
+    # spans of their own, the programs the streamed statements built
     comp = sum(r["phases"].get("stream.compile", 0.0)
-               for r in per_query.values())
+               + r["phases"].get("compile", 0.0)
+               for r in per_query.values() if "stream.compile" in r["phases"])
     drive = sum(r["phases"].get("stream.drive", 0.0)
                 for r in per_query.values())
     if comp or drive:
@@ -908,6 +920,34 @@ def profile_report(profile_dir, top=10):
     return lines
 
 
+def compile_report_lines(path, top=10):
+    """"compile by program" from a ledger's terminal record: the
+    process's program builds (``compiles`` = the totals of
+    ``nds_tpu/obs/compiles.py`` and its twenty programs dearest to
+    compile). [] for a ledger without the block (an older one, a killed
+    run)."""
+    sys.path.insert(0, REPO)
+    from tools._ledger_load import ledger_mod   # stdlib-only: no jax
+    comp = (ledger_mod().load_ledger(path).end or {}).get("compiles")
+    if not comp:
+        return []
+
+    def row(r):
+        return (f"{r.get('builds', 0):6d} {r.get('hits', 0):5d} "
+                f"{r.get('misses', 0):6d} {r.get('backendMs', 0.0):11.1f} "
+                f"{r.get('readMs', 0.0):9.1f} {r.get('traceMs', 0.0):9.1f} "
+                f"{r.get('lowerMs', 0.0):9.1f}")
+    lines = ["", "# compile by program (the process's builds; backend = "
+             "XLA compiles on cache misses, read = persistent-cache "
+             "hits, trace + lower = host Python no cache saves)",
+             "  builds  hits misses  backend ms   read ms  trace ms  "
+             "lower ms  program",
+             "  " + row(comp) + "  (all)"]
+    for r in (comp.get("programs") or [])[:top]:
+        lines.append("  " + row(r) + f"  {r.get('program', '?')}")
+    return lines
+
+
 def report(source, top=10):
     """Aggregate a --trace-dir (directory) or a campaign evidence ledger
     (file); returns the printable lines."""
@@ -921,7 +961,11 @@ def report(source, top=10):
         agg = collect_from_ledger(source)
         if agg is None:
             return [f"# no completed query records in ledger {source}"]
-        return render(agg, source, top=top) + metrics_report_lines(source)
+        # the live-metrics section stays last (append-only: a ledger
+        # without those records reads exactly as the lines before it)
+        return (render(agg, source, top=top)
+                + compile_report_lines(source, top=top)
+                + metrics_report_lines(source))
     return render(agg, source, top=top)
 
 
