@@ -1585,6 +1585,11 @@ def _probe_search_impl(rh_sorted, lh, side):
 # the build side's prefix bitmap: a power of two of slots, at least 8x the
 # build bucket (a live build row sets one slot in eight at most), capped
 _PRESENT_BITS_MAX = 26
+# a narrowed PK probe's bitmap of the dimension's mixed keys: at least 16x
+# the dimension's bucket, under the same cap (a live row sets one slot in
+# sixteen at most: store_sales against store_returns at SF1 keeps 375 k
+# candidates of 2.88 M rows, 72% of a 512 Ki bucket; at 8x about 466 k)
+_PK_PRESENT_FACTOR = 16
 # a probe-side sentinel (side tag 2, REAL bit clear: _key_hash_impl's
 # layout) for the pad slots of a narrowed probe: equals no build hash
 _PROBE_PAD = np.uint64(2)
@@ -1624,6 +1629,16 @@ def _probe_widen_impl(idx, counts, lo, plen):
     zeros on every row that was not searched (pad slots drop)."""
     return (jnp.zeros(plen, counts.dtype).at[idx].set(counts, mode="drop"),
             jnp.zeros(plen, lo.dtype).at[idx].set(lo, mode="drop"))
+
+
+def _narrowed_indices(mask, plen: int):
+    """The step a count-first search shares: ONE batched, counted,
+    replay-logged read of how many rows of ``mask`` (at ``plen``) can
+    match, then their indices at that count's bucket. Returns ``(n, idx)``;
+    ``idx`` is None where the bucket is no smaller than ``plen`` (the
+    search then runs at full width and the read was the only cost)."""
+    n = DeviceCount(jnp.sum(mask), plen).to_int()
+    return n, compact_indices(mask, n) if bucket_len(n) < plen else None
 
 
 def _probe_candidates(left_keys, right_keys, null_safe=False,
@@ -1669,9 +1684,8 @@ def _probe_candidates(left_keys, right_keys, null_safe=False,
         # bucket, or at full width where that is no smaller
         bits = min((8 * max(plen_r, 1) - 1).bit_length(), _PRESENT_BITS_MAX)
         mask = _probe_mask_impl(rh, lh, bits=bits)
-        n_cand = DeviceCount(jnp.sum(mask), plen_l).to_int()
-        if bucket_len(n_cand) < plen_l:
-            idx = compact_indices(mask, n_cand)
+        _, idx = _narrowed_indices(mask, plen_l)
+        if idx is not None:
             lh = _probe_narrow_impl(lh, idx)
     lo = _probe_search_impl(rh_sorted, lh, side="left")
     hi = _probe_search_impl(rh_sorted, lh, side="right")
@@ -1898,6 +1912,88 @@ def _pk_gather_impl(fkey, fvalid, dkey, dvalid, n_fact, n_dim,
     return jnp.take(order, lo), matched
 
 
+@functools.partial(jax.jit, static_argnames="bits")
+@_trace.scoped("pk_gather.candidates")
+def _pk_mask_impl(fkey, fvalid, dkey, dvalid, n_fact, n_dim, f_excl, d_excl,
+                  bits):
+    """Which fact rows can match, at the fact bucket, with no search: the
+    row is live (:func:`_pk_gather_impl`'s ``ok_f``) AND the top ``bits``
+    bits of its MIXED key are those of some live dimension row's
+    (``present``: one scatter at the dimension's bucket, one gather here;
+    the key itself is no hash: a packed key's top bits are its first
+    column's). Equal keys share a prefix, so a row this drops misses under
+    the full search too; a row it keeps may still miss."""
+    plen_d = dkey.shape[0]
+    ok_d = jnp.arange(plen_d) < n_dim
+    if dvalid is not None:
+        ok_d = ok_d & dvalid
+    if d_excl is not None:
+        ok_d = ok_d & ~d_excl
+    shift = jnp.uint64(64 - bits)
+    slot = jnp.where(ok_d,
+                     (_mix64(dkey.astype(jnp.int64)) >> shift)
+                     .astype(jnp.int32), 1 << bits)
+    present = jnp.zeros(1 << bits, dtype=bool).at[slot].set(
+        True, mode="drop")
+    plen_f = fkey.shape[0]
+    ok_f = jnp.arange(plen_f) < n_fact
+    if fvalid is not None:
+        ok_f = ok_f & fvalid
+    if f_excl is not None:
+        ok_f = ok_f & ~f_excl
+    return ok_f & jnp.take(
+        present, (_mix64(fkey.astype(jnp.int64)) >> shift).astype(jnp.int32))
+
+
+@jax.jit
+@_trace.scoped("pk_gather.candidates")
+def _pk_narrow_impl(fkey, idx):
+    """The candidates' keys at their own bucket (``idx`` from
+    :func:`compact_indices`; the pad slots' value is never read: they lie
+    past the candidates' count)."""
+    return jnp.take(fkey, idx, mode="fill", fill_value=0)
+
+
+@functools.partial(jax.jit, static_argnames="plen")
+@_trace.scoped("pk_gather.candidates")
+def _pk_widen_impl(idx, r_idx, matched, plen):
+    """A narrowed probe's answer back at the fact bucket: row 0 and no
+    match on every row that was not searched (pad slots drop)."""
+    return (jnp.zeros(plen, r_idx.dtype).at[idx].set(r_idx, mode="drop"),
+            jnp.zeros(plen, dtype=bool).at[idx].set(matched, mode="drop"))
+
+
+def _pk_gather_sorted(fview, fvalid, dview, dvalid, n_fact, n_dim,
+                      f_excl, d_excl):
+    """The sorted arm of a PK gather: :func:`_pk_gather_impl`, whose binary
+    search costs 20 dependent gathers a fact row at the width it runs at.
+    Past the bucket where ``compact_table`` reads its count first, so does
+    this probe: one batched read of how many fact rows can match at all,
+    and the search runs at their bucket (the same program at a second
+    shape), or at full width where that is no smaller. ``matched`` is the
+    full search's bit for bit and ``r_idx`` is on every matched row."""
+    plen_f = int(fview.shape[0])
+    n_fact = count_arr(n_fact)
+    idx = None
+    if count_first(plen_f):
+        bits = min((_PK_PRESENT_FACTOR * max(int(dview.shape[0]), 1) - 1)
+                   .bit_length(), _PRESENT_BITS_MAX)
+        mask = _pk_mask_impl(fview, fvalid, dview, dvalid, n_fact, n_dim,
+                             f_excl, d_excl, bits=bits)
+        n_cand, idx = _narrowed_indices(mask, plen_f)
+    if idx is None:
+        _trace.annotate(probeRows=plen_f)
+        return _pk_gather_impl(fview, fvalid, dview, dvalid, n_fact, n_dim,
+                               f_excl, d_excl)
+    # the mask folded the fact side's liveness: the candidates are a live
+    # prefix of their bucket
+    _trace.annotate(probeRows=int(idx.shape[0]))
+    r_idx, matched = _pk_gather_impl(_pk_narrow_impl(fview, idx), None,
+                                     dview, dvalid, n_cand, n_dim, None,
+                                     d_excl)
+    return _pk_widen_impl(idx, r_idx, matched, plen=plen_f)
+
+
 _dense_dim_cache: dict = {}
 
 
@@ -1993,8 +2089,8 @@ def pk_gather_join(fact_key: Column, dim_key: Column,
             return _pk_gather_dense_impl(
                 fview, fact_key.valid, dview, dim_key.valid, pos_map,
                 jnp.int64(base), count_arr(n_fact), n_dim, f_excl, d_excl)
-    return _pk_gather_impl(fview, fact_key.valid, dview, dim_key.valid,
-                           count_arr(n_fact), n_dim, f_excl, d_excl)
+    return _pk_gather_sorted(fview, fact_key.valid, dview, dim_key.valid,
+                             n_fact, n_dim, f_excl, d_excl)
 
 
 _dim_span_cache: dict = {}
@@ -2077,8 +2173,8 @@ def pk_gather_join_multi(fact_keys, dim_keys, n_fact: int, n_dim: int,
     dpacked, dok = _pack_keys_impl(
         tuple(c.data for c in dim_keys),
         tuple(c.valid for c in dim_keys), offsets, widths, spans)
-    return _pk_gather_impl(fpacked, fok, dpacked, dok, count_arr(n_fact),
-                           n_dim, f_excl, d_excl)
+    return _pk_gather_sorted(fpacked, fok, dpacked, dok, n_fact, n_dim,
+                             f_excl, d_excl)
 
 
 def _null_column_like(col: Column, n: int) -> Column:

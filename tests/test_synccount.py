@@ -420,6 +420,216 @@ def test_probe_searches_only_its_candidates(
         "probeRows"] == sum(narrow["probeRows"])
 
 
+_PK_FACT_ROWS, _PK_DIM_ROWS = 5_000, 700    # buckets 8192 and 1024
+
+
+def _pk_case(rng, case):
+    """``(call, plen_f, dim bucket)`` of one PK probe: ``call()`` runs
+    ``pk_gather_join`` / ``pk_gather_join_multi`` over device columns made
+    here. Dimension keys are unique and SPARSE (one in about a thousand of
+    their range, so no dense position map) unless the case says dense;
+    one fact row in about seven has a match, every row in the two
+    full-width cases."""
+    from nds_tpu.engine.column import Column
+    n, m = _PK_FACT_ROWS, _PK_DIM_ROWS
+    n_dim = 0 if case == "empty_dim" else m
+    wide = case.startswith("full_width")
+    dk = rng.permutation(1_000_000)[:m] + 7
+    fk = np.where(rng.random(n) < (1.0 if wide else 0.15),
+                  rng.choice(dk, n), rng.integers(0, 1_000_000, n))
+    if case == "dense":
+        dk = np.arange(m) + 7
+        fk = rng.integers(0, 2 * m, n)
+    # a second column ((dk, dk2) stays unique): a fact row whose first key
+    # is a dimension row's carries that row's second key half the time
+    # (always in the full-width cases)
+    dk2 = rng.integers(0, 50, m)
+    pos = {int(k): i for i, k in enumerate(dk)}
+    fk2 = np.array([dk2[pos[int(k)]] if int(k) in pos and
+                    (wide or rng.random() < 0.5) else rng.integers(0, 50)
+                    for k in fk])
+
+    def table(name, cols):
+        s = Session()
+        s.create_temp_view(name, pa.table(cols))
+        return s.catalog[name]
+
+    def ints(v, nulls=0.0):
+        return pa.array([None if z else int(x) for x, z in
+                         zip(v, rng.random(len(v)) < nulls)], pa.int64())
+    if case == "string_pair":
+        fact = table("f", {"k": pa.array([f"s{x}" for x in fk])})
+        dim = table("d", {"k": pa.array([f"s{x}" for x in dk])})
+    else:
+        fact = table("f", {"k": ints(fk, 0.2 * (case == "nullable_fact")),
+                           "k2": ints(fk2)})
+        dim = table("d", {"k": ints(dk), "k2": ints(dk2)})
+    fcols, dcols = [fact["k"]], [dim["k"]]
+    if case in ("composite", "full_width_composite"):
+        fcols, dcols = [fact["k"], fact["k2"]], [dim["k"], dim["k2"]]
+    if case in ("sentinel", "dead_twin"):
+        # hand-made columns: a dead dimension row at a LOWER physical index
+        # than the live row it shares a slot with. sentinel: the dead row is
+        # null-keyed (it takes _PK_SENTINEL) and a live row really holds
+        # 2^63-1; dead_twin: the dead row holds a live row's own key
+        big = int(E._PK_SENTINEL)
+        dkey = np.array(dk, dtype=np.int64)
+        dkey[1] = big if case == "sentinel" else dkey[0]
+        dvalid = np.ones(dim.plen, dtype=bool)
+        dvalid[0] = False
+        fkey = np.array(fk, dtype=np.int64)
+        fkey[:3] = [dkey[1], dkey[2], dkey[1]]
+        fcols = [Column("int", jnp.asarray(
+            np.pad(fkey, (0, fact.plen - n))))]
+        dcols = [Column("int", jnp.asarray(np.pad(dkey, (0, dim.plen - m))),
+                        jnp.asarray(dvalid))]
+    side, _, which = case.partition("_excl_")     # "f_excl_some" -> f, some
+    f_excl = _excl(rng, fact.plen, which if side == "f" else "none")
+    d_excl = _excl(rng, dim.plen, which if side == "d" else "none")
+
+    def call():
+        return E.pk_gather_join_multi(fcols, dcols, n, n_dim,
+                                      f_excl=f_excl, d_excl=d_excl)
+    return call, fact.plen, dim.plen
+
+
+_PK_CASES = ["sparse_int", "composite", "string_pair", "nullable_fact",
+             "f_excl_some", "f_excl_all", "d_excl_some", "d_excl_all",
+             "empty_dim", "sentinel", "dead_twin", "full_width",
+             "full_width_composite", "dense"]
+
+
+@pytest.mark.parametrize("case", _PK_CASES)
+def test_pk_probe_searches_only_its_candidates(rng, monkeypatch, case):
+    """With NDS_TPU_LAZY_SHRINK_ROWS under the fact bucket the sorted arm of
+    ``pk_gather_join`` / ``pk_gather_join_multi`` reads its candidates'
+    count first and runs ``_pk_gather_impl`` at their bucket (at full width
+    where that is no smaller): exactly one more counted sync a probe, and
+    ``matched`` bit for bit, ``r_idx`` on every matched row, what the full
+    search gives with the threshold over the bucket. No read under the
+    threshold, on the dense arm, or inside a stream-bounds region.
+    ``op.pk_gather`` states ``probeRows``, the bucket searched (nothing on
+    the dense arm), and the rollup sums it."""
+    from nds_tpu.obs import trace as obs_trace
+    call, plen_f, plen_d = _pk_case(rng, case)
+
+    def counted():
+        E.resolve_counts()                # start from a drained thread
+        obs_trace.drain_spans()
+        before = _syncs()
+        r_idx, matched = call()
+        used = _syncs() - before
+        phase = obs_export.rollup(obs_trace.drain_spans())["phases"][
+            "op.pk_gather"]
+        return np.asarray(r_idx), np.asarray(matched), used, phase
+
+    monkeypatch.setenv("NDS_TPU_LAZY_SHRINK_ROWS", str(plen_f))
+    call()                                # the dimension's cached host plans
+    full_r, full_m, full_s, full_p = counted()
+    monkeypatch.setenv("NDS_TPU_LAZY_SHRINK_ROWS", str(plen_f // 2))
+    r, m, s, p = counted()
+    with E.stream_bounds():
+        bound_r, bound_m, bound_s, _ = counted()
+
+    assert m.dtype == full_m.dtype == np.bool_ and r.dtype == full_r.dtype
+    assert np.array_equal(m, full_m) and np.array_equal(bound_m, full_m)
+    assert np.array_equal(r[m], full_r[m])
+    assert np.array_equal(bound_r, full_r)
+    assert r.shape == (plen_f,) and r.min() >= 0 and r.max() < plen_d
+    hits = int(full_m.sum())
+    assert hits == 0 if case in ("f_excl_all", "d_excl_all", "empty_dim") \
+        else hits > 0
+    assert full_s == 0 and bound_s == 0
+    if case in ("sentinel", "dead_twin"):
+        # rows 0 and 2 hold the shared slot's key: the LIVE row answers
+        assert m[:3].tolist() == [True] * 3 and r[:3].tolist() == [1, 2, 1]
+    if case == "dense":
+        assert s == 0 and "probeRows" not in p and "probeRows" not in full_p
+        return
+    assert s == 1 and full_p["probeRows"] == plen_f
+    searched = p["probeRows"]
+    if case.startswith("full_width"):
+        assert hits == _PK_FACT_ROWS and searched == plen_f
+    else:
+        assert E.bucket_len(hits) <= searched < plen_f
+        assert not searched & (searched - 1)
+
+
+_PK_SQL = {
+    # Planner._binary_join's LEFT JOIN on the right side's composite PK:
+    # misses null-extended, the IS NULL idiom reads them
+    "left_join": """
+        select ss_item_sk, count(*) c, sum(ss_q) q, sum(sr_amt) a,
+               sum(case when sr_ticket_number is null then 1 else 0 end) z
+        from store_sales left join store_returns
+          on sr_ticket_number = ss_ticket_number and ss_item_sk = sr_item_sk
+        group by ss_item_sk order by ss_item_sk""",
+    # _join_parts' PK edge on the same composite key, under a deferred
+    # filter mask of each side (f_excl / d_excl)
+    "join_parts": """
+        select ss_item_sk, count(*) c, sum(ss_q) q, sum(sr_amt) a
+        from store_sales, store_returns
+        where sr_ticket_number = ss_ticket_number and ss_item_sk = sr_item_sk
+          and ss_q < 700 and sr_amt > 100
+        group by ss_item_sk order by ss_item_sk""",
+}
+
+
+@pytest.mark.parametrize("edge", list(_PK_SQL))
+def test_narrowed_pk_probe_through_sql_and_replay(rng, monkeypatch, edge):
+    """store_sales against store_returns on the declared composite PK
+    (item, ticket), through SQL, with the threshold under the fact's
+    bucket: the PK probe reads its candidates' count and searches at their
+    bucket, and the rows are the full-width search's; eager, recorded and
+    replayed executions give equal rows, the eager and the recorded one
+    make the same reads, the recording compiles (the narrowed shape
+    follows the logged count, no ReplayMismatch)."""
+    from nds_tpu.obs import trace as obs_trace
+    n, m = 6_000, 500                     # buckets 8192 and 512
+    item, ticket = rng.integers(1, 40, n), np.arange(n) // 3
+    back = rng.permutation(n)[:m]         # the sales rows that came back
+    s = Session()
+    s.create_temp_view("store_sales", pa.table({
+        "ss_item_sk": pa.array(item + 40 * (np.arange(n) % 3), pa.int64()),
+        "ss_ticket_number": pa.array(ticket, pa.int64()),
+        "ss_q": pa.array(rng.integers(1, 1000, n), pa.int64())}), base=True)
+    s.create_temp_view("store_returns", pa.table({
+        "sr_item_sk": pa.array((item + 40 * (np.arange(n) % 3))[back],
+                               pa.int64()),
+        "sr_ticket_number": pa.array(ticket[back], pa.int64()),
+        "sr_amt": pa.array(rng.integers(1, 1000, m), pa.int64())}),
+        base=True)
+    fact = s.catalog["store_sales"]
+    q = _PK_SQL[edge]
+    monkeypatch.setenv("NDS_TPU_REPLAY", "force")
+    monkeypatch.setenv("NDS_TPU_LAZY_SHRINK_ROWS", "1024")
+    E.resolve_counts()
+    obs_trace.drain_spans()
+    runs = []
+    for _ in range(4):                    # eager, record + compile, replay x2
+        before = _syncs()
+        rows = s.sql(q).collect()
+        roll = obs_export.rollup(obs_trace.drain_spans(), top_sites=20)
+        own = {x["site"]: x["syncs"] for x in roll["syncSites"]}
+        runs.append((rows, _syncs() - before, own, roll["phases"]))
+    (r0, n0, own0, ph0), (r1, _n1, own1, ph1), (r2, n2, _, ph2), \
+        (r3, n3, _, _) = runs
+    monkeypatch.setenv("NDS_TPU_REPLAY", "off")
+    monkeypatch.delenv("NDS_TPU_LAZY_SHRINK_ROWS")
+    want = s.sql(q).collect()             # full width: default threshold
+    assert want and want == r0 == r1 == r2 == r3
+    # the key ranges' plan (read by the first sight and by the recording)
+    # and the candidates' count, both at the probe's site
+    assert own0 == own1 and sum(own0.values()) == n0
+    assert max(n for site, n in own0.items()
+               if "_binary_join" in site or "_join_parts" in site) == 2, own0
+    assert "replay.compile" in ph1 and s._replay_cache
+    assert "replay.drive" in ph2 and n2 == n3 <= 1
+    # the sorted search ran under the fact's bucket in both tiers
+    assert ph0["op.pk_gather"]["probeRows"] == \
+        ph1["op.pk_gather"]["probeRows"] < fact.plen
+
+
 def test_batched_resolution_is_one_sync():
     """N pending DeviceCounts resolve in ONE counted transfer."""
     a = E.DeviceCount(jnp.asarray(3), 10)

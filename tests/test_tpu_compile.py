@@ -118,27 +118,49 @@ def test_segment_kernel_compiles_for_v5e(one_chip, kernel, dtype, rows,
 
 # the narrowed join probe at the multi-fact cell's shapes: store_sales'
 # 4 Mi probe bucket against store_returns' 512 Ki build bucket (a 4 Mi
-# bitmap), survivors at 64 Ki (the REAL bit alone, query25) and 8 Ki
+# bitmap), survivors at 64 Ki (the REAL bit alone, query25) and 8 Ki; and
+# the narrowed PK probe at the star cell's (query93): the same two buckets
+# (packed int64 keys with their combined validity, an 8 Mi bitmap), 375 k
+# candidates in a 512 Ki bucket, and _pk_gather_impl itself at its second
+# shape, the candidates' bucket
+_I64, _BOOL = jnp.int64, jnp.bool_
 PROBE_CASES = [
-    ("mask", E._probe_mask_impl,
+    ("mask", E._probe_mask_impl, "nds.join.candidates",
      [((1 << 19,), jnp.uint64), ((1 << 22,), jnp.uint64)], {"bits": 22}),
-    ("narrow", E._probe_narrow_impl,
-     [((1 << 22,), jnp.uint64), ((1 << 16,), jnp.int64)], {}),
-    ("widen", E._probe_widen_impl,
-     [((1 << 13,), jnp.int64), ((1 << 13,), jnp.int32),
+    ("narrow", E._probe_narrow_impl, "nds.join.candidates",
+     [((1 << 22,), jnp.uint64), ((1 << 16,), _I64)], {}),
+    ("widen", E._probe_widen_impl, "nds.join.candidates",
+     [((1 << 13,), _I64), ((1 << 13,), jnp.int32),
       ((1 << 13,), jnp.int32)], {"plen": 1 << 22}),
+    ("pk_mask", E._pk_mask_impl, "nds.pk_gather.candidates",
+     [((1 << 22,), _I64), ((1 << 22,), _BOOL), ((1 << 19,), _I64),
+      ((1 << 19,), _BOOL), ((), _I64), ((), _I64), None, None],
+     {"bits": 23}),
+    ("pk_narrow", E._pk_narrow_impl, "nds.pk_gather.candidates",
+     [((1 << 22,), _I64), ((1 << 19,), _I64)], {}),
+    ("pk_widen", E._pk_widen_impl, "nds.pk_gather.candidates",
+     [((1 << 19,), _I64), ((1 << 19,), _I64), ((1 << 19,), _BOOL)],
+     {"plen": 1 << 22}),
+    ("pk_search", E._pk_gather_impl, "nds.pk_gather",
+     [((1 << 19,), _I64), None, ((1 << 19,), _I64), ((1 << 19,), _BOOL),
+      ((), _I64), ((), _I64), None, None], {}),
 ]
 
 
-@pytest.mark.parametrize("fn,shapes,static", [c[1:] for c in PROBE_CASES],
+@pytest.mark.parametrize("fn,scope,shapes,static",
+                         [c[1:] for c in PROBE_CASES],
                          ids=[c[0] for c in PROBE_CASES])
-def test_narrowed_probe_compiles_for_v5e(one_chip, fn, shapes, static):
-    """The three jitted bodies the join probe adds around its searches
-    compile for a v5e at SF1's buckets, under their scope name."""
-    args = [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
-            for shape, dtype in shapes]
+def test_narrowed_probe_compiles_for_v5e(one_chip, fn, scope, shapes,
+                                         static):
+    """The three jitted bodies the join probe adds around its searches, the
+    three the sorted PK probe adds around its search, and that search at
+    the candidates' bucket, compile for a v5e at SF1's buckets, under their
+    scope names."""
+    args = [None if x is None else
+            jax.ShapeDtypeStruct(x[0], x[1], sharding=one_chip)
+            for x in shapes]
     lowered = fn.lower(*args, **static)
-    assert "nds.join.candidates" in lowered.as_text(debug_info=True)
+    assert scope in lowered.as_text(debug_info=True)
     lowered.compile()
 
 
