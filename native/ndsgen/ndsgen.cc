@@ -378,6 +378,9 @@ struct Scaling {
       web_pages, promotions, catalog_pages, reasons;
   int64_t ss_tickets, cs_orders, ws_orders;
 
+  // item's distinct business keys: rows 2k and 2k+1 share item_id k+1
+  int64_t item_ids() const { return (items + 1) / 2; }
+
   explicit Scaling(double sf_) : sf(sf_) {
     static const double aCust[6]   = {100e3, 500e3, 2e6, 12e6, 30e6, 65e6};
     static const double aItem[6]   = {18e3, 102e3, 204e3, 300e3, 360e3, 402e3};
@@ -434,9 +437,11 @@ struct Scaling {
     rows[T_CATALOG_RETURNS] = rows[T_CATALOG_SALES] / 10;
     rows[T_WEB_RETURNS] = rows[T_WEB_SALES] / 10;
     // weekly inventory snapshots over the 5-year sales window; sub-SF1 test
-    // scales shrink the window so inventory stays proportionate
+    // scales shrink the window so inventory stays proportionate. One row a
+    // distinct item_id, as dsdgen counts them (SCD pairs share one id): SF1
+    // holds 261 * 5 * 9000 = 11,745,000 rows
     int64_t inv_weeks = sf >= 1.0 ? 261 : std::max<int64_t>(13, (int64_t)(261 * sf * 10));
-    rows[T_INVENTORY] = inv_weeks * warehouses * items;
+    rows[T_INVENTORY] = inv_weeks * warehouses * item_ids();
     // refresh set: ~0.1% of the base facts per update
     rows[T_S_PURCHASE] = std::max<int64_t>(10, ss_tickets / 1000);
     rows[T_S_PURCHASE_LINEITEM] = rows[T_S_PURCHASE] * 12;
@@ -848,18 +853,22 @@ static void e_catalog_page(Row& w, int64_t r) {
 
 static void e_inventory(Row& w, int64_t r) {
   const uint64_t t = T_INVENTORY;
-  // row -> (week, warehouse, item); weekly snapshots across the sales window
-  int64_t per_week = S->warehouses * S->items;
+  // row -> (week, warehouse, item_id); weekly snapshots across the sales window
+  int64_t ids = S->item_ids();
+  int64_t per_week = S->warehouses * ids;
   int64_t week = r / per_week;
   int64_t rem = r % per_week;
-  int64_t wh = rem / S->items;
-  int64_t item = rem % S->items;
+  int64_t wh = rem / ids;
+  int64_t id = rem % ids;
   // spread the (possibly sub-SF1-shrunken) snapshot count across the FULL
   // 5-year window so date-window queries (q21/q22 month ranges) always find
   // snapshots; at full scale n_weeks == 261 and the stride is exactly 7 days
   int64_t n_weeks = std::max<int64_t>(1, S->rows[T_INVENTORY] / per_week);
   w.i(kSalesDateLo + ((week * 261) / n_weeks) * 7 + 3);
-  w.i(item + 1);
+  // the item_sk of the id's version valid on the snapshot's date (dsdgen's
+  // matchSCDSK): every date here follows the current row's rec_start
+  // (1997-10-27), so it is the id's even row, sk 2 * id + 1
+  w.i(2 * id + 1);
   w.i(wh + 1);
   // stockout-skewed on-hand quantity: ~40% of snapshots near zero, the rest
   // uniform. A pure uniform gives every (item, warehouse) group a coefficient

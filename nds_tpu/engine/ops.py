@@ -1690,8 +1690,9 @@ def _probe_candidates(left_keys, right_keys, null_safe=False,
     lo = _probe_search_impl(rh_sorted, lh, side="left")
     hi = _probe_search_impl(rh_sorted, lh, side="right")
     counts = hi - lo
-    # the bucket the two searches ran at (under plen_l: narrowed)
-    _trace.annotate(probeRows=int(lh.shape[0]))
+    # the bucket the two searches ran at (under plen_l: narrowed) and the
+    # one the build side's hash was sorted at (never narrowed)
+    _trace.annotate(probeRows=int(lh.shape[0]), buildRows=plen_r)
     if idx is not None:
         counts, lo = _probe_widen_impl(idx, counts, lo, plen=plen_l)
     total = host_sync(jnp.sum(counts))                 # host sync 1

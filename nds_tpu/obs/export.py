@@ -102,8 +102,10 @@ def rollup(records, top_sites: int = 5) -> dict:
     arrays and the indices or mask out) carries their sum, as does
     ``probeRows`` of ``op.join``: the bucket each join's two binary
     searches ran at (under the probe side's bucket: narrowed to its
-    candidates; on the chunked arm ``op.join`` also states ``spans``, the
-    spans of probe rows whose pairs it made), and ``deferredArrays`` of
+    candidates), and ``buildRows``, the bucket its build side's hash was
+    sorted at (neither inside a stream-bounds region; on the chunked arm
+    ``op.join`` also states ``spans``, the spans of probe rows whose pairs
+    it made), and ``deferredArrays`` of
     ``op.gather``: the columns' arrays read through a composed index, never
     gathered at the width of the PK-gather join that brought them, and
     every array read through a pair table's index. ``op.setop`` states the key
@@ -154,9 +156,9 @@ def rollup(records, top_sites: int = 5) -> dict:
                 p["compileMs"] + max(r.compile_ns - comp, 0) / 1e6, 3)
             if r.parent is None:
                 p["rootMs"] = round(p["rootMs"] + r.dur_ns / 1e6, 3)
-            for k in ("cells", "probeRows", "deferredArrays", "spans",
-                      "scanColumns", "planned", "correlated", "residual",
-                      "negated"):
+            for k in ("cells", "probeRows", "buildRows", "deferredArrays",
+                      "spans", "scanColumns", "planned", "correlated",
+                      "residual", "negated"):
                 if k in r.attrs:
                     p[k] = p.get(k, 0) + r.attrs[k]
             if r.name == COMPILE:

@@ -73,6 +73,29 @@ def test_chunks_union_equals_whole(tmp_path):
     assert merged == (whole / "time_dim.dat").read_bytes()
 
 
+def test_inventory_holds_one_row_a_distinct_item_week_and_warehouse(tmp_path):
+    """dsdgen's count: inventory spans item's distinct business keys, not its
+    surrogate keys (an SCD pair shares one id), each through the key of the
+    version valid on the snapshot's date."""
+    gen(tmp_path, "-table", "item")
+    gen(tmp_path, "-table", "inventory")
+    schemas = get_schemas(use_decimal=True)
+    item = read_raw_table(str(tmp_path / "item.dat"), schemas["item"])
+    inv = read_raw_table(str(tmp_path / "inventory.dat"), schemas["inventory"])
+    by_sk = {sk: (iid, start, end) for sk, iid, start, end in zip(
+        *(item[c].to_pylist() for c in ("i_item_sk", "i_item_id",
+                                        "i_rec_start_date", "i_rec_end_date")))}
+    sks = set(inv["inv_item_sk"].to_pylist())
+    ids = {by_sk[sk][0] for sk in sks}
+    assert len(ids) == len(sks) == len({v[0] for v in by_sk.values()})
+    assert all(by_sk[sk][2] is None for sk in sks)       # the current version
+    first_day = min(inv["inv_date_sk"].to_pylist()) - 2440588   # julian -> epoch
+    assert all(by_sk[sk][1].toordinal() - 719163 <= first_day for sk in sks)
+    weeks = len(set(inv["inv_date_sk"].to_pylist()))
+    warehouses = len(set(inv["inv_warehouse_sk"].to_pylist()))
+    assert inv.num_rows == weeks * warehouses * len(ids)
+
+
 def test_update_mode_emits_refresh_tables(tmp_path):
     gen(tmp_path, "-update", "1")
     schemas = get_maintenance_schemas(use_decimal=True)

@@ -60,7 +60,15 @@ def _load_sqlite_cached(load_sqlite, data_dir):
 
 @pytest.fixture(scope="module")
 def oracle_setup():
-    os.environ.setdefault("NDS_TPU_COMP_CACHE", "force")
+    # the persistent cache for this module's tests only: a later test in the
+    # same worker that counts compiles must not find it on
+    with pytest.MonkeyPatch.context() as mp:
+        if "NDS_TPU_COMP_CACHE" not in os.environ:
+            mp.setenv("NDS_TPU_COMP_CACHE", "force")
+        yield _oracle_setup()
+
+
+def _oracle_setup():
     from tools.oracle_validate import load_sqlite
     from tools.coverage_sweep import ensure_data
     from nds_tpu.queries import generate_query_streams

@@ -420,6 +420,82 @@ def test_probe_searches_only_its_candidates(
         "probeRows"] == sum(narrow["probeRows"])
 
 
+def _join_spans(fn):
+    """``fn()``'s ``op.join`` spans, from a drained thread."""
+    from nds_tpu.obs import trace as obs_trace
+    E.resolve_counts()
+    obs_trace.drain_spans()
+    fn()
+    return [r for r in obs_trace.drain_spans()
+            if isinstance(r, obs_trace.SpanRecord) and r.name == "op.join"]
+
+
+@pytest.mark.parametrize("arm", ["full_width", "narrowed", "stream_bounds"])
+def test_join_states_its_build_bucket(rng, monkeypatch, arm):
+    """``op.join`` states ``buildRows``, the bucket the build side's hash was
+    sorted at, wherever it states ``probeRows``: the build side's bucket
+    whether the probe ran at full width or narrowed to its candidates (the
+    build side is never narrowed). Inside a stream-bounds region it states
+    neither."""
+    left, right = _probe_tables(rng, "int", False, _BUILD_ROWS)
+    threshold = left.plen if arm == "full_width" else left.plen // 2
+    monkeypatch.setenv("NDS_TPU_LAZY_SHRINK_ROWS", str(threshold))
+    region = E.stream_bounds() if arm == "stream_bounds" \
+        else contextlib.nullcontext()
+
+    def join():
+        with region:
+            E.join_indices([left["lk"]], [right["rk"]], "inner",
+                           n_left=left.nrows, n_right=right.nrows)
+    (span,) = _join_spans(join)
+    if arm == "stream_bounds":
+        assert "probeRows" not in span.attrs
+        assert "buildRows" not in span.attrs
+        return
+    assert span.attrs["buildRows"] == right.plen == E.bucket_len(_BUILD_ROWS)
+    probe = span.attrs["probeRows"]
+    assert probe == left.plen if arm == "full_width" else probe < left.plen
+
+
+def test_rollup_sums_build_rows_over_two_joins(rng):
+    """Two joins of one statement, build sides of two buckets: the rollup's
+    ``op.join`` carries the sum of their ``buildRows``."""
+    left, right = _probe_tables(rng, "int", False, _BUILD_ROWS)
+    wide = _probe_tables(rng, "int", False, 3 * _BUILD_ROWS)[1]
+
+    def two():
+        for build in (right, wide):
+            E.join_indices([left["lk"]], [build["rk"]], "inner",
+                           n_left=left.nrows, n_right=build.nrows)
+    spans = _join_spans(two)
+    assert [s.attrs["buildRows"] for s in spans] == [right.plen, wide.plen]
+    assert right.plen != wide.plen
+    assert obs_export.rollup(spans)["phases"]["op.join"]["buildRows"] \
+        == right.plen + wide.plen
+
+
+def test_build_rows_stated_nowhere_with_tracing_off(rng):
+    """With tracing off no span is made, so nothing states ``buildRows``;
+    the join's pairs are those of the traced call."""
+    from nds_tpu.obs import trace as obs_trace
+    left, right = _probe_tables(rng, "int", False, _BUILD_ROWS)
+
+    def pairs():
+        out = E.join_indices([left["lk"]], [right["rk"]], "inner",
+                             n_left=left.nrows, n_right=right.nrows)
+        return np.asarray(out[0]), np.asarray(out[1])
+    traced = pairs()
+    untraced = []
+    obs_trace.set_enabled(False)
+    try:
+        spans = _join_spans(lambda: untraced.append(pairs()))
+    finally:
+        obs_trace.set_enabled(True)
+    assert spans == []
+    for a, b in zip(untraced[0], traced):
+        assert np.array_equal(a, b)
+
+
 _PK_FACT_ROWS, _PK_DIM_ROWS = 5_000, 700    # buckets 8192 and 1024
 
 
