@@ -662,13 +662,54 @@ def live_mask(plen: int, nrows) -> jnp.ndarray:
     return jnp.arange(plen) < count_arr(nrows)
 
 
+@jax.jit
+@_trace.scoped("compact.sort")
+def _compact_sort_impl(mask):
+    """Positions of ``mask``'s True rows in ascending order, then
+    ``plen`` in every other slot: one operand sorted at the input's length,
+    so one program a bucket whatever the output width. The keys are int32
+    (the chip has no 64-bit integers) unless ``plen`` needs more."""
+    plen = mask.shape[0]
+    dt = jnp.int32 if plen < 2**31 else jnp.int64
+    keys = jnp.where(mask, jax.lax.iota(dt, plen), jnp.asarray(plen, dt))
+    return jax.lax.sort(keys, is_stable=False)
+
+
+@functools.partial(jax.jit, static_argnames=("cap",))
+@_trace.scoped("compact.sort")
+def _compact_cap_impl(keys, cap):
+    """The sorted keys cut (or padded) to ``cap`` and cast to the index
+    dtype ``jnp.nonzero`` returns, so every gather downstream sees the
+    operands it saw before."""
+    plen = keys.shape[0]
+    if cap > plen:
+        keys = jnp.concatenate(
+            [keys, jnp.full(cap - plen, max(plen, 1), keys.dtype)])
+    return keys[:cap].astype(jax.dtypes.canonicalize_dtype(int))
+
+
+def _compact_sorted(mask: jnp.ndarray, cap: int) -> jnp.ndarray:
+    """The sort arm of :func:`compact_indices`: ``jnp.nonzero(mask,
+    size=cap, fill_value=plen)`` index for index. States ``sortedRows``,
+    the input bucket sorted, on the innermost open span where it runs
+    eagerly (traced into a caller's program it states nothing: the trace
+    runs once, the program many times)."""
+    if not isinstance(mask, jax.core.Tracer):
+        _trace.tally(sortedRows=int(mask.shape[0]))
+    return _compact_cap_impl(_compact_sort_impl(mask), cap=cap)
+
+
 def compact_indices(mask: jnp.ndarray, n: int) -> jnp.ndarray:
     """Indices of the first ``n`` True rows of ``mask``, padded to
     ``bucket_len(n)`` with an out-of-range fill (gathers clip, scatters
-    drop)."""
+    drop). By one sort (:func:`_compact_sorted`): ``jnp.nonzero``'s int64
+    cumsums and scatter-add cost the chip about 90 ns an input row. Inside a
+    stream-bounds region the chunk programs keep ``jnp.nonzero``'s form."""
     cap = bucket_len(n)
-    plen = int(mask.shape[0])
-    return jnp.nonzero(mask, size=cap, fill_value=max(plen, 1))[0]
+    if not stream_bounds_on():
+        return _compact_sorted(mask, cap)
+    return jnp.nonzero(mask, size=cap,
+                       fill_value=max(int(mask.shape[0]), 1))[0]
 
 
 # lazy-compaction bucket ceiling: below it, carrying the un-shrunk bucket
@@ -1758,7 +1799,7 @@ def join_indices(left_keys, right_keys, how: str = "inner",
         # into ONE transfer (DESIGN.md item 3) — because the concatenated
         # output layout needs host offsets; an inner join never syncs here.
         n_pairs = DeviceCount(jnp.sum(ok), n_pairs_bound)
-        keep = jnp.nonzero(ok, size=cand, fill_value=cand)[0]
+        keep = compact_indices(ok, cand)
         # out-of-range pads: point pad pairs past both inputs
         l_idx = jnp.take(l_idx, keep, mode="fill", fill_value=plen_l)
         r_idx = jnp.take(r_idx, keep, mode="fill", fill_value=plen_r)
@@ -2329,8 +2370,7 @@ def _exchange_inner_join(left, right, left_keys, right_keys, mesh,
         rh, jnp.arange(plen_r, dtype=jnp.int64), mesh)
     ok = live & _verify_pairs(l_idx_x, r_idx_x, left_keys, right_keys)
     n_pairs = host_sync(jnp.sum(ok))                   # host sync
-    keep = jnp.nonzero(ok, size=bucket_len(n_pairs),
-                       fill_value=int(ok.shape[0]))[0]
+    keep = compact_indices(ok, n_pairs)
     l_idx = jnp.take(l_idx_x, keep, mode="fill", fill_value=plen_l)
     r_idx = jnp.take(r_idx_x, keep, mode="fill", fill_value=plen_r)
     matched = DeviceTable(

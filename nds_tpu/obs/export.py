@@ -116,6 +116,9 @@ def rollup(records, top_sites: int = 5) -> dict:
     states the key arrays its decorrelation reads as ``cells`` and, each
     0 / 1 a span and so a count a phase, ``planned`` (this evaluator
     planned the inner query), ``correlated``, ``residual``, ``negated``.
+    ``sortedRows``: the input buckets the compaction's sort arm sorted
+    (``ops.compact_indices``), stated on whichever span is innermost:
+    ``op.compact``, ``op.join``, ``op.pk_gather`` and the like.
     ``phases["compile"]`` (one span a program build,
     :mod:`nds_tpu.obs.compiles`) carries the sums of its spans'
     ``backendMs`` / ``readMs`` / ``traceMs`` / ``lowerMs`` and counts
@@ -158,7 +161,7 @@ def rollup(records, top_sites: int = 5) -> dict:
                 p["rootMs"] = round(p["rootMs"] + r.dur_ns / 1e6, 3)
             for k in ("cells", "probeRows", "buildRows", "deferredArrays",
                       "spans", "scanColumns", "planned", "correlated",
-                      "residual", "negated"):
+                      "residual", "negated", "sortedRows"):
                 if k in r.attrs:
                     p[k] = p.get(k, 0) + r.attrs[k]
             if r.name == COMPILE:

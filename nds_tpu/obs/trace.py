@@ -436,3 +436,16 @@ def note_compile(ts_ns: int, dur_ns: int, compile_ns: int, **attrs) -> None:
         dur_ns = end_ns - ts_ns
     rec.ts_ns, rec.dur_ns, rec.compile_ns = ts_ns, dur_ns, compile_ns
     _emit(rec)
+
+
+def tally(**counts) -> None:
+    """Add ``counts`` to the attributes of the innermost OPEN span: the
+    summing form of :func:`annotate`, for a callee that may run more than
+    once under one span (the compaction's sort arm: ``sortedRows``)."""
+    if not _enabled or _ops().replay_mode() == "replay":
+        return
+    st = getattr(_tls, "stack", None)
+    if st:
+        attrs = st[-1].attrs
+        for k, v in counts.items():
+            attrs[k] = attrs.get(k, 0) + v

@@ -1117,7 +1117,7 @@ class Planner:
         ri = jnp.tile(jnp.arange(pr), pl)
         live = (li < nl) & (ri < nr)
         # logical count is known on host: compact to bucket with no sync
-        idx = jnp.nonzero(live, size=E.bucket_len(total), fill_value=pl * pr)[0]
+        idx = E.compact_indices(live, total)
         li = jnp.take(li, idx, mode="fill", fill_value=pl)
         ri = jnp.take(ri, idx, mode="fill", fill_value=pr)
         return DeviceTable(

@@ -195,6 +195,50 @@ def test_exchange_repartition_join_matches_single_device(monkeypatch):
     assert got == single.sql(sql).collect()
 
 
+def test_exchange_join_compacts_a_sharded_mask_by_sort(monkeypatch):
+    """The exchange join's pair compaction sorts a mask sharded over the
+    mesh (a global sort under GSPMD) and keeps exactly the pairs the
+    single-device engine keeps."""
+    import pyarrow as pa
+    from nds_tpu.engine import ops as E
+    from nds_tpu.engine.session import Session
+
+    monkeypatch.setenv("NDS_TPU_BROADCAST_BYTES", "64")   # shard everything
+    rng = np.random.default_rng(11)
+    n = 4096
+    a = pa.table({"a_k": pa.array(rng.integers(1, 500, n), pa.int64()),
+                  "a_v": pa.array(rng.integers(1, 1000, n), pa.int64())})
+    b = pa.table({"b_k": pa.array(rng.integers(1, 500, n), pa.int64()),
+                  "b_v": pa.array(rng.integers(1, 1000, n), pa.int64())})
+    sql = ("select a_k, a_v, b_v from a, b where a_k = b_k and a_v < b_v "
+           "order by a_k, a_v, b_v")
+    single = Session()
+    meshed = Session(conf={"mesh_shape": 8})
+    for name, t in (("a", a), ("b", b)):
+        single.create_temp_view(name, t)
+        meshed.create_temp_view(name, t)
+    in_exchange, sharded = [], []
+    orig_join, orig_sorted = E._exchange_inner_join, E._compact_sorted
+
+    def exchange(*args, **kw):
+        in_exchange.append(1)
+        try:
+            return orig_join(*args, **kw)
+        finally:
+            in_exchange.pop()
+
+    def compact_sorted(mask, cap):
+        if in_exchange:
+            sharded.append(len(mask.sharding.device_set))
+        return orig_sorted(mask, cap)
+
+    monkeypatch.setattr(E, "_exchange_inner_join", exchange)
+    monkeypatch.setattr(E, "_compact_sorted", compact_sorted)
+    got = meshed.sql(sql).collect()
+    assert sharded and max(sharded) == 8, sharded
+    assert got == single.sql(sql).collect()
+
+
 def test_exchange_join_dead_rows():
     """Sentinel (dead) rows — null keys, pad rows, deferred-filter exclusions
     — must not corrupt the bucketize: regression for the unsorted-haystack
