@@ -1249,6 +1249,11 @@ def group_ids(key_cols, n_valid: int | None = None):
     # counts the query accumulated upstream (filter compactions, inner-join
     # pair counts) resolve in the SAME transfer
     ngroups = DeviceCount(ng_dev, count_bound(n_valid)).to_int()
+    # what the grouping was, from host-known values: the key count, the
+    # arm, the bucket it sorted at and the groups it found
+    _trace.annotate(keys=len(key_cols),
+                    arm="fold" if plan is None else "packed", rows=plen,
+                    groups=ngroups)
     cap = bucket_len(ngroups)
     rep = _group_rep_impl(gids, nv, cap)
     return gids, ngroups, rep, cap
@@ -1760,6 +1765,8 @@ def join_indices(left_keys, right_keys, how: str = "inner",
     the join without a compaction sync. ``probe`` passes a precomputed
     :func:`_probe_candidates` result.
     """
+    if any(c.kind == "str" for c in left_keys):
+        _trace.annotate(stringKey=True)
     plen_l = len(left_keys[0])
     plen_r = len(right_keys[0])
     n_left = plen_l if n_left is None else n_left

@@ -119,6 +119,11 @@ def rollup(records, top_sites: int = 5) -> dict:
     ``sortedRows``: the input buckets the compaction's sort arm sorted
     (``ops.compact_indices``), stated on whichever span is innermost:
     ``op.compact``, ``op.join``, ``op.pk_gather`` and the like.
+    ``op.group_ids`` states ``keys`` (its arity), ``rows`` (the bucket it
+    grouped at), ``groups`` (the groups it found) and ``arm`` (``packed``
+    | ``fold``; on the span alone); ``plan`` states ``cteReads``, the
+    reads of a ``WITH`` body by name; ``op.join`` states ``stringKey``
+    (True, a count here) where its key columns are strings.
     ``phases["compile"]`` (one span a program build,
     :mod:`nds_tpu.obs.compiles`) carries the sums of its spans'
     ``backendMs`` / ``readMs`` / ``traceMs`` / ``lowerMs`` and counts
@@ -161,7 +166,8 @@ def rollup(records, top_sites: int = 5) -> dict:
                 p["rootMs"] = round(p["rootMs"] + r.dur_ns / 1e6, 3)
             for k in ("cells", "probeRows", "buildRows", "deferredArrays",
                       "spans", "scanColumns", "planned", "correlated",
-                      "residual", "negated", "sortedRows"):
+                      "residual", "negated", "sortedRows", "keys", "rows",
+                      "groups", "cteReads", "stringKey"):
                 if k in r.attrs:
                     p[k] = p.get(k, 0) + r.attrs[k]
             if r.name == COMPILE:

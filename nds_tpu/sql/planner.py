@@ -231,6 +231,9 @@ class Planner:
         # columns the statement's catalog scans kept after the pruning,
         # summed over its scans: the plan span's ``scanColumns``
         self._scan_columns = 0
+        # WITH bodies ``_lookup_table`` served by name, summed over the
+        # statement: the plan span's ``cteReads``
+        self._cte_reads = 0
         # roofline accounting: catalog tables this statement actually bound,
         # with their resident byte sizes (per-query scanBytes in summaries)
         self.scanned: dict[str, int] = {}
@@ -368,6 +371,7 @@ class Planner:
         if top_level:
             self._needed_names = self._collect_needed_names(q)
             self._scan_columns = 0
+            self._cte_reads = 0
         scope = {}
         self.cte_stack.append(scope)
         # the statement-level plan/execute span (this engine plans as it
@@ -383,7 +387,8 @@ class Planner:
                     out = self._apply_order_by(out, q.order_by, q.body)
                 if q.limit is not None:
                     out = E.limit_table(out, q.limit)
-                plan_span.set(scanColumns=self._scan_columns)
+                plan_span.set(scanColumns=self._scan_columns,
+                              cteReads=self._cte_reads)
                 return out
         finally:
             self.cte_stack.pop()
@@ -482,6 +487,7 @@ class Planner:
     def _lookup_table(self, name: str) -> DeviceTable:
         for scope in reversed(self.cte_stack):
             if name.lower() in scope:
+                self._cte_reads += 1
                 return scope[name.lower()]
         key = name.lower() if name.lower() in self.catalog else name
         if key in self.catalog:

@@ -1013,3 +1013,84 @@ def test_a_full_outer_join_states_the_unmatched_rows_of_both_sides():
         + int(l_extra.shape[0]) + int(r_extra.shape[0]))
     assert int(l_extra.shape[0]) == E.bucket_len(n_lx)
     assert int(r_extra.shape[0]) == E.bucket_len(n_rx)
+
+
+# ---------------------------------------------------------------------------
+# op.group_ids' keys / arm / rows / groups, plan's cteReads, op.join's
+# stringKey: a WITH grouped by customer and year, read twice, self-joined
+# on a string key
+# ---------------------------------------------------------------------------
+
+_YOY_QUERY = ("with yt as (select c_id, nm, y, sum(v) total from tc "
+              "group by c_id, nm, y) "
+              "select a.c_id, a.nm from yt a, yt b where a.c_id = b.c_id "
+              "and a.y = 1 and b.y = 2 and b.total > a.total order by 1, 2")
+
+
+def _yoy_session():
+    rng = np.random.default_rng(42)
+    n = 600
+    s = Session()
+    s.create_temp_view("tc", pa.table({
+        "c_id": pa.array([f"C{int(i):05d}"
+                          for i in rng.integers(0, 80, n)]),
+        "nm": pa.array([None if i % 5 == 0 else f"N{i % 7}"
+                        for i in range(n)]),
+        "y": pa.array(rng.integers(1, 3, n), pa.int64()),
+        "v": pa.array(rng.integers(0, 1000, n), pa.int64())}))
+    return s
+
+
+@pytest.mark.parametrize("arm", ["packed", "fold"])
+def test_group_ids_states_keys_arm_rows_and_groups(arm, monkeypatch):
+    """``op.group_ids`` states its arity, the arm it took, the bucket it
+    grouped at and the group count it read anyway: no host read is added
+    (the same rows and reads with tracing off), and the rollup sums the
+    three numbers."""
+    monkeypatch.setenv("NDS_TPU_GROUP_PACK_MIN",
+                       "1" if arm == "packed" else str(1 << 30))
+    s = _yoy_session()
+    tc = s.catalog["tc"]
+    _rows, records = _traced_and_untraced(s, _YOY_QUERY)
+    (span,) = [r for r in records if isinstance(r, obs_trace.SpanRecord)
+               and r.name == "op.group_ids"]
+    t = tc.to_arrow()
+    distinct = {(c, n, y) for c, n, y in zip(t.column("c_id").to_pylist(),
+                                              t.column("nm").to_pylist(),
+                                              t.column("y").to_pylist())}
+    assert span.attrs == {"keys": 3, "arm": arm, "rows": tc.plen,
+                          "groups": len(distinct)}
+    phase = obs_export.rollup(records)["phases"]["op.group_ids"]
+    assert (phase["keys"], phase["rows"], phase["groups"]) == (
+        3, tc.plen, len(distinct))
+    assert "arm" not in phase
+
+
+def test_plan_counts_the_with_bodys_reads_by_name():
+    """``plan`` states ``cteReads``: the times a ``WITH`` body was served by
+    its name (twice here: two aliases), 0 where the statement has none."""
+    s = _yoy_session()
+    for query, reads in ((_YOY_QUERY, 2),
+                         ("select count(*) from tc where y = 1", 0)):
+        _rows, records = _traced_and_untraced(s, query)
+        (plan,) = [r for r in records if isinstance(r, obs_trace.SpanRecord)
+                   and r.name == "plan"]
+        assert plan.attrs["cteReads"] == reads
+        assert obs_export.rollup(records)["phases"]["plan"]["cteReads"] \
+            == reads
+
+
+def test_a_join_on_a_string_key_says_so():
+    """``op.join`` states ``stringKey`` where its key columns are strings
+    and nothing where they are integers; no host read is added."""
+    s = _yoy_session()
+    _rows, records = _traced_and_untraced(s, _YOY_QUERY)
+    joins = [r for r in records if isinstance(r, obs_trace.SpanRecord)
+             and r.name == "op.join"]
+    assert [r.attrs["stringKey"] for r in joins if "stringKey" in r.attrs] \
+        == [True]
+    assert obs_export.rollup(records)["phases"]["op.join"]["stringKey"] == 1
+    _rows, records = _traced_and_untraced(
+        s, "select count(*) from tc a, tc b where a.y = b.y and a.v < 9")
+    assert not [r for r in records if isinstance(r, obs_trace.SpanRecord)
+                and "stringKey" in r.attrs]
